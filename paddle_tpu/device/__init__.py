@@ -37,10 +37,7 @@ def _device(dev=None):
 def memory_stats(device=None) -> dict:
     """Raw PJRT allocator stats (bytes_in_use, peak_bytes_in_use,
     bytes_limit, ...). Empty dict on backends without stats (CPU)."""
-    try:
-        return dict(_device(device).memory_stats() or {})
-    except Exception:
-        return {}
+    return dict(_device(device).memory_stats() or {})
 
 
 def memory_allocated(device=None) -> int:

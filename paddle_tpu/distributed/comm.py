@@ -28,10 +28,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:
-    from jax import shard_map as _shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
+from jax import shard_map as _shard_map
 
 
 # The mesh-axis classification every hot-path router shares (round 7):
@@ -56,27 +53,15 @@ def shard_map(f, mesh, in_specs, out_specs, auto=None):
     """The repo-wide shard_map wrapper (replication checking off — bodies
     use explicit collectives). `auto` names mesh axes left to GSPMD
     inside the body (partial-manual regions: the async-dcn grad
-    reduction is manual over 'dcn', auto over ici/mp/...)."""
-    kw = {} if auto is None else {"auto": frozenset(auto)}
-    try:
-        return _shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False, **kw,
-        )
-    except TypeError as e:
-        if kw and "auto" in str(e):
-            # distinct failure from the check_vma/check_rep rename: this
-            # jax's shard_map has no partial-auto support at all
-            raise NotImplementedError(
-                "this jax's shard_map does not accept `auto` (partial-"
-                "manual regions) — async_dcn_allreduce needs a jax with "
-                "partial-auto shard_map"
-            ) from e
-        # pre-0.9 jax: the flag was called check_rep
-        return _shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False, **kw,
-        )
+    reduction is manual over 'dcn', auto over ici/mp/...); jax spells
+    that as its complement, `axis_names` = the manual axes."""
+    kw = {}
+    if auto is not None:
+        kw["axis_names"] = frozenset(mesh.axis_names) - frozenset(auto)
+    return _shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False, **kw,
+    )
 
 
 class Group:
@@ -276,17 +261,11 @@ def init_parallel_env(backend: Optional[str] = None) -> "ParallelEnv":
 
         def _init(remaining):
             try:
-                try:
-                    jax.distributed.initialize(
-                        coordinator_address=coordinator,
-                        num_processes=num, process_id=pid,
-                        initialization_timeout=max(int(remaining), 1),
-                    )
-                except TypeError:  # older jax: no initialization_timeout
-                    jax.distributed.initialize(
-                        coordinator_address=coordinator,
-                        num_processes=num, process_id=pid,
-                    )
+                jax.distributed.initialize(
+                    coordinator_address=coordinator,
+                    num_processes=num, process_id=pid,
+                    initialization_timeout=max(int(remaining), 1),
+                )
             except Exception:
                 try:  # leave no half-initialized client behind a retry
                     jax.distributed.shutdown()

@@ -4,25 +4,21 @@ Reference: python/paddle/distributed/spawn.py:276 — start nprocs python
 processes running `func(*args)` with the cluster env injected, join, and
 re-raise the first failure.
 
-TPU note: one jax process per HOST; nprocs>1 is the CPU-backend testing
-path (each child pins JAX_PLATFORM_NAME=cpu unless told otherwise). Env
-is injected before `func` runs; lazily-imported jax in the child then
-picks up the coordinator settings.
+TPU note: one jax process per HOST owns the chip; nprocs>1 is the
+CPU-backend testing path (`backend="cpu"` puts JAX_PLATFORMS=cpu in each
+child's environment). The cluster env is in the child's environment from
+its first instruction — before `func` is unpickled, which may already
+import jax.
 """
 from __future__ import annotations
 
 import multiprocessing as mp
-import os
 from typing import Tuple
 
+from ..core.device import child_environ
 from .launch import build_cluster_env
 
 __all__ = ["spawn"]
-
-
-def _worker(func, args, env):
-    os.environ.update(env)
-    func(*args)
 
 
 def spawn(func, args: Tuple = (), nprocs: int = 1, join: bool = True,
@@ -34,10 +30,10 @@ def spawn(func, args: Tuple = (), nprocs: int = 1, join: bool = True,
     procs = []
     for env in envs:
         if backend:
-            env["JAX_PLATFORM_NAME"] = backend
-        p = ctx.Process(target=_worker, args=(func, args, env),
-                        daemon=daemon)
-        p.start()
+            env["JAX_PLATFORMS"] = backend
+        p = ctx.Process(target=func, args=args, daemon=daemon)
+        with child_environ(env):
+            p.start()
         procs.append(p)
     if not join:
         return procs

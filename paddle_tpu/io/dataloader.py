@@ -21,6 +21,7 @@ from typing import Callable, Optional
 
 import numpy as np
 
+from ..core.device import CpuSpawnContext
 from ..core.tensor import Tensor
 from .dataset import Dataset, IterableDataset
 from .sampler import BatchSampler
@@ -32,12 +33,10 @@ _PROC_STATE = {}
 def _proc_worker_init(dataset, collate_fn):
     """Runs once per spawned worker: bind the dataset/collate globally
     (the mmap-shared-dataset analog — spawn ships them exactly once).
-    Workers pin jax to CPU FIRST — a child touching jnp (e.g. a dataset
-    returning Tensors) must never grab the parent's TPU."""
-    import os
-
-    os.environ["JAX_PLATFORMS"] = "cpu"
-    os.environ["JAX_PLATFORM_NAME"] = "cpu"
+    The pool's `CpuSpawnContext` has already pinned the worker to the
+    CPU backend in its environment — a child touching jnp (e.g. a
+    dataset returning Tensors) must never grab the parent's TPU, and by
+    the time this runs unpickling has imported jax."""
     _PROC_STATE["dataset"] = dataset
     _PROC_STATE["collate"] = collate_fn
 
@@ -279,11 +278,9 @@ class DataLoader:
                 pool = ThreadPoolExecutor(max_workers=self.num_workers)
                 self._pool_is_proc = False
             else:
-                import multiprocessing as mp
-
                 pool = ProcessPoolExecutor(
                     max_workers=self.num_workers,
-                    mp_context=mp.get_context("spawn"),
+                    mp_context=CpuSpawnContext(),
                     initializer=_proc_worker_init,
                     initargs=(self.dataset, self.collate_fn),
                 )

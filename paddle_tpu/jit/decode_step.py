@@ -7,7 +7,8 @@ sampling compiled as ONE XLA program per token, with
 - **donated cache buffers** — the [B, H, cap, Dh] K/V caches are
   replaced every step (written in place at per-slot positions), so XLA
   updates them in HBM instead of copying; like TrainStep, donation is
-  skipped on the CPU backend;
+  on for every backend (the CPU client honours it, so the CPU tests
+  run the same aliasing the chip does);
 - **recompile-ledger instrumentation** — the jitted step dispatches
   through `observability.ledger.instrument` (labels ``DecodeStep`` /
   ``PrefillStep``), so a shape wobble in the serving loop lands on the
@@ -204,7 +205,7 @@ class _CompiledDecodeBase:
                     getattr(o._data, "sharding", None), NamedSharding
                 ):
                     o._data = jax.device_put(o._data, repl)
-        self._donate = donate and jax.default_backend() != "cpu"
+        self._donate = donate
         # STATIC at construction (like the model objects themselves):
         # a model with an AdapterSet attached threads per-slot adapter
         # ids into its forward; without one the traced program is
@@ -438,7 +439,7 @@ class MigrateInsert:
     _label = "CacheInsert"
 
     def __init__(self, *, donate: bool = True):
-        self._donate = donate and jax.default_backend() != "cpu"
+        self._donate = donate
         self._jitted = None
         self._n_steps = 0
         from ..observability import bus as _bus, ledger as _ledger

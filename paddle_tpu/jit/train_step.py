@@ -34,6 +34,29 @@ def _as_list(x):
     return [x]
 
 
+def _commit_on_one_device(tree):
+    """Commit every eager-built (uncommitted) array of the loop-carried
+    step state to the device it already sits on — when, and only when,
+    the whole state lives on ONE device.
+
+    The step's outputs come back committed as soon as any input was
+    (the optimizer's moments are), so fresh params and the guard carry
+    flip uncommitted -> committed between call 1 and call 2: same
+    shapes, another input signature, and the entire step silently
+    compiles twice. State that spans several devices is left alone:
+    pinning a stray single-device leaf next to operands a DataParallel
+    wrap laid out on the default-group mesh is an "incompatible devices"
+    error at dispatch, and the hybrid-mesh path normalizes placement in
+    the constructor instead."""
+    leaves = [x for x in jax.tree_util.tree_leaves(tree)
+              if isinstance(x, jax.Array)]
+    if len({d for x in leaves for d in x.sharding.device_set}) != 1:
+        return tree
+    return jax.tree_util.tree_map(
+        lambda x: jax.device_put(x, x.sharding)
+        if isinstance(x, jax.Array) and not x.committed else x, tree)
+
+
 def process_grads(opt, p_objs, p_raws, g_raws, grad_post_hook=None):
     """Regularizer terms + grad clip + strategy hook, traced. Shared by
     TrainStep and LocalSGDStep so strategy/optimizer extras never silently
@@ -69,7 +92,7 @@ class TrainStep:
 
     loss_fn receives (model_outputs, *labels) as Tensors under trace and
     returns a scalar loss Tensor. Parameter and optimizer-state buffers are
-    donated to XLA (in-place HBM update) except on the CPU backend.
+    donated to XLA (in-place HBM update) on every backend.
     Gradient clipping, per-param regularizers, and LR schedules compose
     inside the compiled program; the LR rides as a traced scalar so schedule
     changes never retrigger compilation.
@@ -269,7 +292,7 @@ class TrainStep:
                 # optimizer's eager boundary policy armed, not silently
                 # full-width
                 optimizer._quant_explicit = True
-        self._donate = donate and jax.default_backend() != "cpu"
+        self._donate = donate
         # -- numerical guardrails (utils/train_guard.py): the in-graph
         # sentinel + skip masking engage unless PADDLE_GUARD_MODE=off;
         # the guard-policy counters ride the program as a small f32
@@ -704,11 +727,8 @@ class TrainStep:
             return None
         from ..observability import mfu as _mfu
 
-        try:
-            lowered = self._jitted.lower(*self._lower_avals)
-        except Exception:  # noqa: BLE001 — accounting stays best-effort
-            return None
-        self._flops = _mfu.flops_of_lowered(lowered)
+        self._flops = _mfu.flops_of_lowered(
+            self._jitted.lower(*self._lower_avals))
         return self._flops
 
     def mfu_pct(self, step_seconds: float):
@@ -788,6 +808,10 @@ class TrainStep:
                 p_raws, b_raws, key, in_raws, label_raws
             )
         if self._jitted is None:
+            (p_raws, opt_state, b_raws, self._scaler_state,
+             self._guard_state) = _commit_on_one_device(
+                (p_raws, opt_state, b_raws, self._scaler_state,
+                 self._guard_state))
             # pin state outputs to their input shardings — EXCEPT what the
             # ZeRO strategy intentionally reshards (stage>=1 shards the
             # optimizer state inside the update, stage 3 the params):

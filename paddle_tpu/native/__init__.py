@@ -6,17 +6,17 @@ pybind `core._convert_to_tensor_list`) — the parts of the reference's
 native runtime that remain load-bearing on a TPU host, where XLA/PJRT
 owns device memory and compute.
 
-The library builds lazily with the system g++ into a per-version cached
-shared object (the build-at-first-use model of the reference's JIT
-op-compilation, fluid custom-op SDK). Every consumer must handle
+The library builds lazily with the system g++ into a source-hash-named
+shared object under `native/_build/` (the build-at-first-use model of the
+reference's JIT op-compilation, fluid custom-op SDK). Every consumer must handle
 `available() == False` (no toolchain) and fall back to numpy.
 """
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
-import tempfile
 import threading
 
 import numpy as np
@@ -24,6 +24,8 @@ import numpy as np
 __all__ = ["available", "stack_samples", "stack_u8_to_f32", "lib"]
 
 _SRC = os.path.join(os.path.dirname(__file__), "staging.cpp")
+#: fixed, git-ignored, inside the checkout
+_BUILD_DIR = os.path.join(os.path.dirname(__file__), "_build")
 _LOCK = threading.Lock()
 _LIB = None
 _TRIED = False
@@ -31,14 +33,16 @@ _DEFAULT_THREADS = min(8, os.cpu_count() or 1)
 
 
 def _build() -> str:
-    cache = os.path.join(
-        tempfile.gettempdir(),
-        f"paddle_tpu_native_{os.getuid()}",
-    )
-    os.makedirs(cache, exist_ok=True)
-    so = os.path.join(cache, "libptstaging_v1.so")
-    if os.path.exists(so) and os.path.getmtime(so) >= os.path.getmtime(_SRC):
+    """Compile staging.cpp into `_BUILD_DIR`, named by a hash of the
+    source: the artefact comes from the files of THIS checkout and
+    nothing else (an mtime test against a shared temp directory could
+    load another checkout's binary)."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    so = os.path.join(_BUILD_DIR, f"libptstaging_{digest}.so")
+    if os.path.exists(so):
         return so
+    os.makedirs(_BUILD_DIR, exist_ok=True)
     tmp = so + f".build{os.getpid()}"
     subprocess.run(
         ["g++", "-O3", "-march=native", "-shared", "-fPIC", "-std=c++17",

@@ -55,14 +55,11 @@ def decode_metrics_enabled() -> bool:
 def device_memory() -> Optional[dict]:
     """Allocator counters of the first local device (bytes_in_use /
     peak_bytes_in_use), or None when the backend doesn't report them
-    (CPU) or jax isn't up. A runtime bookkeeping query — no dispatch,
-    no device sync."""
-    try:
-        import jax
+    (the CPU client's ``memory_stats()`` is None). A runtime bookkeeping
+    query — no dispatch, no device sync."""
+    import jax
 
-        stats = jax.local_devices()[0].memory_stats()
-    except Exception:  # noqa: BLE001 — metrics stay best-effort
-        return None
+    stats = jax.local_devices()[0].memory_stats()
     if not stats:
         return None
     return {

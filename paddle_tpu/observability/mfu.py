@@ -45,21 +45,19 @@ PEAK_FLOPS = (
 
 
 def peak_flops() -> Optional[float]:
-    """Per-device peak FLOPs/s, or None when unknown (CPU CI without the
-    ``PADDLE_OBS_PEAK_FLOPS`` override — MFU is then not reported rather
-    than reported against a made-up number)."""
+    """Per-device peak FLOPs/s, or None when the device kind is not in
+    the table (CPU CI without the ``PADDLE_OBS_PEAK_FLOPS`` override —
+    MFU is then not reported rather than reported against a made-up
+    number). A backend that fails to initialize raises."""
     raw = os.environ.get(_PEAK_ENV, "").strip()
     if raw:
         try:
             return float(raw)
         except ValueError:
             pass
-    try:
-        import jax
+    import jax
 
-        kind = jax.local_devices()[0].device_kind.lower()
-    except Exception:  # noqa: BLE001
-        return None
+    kind = jax.local_devices()[0].device_kind.lower()
     for sub, peak in PEAK_FLOPS:
         if sub in kind:
             return peak

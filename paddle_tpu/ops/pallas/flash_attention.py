@@ -34,24 +34,28 @@ _NEG = -1e30
 
 
 
+_DIM_SEMANTICS = ("parallel", "arbitrary")
+
+
 def _tpu_params(*sem):
     """dimension_semantics hint: q/batch grid axes are parallel, the
     online-softmax k axis is sequential — lets Mosaic pipeline block
     fetches across grid steps (interpret mode ignores it)."""
     from jax.experimental.pallas import tpu as pltpu
 
-    try:
-        return pltpu.CompilerParams(dimension_semantics=tuple(sem))
-    except Exception:
-        return None
+    bad = [s for s in sem if s not in _DIM_SEMANTICS]
+    if bad:
+        raise ValueError(
+            f"dimension_semantics {bad} not in {_DIM_SEMANTICS}")
+    return pltpu.CompilerParams(dimension_semantics=tuple(sem))
 
 
 def _fwd_kernel_resident(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k,
                          causal, scale, seq_k, q_offset, kv_offset):
-    """Fast path for K/V that fit VMEM (~8MB): this head's FULL K/V are
-    resident and a fori_loop runs the online softmax — measured ~2.5x
-    faster than grid-streaming at S=2048 (no per-grid-step scratch
-    round-trips); the streaming kernel takes over beyond the VMEM budget.
+    """Fast path for K/V that fit VMEM (`_RESIDENT_KV_BYTES`): this
+    head's FULL K/V are resident and a fori_loop runs the online softmax
+    (no per-grid-step scratch round-trips); the streaming kernel takes
+    over beyond the VMEM budget.
     """
     from jax.experimental import pallas as pl
 
@@ -104,7 +108,13 @@ def _fwd_kernel_resident(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k,
     lse_ref[0] = jnp.broadcast_to(lse[:, None], lse_ref.shape[1:])
 
 
-_RESIDENT_KV_BYTES = 8 << 20
+#: K+V bytes of one head the resident forward admits. Mosaic double-
+#: buffers both blocks: compiled for v5e the kernel's scoped VMEM is
+#: 2 x (K+V) + ~0.75 MiB against the 16 MiB default limit, so 8 MiB was
+#: refused at D=128 in bf16 and f32 (16.5 / 16.75 MiB); 4 MiB compiles
+#: at D in {64, 128} in both dtypes and leaves room for the Q/O/lse
+#: blocks of a 256-row tile.
+_RESIDENT_KV_BYTES = 4 << 20
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,

@@ -32,14 +32,26 @@ import jax.numpy as jnp
 from .flash_attention import _tpu_params
 
 
-def _pick_block_r(R: int, dtype) -> int:
-    """Largest row tile from {512..floor} dividing R; bf16 sublanes pack
-    16 rows, so bf16 tiles stay multiples of 16. The kernels require the
-    tile to DIVIDE R (the grid would silently drop tail rows otherwise)
-    — callers that can't guarantee rows % floor == 0 must use the dense
-    path (`nn.functional.layer_norm` gates on exactly this)."""
+#: f32 working-set budget of one (block_r, D) tile. The add-LN forward
+#: holds four (block_r, D) blocks in the I/O dtype, double-buffered, plus
+#: a handful of f32 temporaries of the same extent; at 1 MiB per f32
+#: tile that is <= 8 + ~5 MiB, inside the 16 MiB scoped-VMEM default
+#: (a 512 x 1024 f32 tile measured 17.01 MiB and was refused).
+_TILE_F32_BYTES = 1 << 20
+
+
+def _pick_block_r(R: int, D: int, dtype) -> int:
+    """Largest power-of-two row tile dividing R whose f32 (tile, D)
+    working copy fits `_TILE_F32_BYTES` (at most 512 rows); bf16
+    sublanes pack 16 rows, so bf16 tiles stay multiples of 16. The
+    kernels require the tile to DIVIDE R (the grid would silently drop
+    tail rows otherwise) — callers that can't guarantee rows % floor == 0
+    must use the dense path (`nn.functional.layer_norm` gates on exactly
+    this)."""
     floor = 16 if dtype == jnp.bfloat16 else 8
     b = 512
+    while b > floor and b * D * 4 > _TILE_F32_BYTES:
+        b //= 2
     while b >= floor and R % b:
         b //= 2
     if b < floor or R % b:
@@ -98,17 +110,20 @@ def _ln_bwd_kernel(x_ref, w_ref, mu_ref, rs_ref, g_ref, dx_ref, dw_ref,
     dx_ref[...] = (
         rs[:, None] * (dxhat - m1[:, None] - xhat * m2[:, None])
     ).astype(dx_ref.dtype)
-    # per-row-block partial dgamma/dbeta; the cross-block sum is one tiny
-    # [n_blocks, D] reduce outside the kernel
-    dw_ref[...] = jnp.sum(g * xhat, axis=0)[None]
-    db_ref[...] = jnp.sum(g, axis=0)[None]
+    # per-row-block partial dgamma/dbeta, folded to ONE (8, D) sublane
+    # tile per block (an output block's second-minor dim must be a
+    # multiple of 8 — a (1, D) block does not lower); the cross-block sum
+    # is one tiny [8 * n_blocks, D] reduce outside the kernel
+    br, d = x.shape
+    dw_ref[...] = jnp.sum((g * xhat).reshape(br // 8, 8, d), axis=0)
+    db_ref[...] = jnp.sum(g.reshape(br // 8, 8, d), axis=0)
 
 
 def _ln_forward(x2d, w2d, b2d, eps, interpret):
     from jax.experimental import pallas as pl
 
     R, D = x2d.shape
-    br = _pick_block_r(R, x2d.dtype)
+    br = _pick_block_r(R, D, x2d.dtype)
     out, mu, rs = pl.pallas_call(
         functools.partial(_ln_fwd_kernel, eps=eps),
         out_shape=(
@@ -137,7 +152,7 @@ def _ln_backward(x2d, w2d, mu, rs, g2d, interpret):
     from jax.experimental import pallas as pl
 
     R, D = x2d.shape
-    br = _pick_block_r(R, x2d.dtype)
+    br = _pick_block_r(R, D, x2d.dtype)
     n = R // br
     mu128 = jnp.broadcast_to(mu[:, None], (R, 128))
     rs128 = jnp.broadcast_to(rs[:, None], (R, 128))
@@ -145,8 +160,8 @@ def _ln_backward(x2d, w2d, mu, rs, g2d, interpret):
         _ln_bwd_kernel,
         out_shape=(
             jax.ShapeDtypeStruct((R, D), x2d.dtype),
-            jax.ShapeDtypeStruct((n, D), jnp.float32),
-            jax.ShapeDtypeStruct((n, D), jnp.float32),
+            jax.ShapeDtypeStruct((8 * n, D), jnp.float32),
+            jax.ShapeDtypeStruct((8 * n, D), jnp.float32),
         ),
         grid=(n,),
         in_specs=[
@@ -158,8 +173,8 @@ def _ln_backward(x2d, w2d, mu, rs, g2d, interpret):
         ],
         out_specs=(
             pl.BlockSpec((br, D), lambda i: (i, 0)),
-            pl.BlockSpec((1, D), lambda i: (i, 0)),
-            pl.BlockSpec((1, D), lambda i: (i, 0)),
+            pl.BlockSpec((8, D), lambda i: (i, 0)),
+            pl.BlockSpec((8, D), lambda i: (i, 0)),
         ),
         compiler_params=_tpu_params("parallel"),
         interpret=interpret,
@@ -218,7 +233,7 @@ def _add_ln_forward(x, y, weight, bias, eps, interpret):
     x2d, shape = _flatten(x)
     y2d = y.reshape(x2d.shape)
     R, D = x2d.shape
-    br = _pick_block_r(R, x2d.dtype)
+    br = _pick_block_r(R, D, x2d.dtype)
     s, out, mu, rs = pl.pallas_call(
         functools.partial(_add_ln_fwd_kernel, eps=eps),
         out_shape=(

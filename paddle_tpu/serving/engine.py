@@ -1314,7 +1314,7 @@ class InferenceEngine:
         if self._insert_jitted is None:
             from ..observability import ledger as _ledger
 
-            donate = (0,) if jax.default_backend() != "cpu" else ()
+            donate = (0,)
             fn = _paged_insert_fn if self._pool is not None \
                 else _insert_fn
             self._insert_jitted = _ledger.instrument(
@@ -1356,7 +1356,7 @@ class InferenceEngine:
         if self._prefix_fetch_jitted is None:
             from ..observability import ledger as _ledger
 
-            donate = (1,) if jax.default_backend() != "cpu" else ()
+            donate = (1,)
             self._prefix_fetch_jitted = _ledger.instrument(
                 jax.jit(_prefix_fetch_fn, donate_argnums=donate),
                 label="PrefixFetch", donate=donate)
@@ -1378,7 +1378,7 @@ class InferenceEngine:
         if self._prefix_insert_jitted is None:
             from ..observability import ledger as _ledger
 
-            donate = (0,) if jax.default_backend() != "cpu" else ()
+            donate = (0,)
             self._prefix_insert_jitted = _ledger.instrument(
                 jax.jit(_paged_prefix_insert_fn, donate_argnums=donate),
                 label="CacheInsert", donate=donate)

@@ -170,7 +170,7 @@ class Executor:
             )
             return fetches, new_p, new_state, state_vals
 
-        donate = (0, 4) if jax.default_backend() != "cpu" else ()
+        donate = (0, 4)
         return (jax.jit(run_fn, donate_argnums=donate), leaves, params, opt,
                 rng_vars)
 

@@ -7,7 +7,6 @@ import os
 import sys
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-os.environ.setdefault("JAX_PLATFORM_NAME", "cpu")
 
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
