@@ -13,6 +13,10 @@ collectives, not comm rings.
 """
 from __future__ import annotations
 
+import time as _time
+
+_t_import = _time.perf_counter()
+
 # core first (no heavy deps)
 from .core import (  # noqa: F401
     CPUPlace,
@@ -95,3 +99,8 @@ from .batch import batch  # noqa: F401,E402
 from . import reader  # noqa: F401,E402
 from . import dataset  # noqa: F401,E402
 from . import tensor  # noqa: F401,E402
+
+#: wall seconds this package's own import took, jax included when this
+#: import was the first to pull it in: the part of a process's set-up
+#: that is spent before any program is built
+import_seconds = _time.perf_counter() - _t_import

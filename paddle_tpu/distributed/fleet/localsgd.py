@@ -106,10 +106,8 @@ class LocalSGDStep:
         # anything past two is a real miss worth a bus row.
         from ...observability import ledger as _ledger
 
-        self._jitted = _ledger.instrument(
-            jax.jit(self._step_fn, static_argnums=8),
-            label="LocalSGDStep",
-        )
+        self._jitted = _ledger.jit(self._step_fn, "LocalSGDStep",
+                                   static_argnums=8)
         self._n_steps = 0
         self._dirty = False
         # checkpoint consumers must see averaged weights: state_dict pulls

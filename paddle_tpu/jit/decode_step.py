@@ -256,11 +256,9 @@ class _CompiledDecodeBase:
     def _instrumented(self, donate, out_shardings):
         from ..observability import ledger as _ledger
 
-        return _ledger.instrument(
-            jax.jit(self._step_fn, donate_argnums=donate,
-                    out_shardings=out_shardings),
-            label=self._label, donate=donate,
-        )
+        return _ledger.jit(self._step_fn, self._label,
+                           donate_argnums=donate,
+                           out_shardings=out_shardings)
 
     @property
     def compiles(self) -> Optional[int]:
@@ -483,9 +481,8 @@ class MigrateInsert:
             from ..observability import ledger as _ledger
 
             donate = (0,) if self._donate else ()
-            self._jitted = _ledger.instrument(
-                jax.jit(self._step_fn, donate_argnums=donate),
-                label=self._label, donate=donate)
+            self._jitted = _ledger.jit(self._step_fn, self._label,
+                                       donate_argnums=donate)
         self._n_steps += 1
         return self._jitted(cache_raws, rows, slot, table_row, pos, tok,
                             done, temp, top_k, top_p, eos, budget,

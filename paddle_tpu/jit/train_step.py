@@ -790,124 +790,126 @@ class TrainStep:
     def _call_impl(self, inputs, labels=None):
         if self._delegate is not None:
             return self._delegate(inputs, labels)
-        opt = self.opt
-        in_raws = tuple(
-            x._data if isinstance(x, Tensor) else jnp.asarray(x)
-            for x in _as_list(inputs)
-        )
-        label_raws = tuple(
-            y._data if isinstance(y, Tensor) else jnp.asarray(y)
-            for y in _as_list(labels)
-        )
-        p_raws = tuple(p._data for p in self._p_objs)
-        opt_state = opt._functional_state(self._p_objs)
-        b_raws = tuple(b._data for b in self._b_objs)
-        key = rnd.next_key()
-        if self._used_mask is None:
-            self._used_mask = self._analyze_usage(
-                p_raws, b_raws, key, in_raws, label_raws
-            )
-        if self._jitted is None:
-            (p_raws, opt_state, b_raws, self._scaler_state,
-             self._guard_state) = _commit_on_one_device(
-                (p_raws, opt_state, b_raws, self._scaler_state,
-                 self._guard_state))
-            # pin state outputs to their input shardings — EXCEPT what the
-            # ZeRO strategy intentionally reshards (stage>=1 shards the
-            # optimizer state inside the update, stage 3 the params):
-            # those converge to their sharded form after one call instead
-            from jax.sharding import NamedSharding as _NS
-
-            def pin(tree):
-                # only NamedSharding leaves are pinned; single-device
-                # leaves (e.g. freshly made scalar counters) stay
-                # unconstrained — pinning them to device 0 conflicts
-                # with mesh-placed operands
-                return jax.tree_util.tree_map(
-                    lambda r: r.sharding
-                    if isinstance(getattr(r, "sharding", None), _NS)
-                    else None,
-                    tree,
-                )
-            stage = int(getattr(self.opt, "_sharding_stage", 0) or 0)
-            out_sh = (
-                None,                                    # loss
-                pin(p_raws) if stage < 3 else None,      # new_p
-                pin(opt_state) if stage < 1 else None,   # new_state
-                pin(b_raws),                             # new_b
-                None,                                    # outs
-                None,                                    # scaler_state
-                pin(self._guard_state),                  # guard_state
-            )
-            # params, opt state, buffers — and the loss-scaler state
-            # when dynamic scaling is on (replaced every step, same
-            # shape) — are donated so XLA updates them in place in HBM.
-            # The guard carry is NOT donated: the host monitor still
-            # holds the previous step's vector for its deferred read
-            # (observe()'s async prefetch), and donating it would
-            # invalidate that buffer the moment it is re-passed — a
-            # 40-byte array buys nothing from donation anyway.
-            donate = (0, 1, 2) if self._donate else ()
-            if self._donate and self._loss_scale_cfg is not None:
-                donate = donate + (6,)
-            from ..observability import ledger as _ledger
-
-            # the ledger wrapper turns every jit cache miss into a
-            # `recompile` bus record (arg fingerprint + compile seconds)
-            # — one integer compare per call on the hit path
-            self._jitted = _ledger.instrument(
-                jax.jit(
-                    self._step_fn,
-                    donate_argnums=donate,
-                    out_shardings=out_sh,
-                ),
-                label="TrainStep", donate=donate,
-            )
-        opt._step_count += 1
-        lr = jnp.asarray(opt.get_lr(), jnp.float32)
-        t = jnp.asarray(opt._step_count, jnp.float32)
-        inject = (_FI.consume_grad_action() if self._inject_enabled else 0)
-        if self._guard is not None:
-            self._guard.capture(key, in_raws, label_raws)
-        # observability per-step hooks (one int assign + one None check
-        # when nothing is armed): the bus step index events inherit, and
-        # the capture-on-anomaly trace window opens BEFORE the dispatch
-        # it is meant to cover
         from .. import profiler as _prof
         from ..observability import bus as _bus
 
-        self._n_steps += 1
-        _bus.set_step(self._n_steps)
-        _prof.step_boundary(self._n_steps)
-        call_args = (
-            p_raws, opt_state, b_raws, key, lr, t, self._scaler_state,
-            self._guard_state, jnp.asarray(inject, jnp.int32),
-            in_raws, label_raws,
-        )
-        if self._lower_avals is None:
-            # shape/dtype skeleton of the call signature, kept for the
-            # cost-analysis lowering (flops_per_step): donated buffers
-            # are invalidated after dispatch, avals hold no storage
-            self._lower_avals = jax.tree_util.tree_map(
-                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)
-                if hasattr(x, "shape") and hasattr(x, "dtype") else x,
-                call_args,
+        # three flat phases on the profiler's clock (`profiler.phase`):
+        # an idle gap of the device under a step reads as one of them
+        with _prof.phase("TrainStep.prepare"):
+            opt = self.opt
+            in_raws = tuple(
+                x._data if isinstance(x, Tensor) else jnp.asarray(x)
+                for x in _as_list(inputs)
             )
-        (loss, new_p, new_state, new_b, outs, self._scaler_state,
-         self._guard_state) = self._jitted(*call_args)
-        for p, raw in zip(self._p_objs, new_p):
-            p._data = raw
-            p._node = None
-            p.grad = None
-        opt._load_functional_state(self._p_objs, new_state)
-        for b, raw in zip(self._b_objs, new_b):
-            b._data = raw
-            b._node = None
-        if self._guard is not None:
-            # lazy, interval-synced policy read; on rollback the guard's
-            # _on_rollback hook (-> _after_rollback) has already
-            # refreshed the device carries
-            self._guard.observe(self._guard_state)
+            label_raws = tuple(
+                y._data if isinstance(y, Tensor) else jnp.asarray(y)
+                for y in _as_list(labels)
+            )
+            p_raws = tuple(p._data for p in self._p_objs)
+            opt_state = opt._functional_state(self._p_objs)
+            b_raws = tuple(b._data for b in self._b_objs)
+            key = rnd.next_key()
+            if self._used_mask is None:
+                self._used_mask = self._analyze_usage(
+                    p_raws, b_raws, key, in_raws, label_raws
+                )
+            if self._jitted is None:
+                (p_raws, opt_state, b_raws, self._scaler_state,
+                 self._guard_state) = _commit_on_one_device(
+                    (p_raws, opt_state, b_raws, self._scaler_state,
+                     self._guard_state))
+                # pin state outputs to their input shardings — EXCEPT what
+                # the ZeRO strategy intentionally reshards (stage>=1 shards
+                # the optimizer state inside the update, stage 3 the
+                # params): those converge to their sharded form after one
+                # call instead
+                from jax.sharding import NamedSharding as _NS
+
+                def pin(tree):
+                    # only NamedSharding leaves are pinned; single-device
+                    # leaves (e.g. freshly made scalar counters) stay
+                    # unconstrained — pinning them to device 0 conflicts
+                    # with mesh-placed operands
+                    return jax.tree_util.tree_map(
+                        lambda r: r.sharding
+                        if isinstance(getattr(r, "sharding", None), _NS)
+                        else None,
+                        tree,
+                    )
+                stage = int(getattr(self.opt, "_sharding_stage", 0) or 0)
+                out_sh = (
+                    None,                                    # loss
+                    pin(p_raws) if stage < 3 else None,      # new_p
+                    pin(opt_state) if stage < 1 else None,   # new_state
+                    pin(b_raws),                             # new_b
+                    None,                                    # outs
+                    None,                                    # scaler_state
+                    pin(self._guard_state),                  # guard_state
+                )
+                # params, opt state, buffers — and the loss-scaler state
+                # when dynamic scaling is on (replaced every step, same
+                # shape) — are donated so XLA updates them in place in HBM.
+                # The guard carry is NOT donated: the host monitor still
+                # holds the previous step's vector for its deferred read
+                # (observe()'s async prefetch), and donating it would
+                # invalidate that buffer the moment it is re-passed — a
+                # 40-byte array buys nothing from donation anyway.
+                donate = (0, 1, 2) if self._donate else ()
+                if self._donate and self._loss_scale_cfg is not None:
+                    donate = donate + (6,)
+                from ..observability import ledger as _ledger
+
+                # the ledger wrapper turns every jit cache miss into a
+                # `recompile` bus record (arg fingerprint + compile seconds)
+                # — one integer compare per call on the hit path — and
+                # names the module `jit_TrainStep` in a device trace
+                self._jitted = _ledger.jit(self._step_fn, "TrainStep",
+                                           donate_argnums=donate,
+                                           out_shardings=out_sh)
+            opt._step_count += 1
+            lr = jnp.asarray(opt.get_lr(), jnp.float32)
+            t = jnp.asarray(opt._step_count, jnp.float32)
+            inject = (_FI.consume_grad_action() if self._inject_enabled else 0)
+            if self._guard is not None:
+                self._guard.capture(key, in_raws, label_raws)
+            # observability per-step hooks (one int assign + one None check
+            # when nothing is armed): the bus step index events inherit, and
+            # the capture-on-anomaly trace window opens BEFORE the dispatch
+            # it is meant to cover
+            self._n_steps += 1
+            _bus.set_step(self._n_steps)
+            _prof.step_boundary(self._n_steps)
+            call_args = (
+                p_raws, opt_state, b_raws, key, lr, t, self._scaler_state,
+                self._guard_state, jnp.asarray(inject, jnp.int32),
+                in_raws, label_raws,
+            )
+            if self._lower_avals is None:
+                # shape/dtype skeleton of the call signature, kept for the
+                # cost-analysis lowering (flops_per_step): donated buffers
+                # are invalidated after dispatch, avals hold no storage
+                self._lower_avals = jax.tree_util.tree_map(
+                    lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)
+                    if hasattr(x, "shape") and hasattr(x, "dtype") else x,
+                    call_args,
+                )
+        with _prof.phase("TrainStep.dispatch"):
+            (loss, new_p, new_state, new_b, outs, self._scaler_state,
+             self._guard_state) = self._jitted(*call_args)
+        with _prof.phase("TrainStep.rebind"):
+            for p, raw in zip(self._p_objs, new_p):
+                p._data = raw
+                p._node = None
+                p.grad = None
+            opt._load_functional_state(self._p_objs, new_state)
+            for b, raw in zip(self._b_objs, new_b):
+                b._data = raw
+                b._node = None
+            if self._guard is not None:
+                # lazy, interval-synced policy read; on rollback the guard's
+                # _on_rollback hook (-> _after_rollback) has already
+                # refreshed the device carries
+                self._guard.observe(self._guard_state)
         loss_t = Tensor._wrap(loss, stop_gradient=True)
         if self._ret_out:
             outs_t = jax.tree_util.tree_map(

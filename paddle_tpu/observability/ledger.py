@@ -32,9 +32,16 @@ the bus as ``backend_compile`` rows with their true compile seconds.
 ``compile_count()`` is the process-wide miss total — ``bench.py``
 records it per round so compile-count drift is tracked next to the
 compile-time drift table (report-only, tools/bench_continuity.py).
+``compile_seconds()`` is the wall time of those compiling calls, summed:
+the part of a process's set-up that went into compiling its steps.
+
+:func:`jit` is the one way a compiled step is made: it names the function
+by its ledger label before jitting it, so the label on a ``recompile``
+row and the module a device trace shows (``jit_<label>``) are one string.
 """
 from __future__ import annotations
 
+import functools
 import os
 import time
 from typing import Dict, List, Optional, Tuple
@@ -42,14 +49,15 @@ from typing import Dict, List, Optional, Tuple
 from . import bus
 
 __all__ = [
-    "arg_fingerprint", "diff_fingerprints", "instrument",
-    "LedgeredFunction", "compile_count", "install_backend_listener",
-    "reset",
+    "arg_fingerprint", "diff_fingerprints", "instrument", "jit",
+    "LedgeredFunction", "compile_count", "compile_seconds",
+    "install_backend_listener", "reset",
 ]
 
 _STORM_ENV = "PADDLE_OBS_STORM_N"
 
 _total_compiles = 0
+_total_compile_s = 0.0
 _listener_installed = False
 
 
@@ -58,10 +66,17 @@ def compile_count() -> int:
     return _total_compiles
 
 
+def compile_seconds() -> float:
+    """Process-wide wall seconds of the calls :func:`compile_count`
+    counts (trace + lower + compile + that call's dispatch)."""
+    return _total_compile_s
+
+
 def reset() -> None:
-    """Tests: zero the process-wide counter."""
-    global _total_compiles
+    """Tests: zero the process-wide counters."""
+    global _total_compiles, _total_compile_s
     _total_compiles = 0
+    _total_compile_s = 0.0
 
 
 def _leaf_sig(x) -> str:
@@ -165,9 +180,10 @@ class LedgeredFunction:
         return out
 
     def _on_compile(self, fp, wall_s: float) -> None:
-        global _total_compiles
+        global _total_compiles, _total_compile_s
         self.compiles += 1
         _total_compiles += 1
+        _total_compile_s += wall_s
         changed = (diff_fingerprints(self._prev_fp, fp)
                    if self._prev_fp is not None and fp is not None else [])
         if bus.enabled():
@@ -195,6 +211,24 @@ class LedgeredFunction:
 def instrument(jitted, label: str, donate=()) -> LedgeredFunction:
     """Wrap one jitted callable so its cache misses feed the ledger."""
     return LedgeredFunction(jitted, label, donate)
+
+
+def jit(fn, label: str, *, donate_argnums=(),
+        **jit_kwargs) -> LedgeredFunction:
+    """``jax.jit`` of ``fn`` under the name ``label``, instrumented: the
+    compiled module is ``jit_<label>`` in HLO text and in a device trace,
+    and its cache misses are ``recompile`` rows with the same label.
+    Keywords are ``jax.jit``'s own."""
+    import jax
+
+    @functools.wraps(fn)
+    def named(*args, **kwargs):
+        return fn(*args, **kwargs)
+
+    named.__name__ = named.__qualname__ = label
+    return instrument(
+        jax.jit(named, donate_argnums=donate_argnums, **jit_kwargs),
+        label=label, donate=donate_argnums)
 
 
 def install_backend_listener() -> None:

@@ -331,6 +331,7 @@ def _forward(q, k, v, *, causal, block_q, block_k, scale, interpret,
             ),
             compiler_params=_tpu_params("parallel", "parallel"),
             interpret=interpret,
+            name="flash_fwd",
         )(qr, kr, vr)
     else:
         n_k = Sk // block_k
@@ -363,6 +364,7 @@ def _forward(q, k, v, *, causal, block_q, block_k, scale, interpret,
             compiler_params=_tpu_params(
                 "parallel", "parallel", "arbitrary"),
             interpret=interpret,
+            name="flash_fwd",
         )(qr, kr, vr)
     out = out.reshape(B, H, S, D)
     lse = lse[:, :, 0].reshape(B, H, S)
@@ -513,6 +515,7 @@ def _backward_with_delta(q, k, v, g, lse, delta, *, causal, block_q,
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         compiler_params=_tpu_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
+        name="flash_dq",
     )(qr, kr, vr, dor, lse128, delta128)
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, n_q=n_q, **common),
@@ -539,6 +542,7 @@ def _backward_with_delta(q, k, v, g, lse, delta, *, causal, block_q,
         ],
         compiler_params=_tpu_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
+        name="flash_dkv",
     )(kr, vr, qr, dor, lse128, delta128)
     return (
         dq.reshape(B, H, S, D),

@@ -144,6 +144,7 @@ def _ln_forward(x2d, w2d, b2d, eps, interpret):
         ),
         compiler_params=_tpu_params("parallel"),
         interpret=interpret,
+        name="ln_fwd",
     )(x2d, w2d, b2d)
     return out, mu[:, 0], rs[:, 0]
 
@@ -178,6 +179,7 @@ def _ln_backward(x2d, w2d, mu, rs, g2d, interpret):
         ),
         compiler_params=_tpu_params("parallel"),
         interpret=interpret,
+        name="ln_bwd",
     )(x2d, w2d, mu128, rs128, g2d)
     return dx, dwp.sum(axis=0), dbp.sum(axis=0)
 
@@ -257,6 +259,7 @@ def _add_ln_forward(x, y, weight, bias, eps, interpret):
         ),
         compiler_params=_tpu_params("parallel"),
         interpret=interpret,
+        name="ln_residual_fwd",
     )(x2d, y2d, weight.reshape(1, -1), bias.reshape(1, -1))
     return (s.reshape(shape), out.reshape(shape), mu[:, 0], rs[:, 0])
 

@@ -14,6 +14,15 @@ table and on the device timeline; `start_profiler`/`stop_profiler` wrap
 DeviceTracer analog, produced by libtpu rather than CUPTI). Op dispatch
 and TrainStep carry RecordEvent hooks that cost one module-flag check
 when profiling is off.
+
+`phase(name, **meta)` is the ungated primitive under `RecordEvent`: a bare
+`TraceAnnotation`, entered whether or not `start_profiler()` was called, so
+it lands in whatever profiler session is open (the benchmark harness starts
+`jax.profiler` itself) on the device trace's clock, and costs about a
+microsecond when none is. It marks work done once a turn, a request or a
+step: the `engine.*` phases of `InferenceEngine.turn` and the `TrainStep.*`
+phases of `TrainStep.__call__`, flat siblings that never nest, so an idle
+gap of the device reads as the one phase the host was in.
 """
 from __future__ import annotations
 
@@ -24,7 +33,7 @@ import time
 from typing import Dict, List, Optional
 
 __all__ = [
-    "RecordEvent", "record_event", "start_profiler", "stop_profiler",
+    "RecordEvent", "record_event", "phase", "start_profiler", "stop_profiler",
     "profiler", "is_profiling", "event_summary", "reset_profiler",
     "device_annotation", "arm_trace", "disarm_trace", "step_boundary",
     "trace_window_state",
@@ -47,6 +56,16 @@ def is_profiling() -> bool:
     return _enabled
 
 
+def phase(name: str, **meta):
+    """A host span named ``name`` in the open profiler session, if any;
+    ``meta`` (``rid=``, ``slot=``) arrives as the event's stats and leaves
+    its name bare. Not gated by :func:`start_profiler` and not recorded in
+    :func:`event_summary`: per-op events use :class:`RecordEvent`."""
+    import jax
+
+    return jax.profiler.TraceAnnotation(name, **meta)
+
+
 class RecordEvent:
     """RAII event annotation (profiler.h:127). Usable as a context manager
     or decorator; nests; no-op (one flag check) when profiling is off."""
@@ -58,9 +77,7 @@ class RecordEvent:
 
     def __enter__(self):
         if _enabled:
-            import jax
-
-            self._ann = jax.profiler.TraceAnnotation(self.name)
+            self._ann = phase(self.name)
             self._ann.__enter__()
             self._t0 = time.perf_counter()
         return self

@@ -73,6 +73,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from .. import profiler as _prof
 from ..jit.decode_step import (
     NO_BUDGET, DecodeState, DecodeStep, PrefillStep, SpecDecodeState,
     SpeculativeDecodeStep, spec_k_default,
@@ -1035,47 +1036,52 @@ class InferenceEngine:
         window = self._window()
         t0 = time.perf_counter()
         emits = []
-        for _ in range(window):
-            emit, _, self._state = self._decode(self._state)
-            emits.append(emit)
+        with _prof.phase("engine.decode_dispatch"):
+            for _ in range(window):
+                emit, _, self._state = self._decode(self._state)
+                emits.append(emit)
         # THE readback: one stacked token transfer + the done mask
         # per window — the only recurring device->host reads in the
         # serving loop (decode_metrics rides exactly this cadence)
-        tok_block = np.asarray(jnp.stack(emits, axis=0))
-        done = np.asarray(self._state.done)
+        with _prof.phase("engine.readback"):
+            tok_block = np.asarray(jnp.stack(emits, axis=0))
+            done = np.asarray(self._state.done)
         dt = time.perf_counter() - t0
-        # decode-window span for traced requests: emitted on the
-        # SAME readback cadence (host values only, zero new reads)
-        self._metrics.window_span(
-            [s.req.trace_id for s in self._active.values()],
-            steps=window)
-        self._collect(tok_block, done, results)
-        if self._retiring:
-            # relocate in-flight work off the retiring tail first (the
-            # ISSUE-17 fast path), THEN try the truncation it unblocks
-            self._relocate_retiring()
-            self._maybe_shrink()  # a freed retiring tail truncates here
-        ttfts, self._ttft_window = self._ttft_window, []
-        self._metrics.window(
-            steps=window, tokens=int((tok_block >= 0).sum()),
-            wall_s=dt, inflight=len(self._active),
-            queue_depth=len(self._queue),
-            ttft_ms=ttfts,
-            blocks_in_use=(None if self._pool is None
-                           else self._pool.in_use),
-            blocks_total=(None if self._pool is None
-                          else self._pool.total),
-            blocks_freed=(None if self._pool is None
-                          else self._pool.freed_total),
-            admit_deferred=self._admit_deferred,
-            prefix_hits=(None if self._prefix is None
-                         else self._prefix_hits),
-            prefix_blocks_shared=(None if self._prefix is None
-                                  else self._prefix_blocks_shared),
-            cow_copies=(None if self._prefix is None
-                        else self._cow_copies),
-            adapters_resident=(None if self.adapters is None
-                               else len(self.adapters.resident)))
+        with _prof.phase("engine.collect"):
+            # decode-window span for traced requests: emitted on the
+            # SAME readback cadence (host values only, zero new reads)
+            self._metrics.window_span(
+                [s.req.trace_id for s in self._active.values()],
+                steps=window)
+            self._collect(tok_block, done, results)
+        with _prof.phase("engine.turn_tail"):
+            if self._retiring:
+                # relocate in-flight work off the retiring tail first
+                # (the ISSUE-17 fast path), THEN try the truncation it
+                # unblocks
+                self._relocate_retiring()
+                self._maybe_shrink()  # a freed retiring tail truncates
+            ttfts, self._ttft_window = self._ttft_window, []
+            self._metrics.window(
+                steps=window, tokens=int((tok_block >= 0).sum()),
+                wall_s=dt, inflight=len(self._active),
+                queue_depth=len(self._queue),
+                ttft_ms=ttfts,
+                blocks_in_use=(None if self._pool is None
+                               else self._pool.in_use),
+                blocks_total=(None if self._pool is None
+                              else self._pool.total),
+                blocks_freed=(None if self._pool is None
+                              else self._pool.freed_total),
+                admit_deferred=self._admit_deferred,
+                prefix_hits=(None if self._prefix is None
+                             else self._prefix_hits),
+                prefix_blocks_shared=(None if self._prefix is None
+                                      else self._prefix_blocks_shared),
+                cow_copies=(None if self._prefix is None
+                            else self._cow_copies),
+                adapters_resident=(None if self.adapters is None
+                                   else len(self.adapters.resident)))
         return bool(self._queue or self._active or self._pending)
 
     # -- internals ---------------------------------------------------------
@@ -1093,10 +1099,11 @@ class InferenceEngine:
         self._key, sub = jax.random.split(self._key)
         return sub
 
-    def _slot_cache(self):
+    def _slot_cache(self, req, slot):
         """A CONTIGUOUS batch-1 cache for one request's prefill (the
         pool may be paged; the splice re-blocks it)."""
-        return self.model.gen_cache(1, self.max_length, block_size=0)
+        with _prof.phase("engine.slot_cache", rid=req.rid, slot=slot):
+            return self.model.gen_cache(1, self.max_length, block_size=0)
 
     def _advance_prefills(self, results) -> None:
         """One chunk per pending prefill per engine turn: the chunked-
@@ -1106,22 +1113,24 @@ class InferenceEngine:
             job = self._pending[slot]
             C = self.prefill_chunk
             L = job.req.prefill_ids.size
-            t0 = time.perf_counter()
-            take = min(C, L - job.consumed)
-            chunk = np.zeros((1, C), np.int32)
-            chunk[0, :take] = job.req.prefill_ids[
-                job.consumed: job.consumed + take]
-            last, job.raws, _ = self._prefill(
-                job.raws, chunk, np.asarray([take], np.int32),
-                start=np.asarray([job.consumed], np.int32),
-                adapter=np.asarray([job.req.adapter], np.int32))
-            job.consumed += take
-            job.prefill_s += time.perf_counter() - t0
-            self._metrics.span(
-                "prefill_chunk", trace_id=job.req.trace_id,
-                rid=job.req.rid, slot=slot, consumed=job.consumed,
-                prompt_len=L,
-                chunk_ms=round((time.perf_counter() - t0) * 1e3, 3))
+            with _prof.phase("engine.prefill_chunk", rid=job.req.rid,
+                             slot=slot):
+                t0 = time.perf_counter()
+                take = min(C, L - job.consumed)
+                chunk = np.zeros((1, C), np.int32)
+                chunk[0, :take] = job.req.prefill_ids[
+                    job.consumed: job.consumed + take]
+                last, job.raws, _ = self._prefill(
+                    job.raws, chunk, np.asarray([take], np.int32),
+                    start=np.asarray([job.consumed], np.int32),
+                    adapter=np.asarray([job.req.adapter], np.int32))
+                job.consumed += take
+                job.prefill_s += time.perf_counter() - t0
+                self._metrics.span(
+                    "prefill_chunk", trace_id=job.req.trace_id,
+                    rid=job.req.rid, slot=slot, consumed=job.consumed,
+                    prompt_len=L,
+                    chunk_ms=round((time.perf_counter() - t0) * 1e3, 3))
             if job.consumed >= L:
                 del self._pending[slot]
                 self._activate(slot, job.req, job.raws, last,
@@ -1142,50 +1151,56 @@ class InferenceEngine:
             req = self._queue[0]
             blocks = None
             share = None
-            if self._pool is not None:
-                # prefix-cache admission (ISSUE 18): a matched prefix
-                # is taken by table reference, so the pool is charged
-                # only the UNSHARED block demand; when even that can't
-                # be covered, idle cached entries are evicted before
-                # the request defers
-                if self._prefix is not None:
-                    share = self._prefix.lookup(req.prefill_ids)
-                need = self.needed_blocks(req)
-                fresh_need = need - (0 if share is None
-                                     else len(share.ref_blocks))
-                blocks = self._pool.alloc(fresh_need)
-                if blocks is None and self._prefix is not None:
-                    self._prefix.evict_for(self._pool, fresh_need)
+            with _prof.phase("engine.admit", rid=req.rid, slot=slot):
+                if self._pool is not None:
+                    # prefix-cache admission (ISSUE 18): a matched
+                    # prefix is taken by table reference, so the pool is
+                    # charged only the UNSHARED block demand; when even
+                    # that can't be covered, idle cached entries are
+                    # evicted before the request defers
+                    if self._prefix is not None:
+                        share = self._prefix.lookup(req.prefill_ids)
+                    need = self.needed_blocks(req)
+                    fresh_need = need - (0 if share is None
+                                         else len(share.ref_blocks))
                     blocks = self._pool.alloc(fresh_need)
-                if blocks is None:
-                    # pool can't cover the head request: DEFER admission
-                    # (blocks come back when inflight work retires) —
-                    # head-of-line on purpose: skipping ahead would
-                    # starve long-context requests under load
-                    self._admit_deferred += 1
-                    break
-            self._queue.popleft()
-            progress = True
-            self._metrics.span(
-                "admit", trace_id=req.trace_id, rid=req.rid, slot=slot,
-                queue_wait_ms=(
-                    round((time.perf_counter() - req.t_submit) * 1e3, 3)
-                    if req.t_submit is not None else None))
+                    if blocks is None and self._prefix is not None:
+                        self._prefix.evict_for(self._pool, fresh_need)
+                        blocks = self._pool.alloc(fresh_need)
+                    if blocks is None:
+                        # pool can't cover the head request: DEFER
+                        # admission (blocks come back when inflight work
+                        # retires) — head-of-line on purpose: skipping
+                        # ahead would starve long-context requests under
+                        # load
+                        self._admit_deferred += 1
+                        break
+                self._queue.popleft()
+                progress = True
+                self._metrics.span(
+                    "admit", trace_id=req.trace_id, rid=req.rid,
+                    slot=slot,
+                    queue_wait_ms=(
+                        round((time.perf_counter() - req.t_submit) * 1e3,
+                              3)
+                        if req.t_submit is not None else None))
             if share is not None:
                 self._admit_shared(slot, req, share, blocks, results)
                 continue
             L = req.prefill_ids.size
             if self.prefill_chunk > 0 and L > self.prefill_chunk:
                 self._pending[slot] = _Pending(
-                    req, slot, blocks, self._slot_cache(),
+                    req, slot, blocks, self._slot_cache(req, slot),
                     time.perf_counter())
                 continue
             t0 = time.perf_counter()
-            bucket = bucket_for(L, self.max_length)
-            ids, lens = _pad_prompts([req.prefill_ids], bucket)
-            last, slot_raws, _ = self._prefill(
-                self._slot_cache(), ids, lens,
-                adapter=np.asarray([req.adapter], np.int32))
+            scratch = self._slot_cache(req, slot)
+            with _prof.phase("engine.prefill", rid=req.rid, slot=slot):
+                bucket = bucket_for(L, self.max_length)
+                ids, lens = _pad_prompts([req.prefill_ids], bucket)
+                last, slot_raws, _ = self._prefill(
+                    scratch, ids, lens,
+                    adapter=np.asarray([req.adapter], np.int32))
             self._activate(slot, req, slot_raws, last, blocks=blocks,
                            t_enq=t0,
                            prefill_ms=(time.perf_counter() - t0) * 1e3,
@@ -1215,38 +1230,40 @@ class InferenceEngine:
         copies the one colliding shared block copy-on-write first when
         the match covered the whole prompt."""
         t0 = time.perf_counter()
-        self._pool.ref(share.ref_blocks)
-        cow = share.cow_src is not None
-        table = list(share.ref_blocks) + list(fresh)
-        cow_src = share.cow_src if cow else 0
-        cow_dst = fresh[0] if cow else 0  # 0,0 = trash self-copy
-        row = np.zeros((self._nmax,), np.int32)
-        row[: len(table)] = table
-        row_j = jnp.asarray(row)
-        # the fetch reads the SOURCE chain (share.src_blocks) — the
-        # slot's table row is NOT it: on a full-prefix match its last
-        # shared logical block points at the private cow_dst, which
-        # holds garbage until the splice runs
-        srow = np.zeros((self._nmax,), np.int32)
-        srow[: len(share.src_blocks)] = share.src_blocks
-        raws = self._prefix_fetch(self._slot_cache(),
-                                  jnp.asarray(srow))
-        L = req.prefill_ids.size
-        tail_start = int(share.tail_start)
-        tail_len = L - tail_start
-        # the tail window writes start..start+W-1 and W must keep the
-        # write INSIDE the cache — dynamic_update_slice would clamp an
-        # overrunning start and silently trash prefix rows the same
-        # call's attention reads. bucket_for against the REMAINING
-        # capacity picks the smallest bucket that fits (or exactly the
-        # remainder), so the tail always prefills in ONE shot.
-        W = bucket_for(tail_len, self.max_length - tail_start)
-        ids = np.zeros((1, W), np.int32)
-        ids[0, :tail_len] = req.prefill_ids[tail_start:]
-        last, raws, _ = self._prefill(
-            raws, ids, np.asarray([tail_len], np.int32),
-            start=np.asarray([tail_start], np.int32),
-            adapter=np.asarray([req.adapter], np.int32))
+        scratch = self._slot_cache(req, slot)
+        with _prof.phase("engine.prefill", rid=req.rid, slot=slot):
+            self._pool.ref(share.ref_blocks)
+            cow = share.cow_src is not None
+            table = list(share.ref_blocks) + list(fresh)
+            cow_src = share.cow_src if cow else 0
+            cow_dst = fresh[0] if cow else 0  # 0,0 = trash self-copy
+            row = np.zeros((self._nmax,), np.int32)
+            row[: len(table)] = table
+            row_j = jnp.asarray(row)
+            # the fetch reads the SOURCE chain (share.src_blocks) — the
+            # slot's table row is NOT it: on a full-prefix match its last
+            # shared logical block points at the private cow_dst, which
+            # holds garbage until the splice runs
+            srow = np.zeros((self._nmax,), np.int32)
+            srow[: len(share.src_blocks)] = share.src_blocks
+            raws = self._prefix_fetch(scratch, jnp.asarray(srow))
+            L = req.prefill_ids.size
+            tail_start = int(share.tail_start)
+            tail_len = L - tail_start
+            # the tail window writes start..start+W-1 and W must keep
+            # the write INSIDE the cache — dynamic_update_slice would
+            # clamp an overrunning start and silently trash prefix rows
+            # the same call's attention reads. bucket_for against the
+            # REMAINING capacity picks the smallest bucket that fits (or
+            # exactly the remainder), so the tail always prefills in ONE
+            # shot.
+            W = bucket_for(tail_len, self.max_length - tail_start)
+            ids = np.zeros((1, W), np.int32)
+            ids[0, :tail_len] = req.prefill_ids[tail_start:]
+            last, raws, _ = self._prefill(
+                raws, ids, np.asarray([tail_len], np.int32),
+                start=np.asarray([tail_start], np.int32),
+                adapter=np.asarray([req.adapter], np.int32))
         first = self._prefix_insert(slot, req, raws, last, row_j,
                                     tail_start, L, cow_src, cow_dst)
         self._prefix_hits += 1
@@ -1300,50 +1317,63 @@ class InferenceEngine:
         self._state.caches = pk.retire_tables(self._state.caches, slot)
         self._pool.release(blocks)
 
+    def _first_token(self, slot, req, last):
+        """Sample the request's first token from its last prefill
+        logits (eager, on the device; `_read_first` brings it over)."""
+        with _prof.phase("engine.first_token", rid=req.rid, slot=slot):
+            sub = self._next_key()
+            return sampling.sample(
+                last, sub,
+                jnp.asarray([req.temperature], jnp.float32),
+                jnp.asarray([req.top_k], jnp.int32),
+                jnp.asarray([req.top_p], jnp.float32))
+
+    def _read_first(self, slot, req, first) -> int:
+        """The one blocking host read a request."""
+        with _prof.phase("engine.first_token_read", rid=req.rid,
+                         slot=slot):
+            return int(np.asarray(first)[0])
+
     def _insert(self, slot: int, req: Request, slot_raws, last,
                 blocks) -> int:
         """Splice one prefilled batch-1 cache into the pool slot.
         Returns its first generated token (the one per-request host
         read — per REQUEST, not per token)."""
-        sub = self._next_key()
-        first = sampling.sample(
-            last, sub,
-            jnp.asarray([req.temperature], jnp.float32),
-            jnp.asarray([req.top_k], jnp.int32),
-            jnp.asarray([req.top_p], jnp.float32))
-        if self._insert_jitted is None:
-            from ..observability import ledger as _ledger
+        first = self._first_token(slot, req, last)
+        with _prof.phase("engine.insert", rid=req.rid, slot=slot):
+            if self._insert_jitted is None:
+                from ..observability import ledger as _ledger
 
-            donate = (0,)
-            fn = _paged_insert_fn if self._pool is not None \
-                else _insert_fn
-            self._insert_jitted = _ledger.instrument(
-                jax.jit(fn, donate_argnums=donate, static_argnums=()),
-                label="CacheInsert", donate=donate)
-        st = self._state
-        L = req.prefill_ids.size
-        extra = ()
-        if self._pool is not None:
-            row = np.zeros((self._nmax,), np.int32)
-            row[: len(blocks)] = blocks  # trash-padded past allocation
-            extra = (jnp.asarray(row),)
-        (caches, pos, tok, done, temp, top_k, top_p, eos, budget,
-         adapter) = self._insert_jitted(
-            st.caches, slot_raws, jnp.asarray(slot, jnp.int32),
-            *extra,
-            st.pos, st.tok, st.done, st.temperature, st.top_k,
-            st.top_p, st.eos, st.budget, st.adapter,
-            jnp.asarray(L, jnp.int32),
-            first[0],
-            jnp.asarray(req.temperature, jnp.float32),
-            jnp.asarray(req.top_k, jnp.int32),
-            jnp.asarray(req.top_p, jnp.float32),
-            jnp.asarray(req.eos_id, jnp.int32),
-            jnp.asarray(req.max_new_tokens - 1, jnp.int32),
-            jnp.asarray(req.adapter, jnp.int32))
-        self._state = DecodeState(caches, pos, tok, done, st.key, temp,
-                                  top_k, top_p, eos, budget, adapter)
-        return int(np.asarray(first)[0])
+                donate = (0,)
+                fn = _paged_insert_fn if self._pool is not None \
+                    else _insert_fn
+                self._insert_jitted = _ledger.jit(fn, "CacheInsert",
+                                                  donate_argnums=donate)
+            st = self._state
+            L = req.prefill_ids.size
+            extra = ()
+            if self._pool is not None:
+                row = np.zeros((self._nmax,), np.int32)
+                row[: len(blocks)] = blocks  # trash-padded past allocation
+                extra = (jnp.asarray(row),)
+            (caches, pos, tok, done, temp, top_k, top_p, eos, budget,
+             adapter) = self._insert_jitted(
+                st.caches, slot_raws, jnp.asarray(slot, jnp.int32),
+                *extra,
+                st.pos, st.tok, st.done, st.temperature, st.top_k,
+                st.top_p, st.eos, st.budget, st.adapter,
+                jnp.asarray(L, jnp.int32),
+                first[0],
+                jnp.asarray(req.temperature, jnp.float32),
+                jnp.asarray(req.top_k, jnp.int32),
+                jnp.asarray(req.top_p, jnp.float32),
+                jnp.asarray(req.eos_id, jnp.int32),
+                jnp.asarray(req.max_new_tokens - 1, jnp.int32),
+                jnp.asarray(req.adapter, jnp.int32))
+            self._state = DecodeState(caches, pos, tok, done, st.key,
+                                      temp, top_k, top_p, eos, budget,
+                                      adapter)
+        return self._read_first(slot, req, first)
 
     def _prefix_fetch(self, scratch, table_row):
         """Materialize the shared-prefix blocks named by ``table_row``
@@ -1357,9 +1387,8 @@ class InferenceEngine:
             from ..observability import ledger as _ledger
 
             donate = (1,)
-            self._prefix_fetch_jitted = _ledger.instrument(
-                jax.jit(_prefix_fetch_fn, donate_argnums=donate),
-                label="PrefixFetch", donate=donate)
+            self._prefix_fetch_jitted = _ledger.jit(
+                _prefix_fetch_fn, "PrefixFetch", donate_argnums=donate)
         return self._prefix_fetch_jitted(self._state.caches, raws,
                                          table_row)
 
@@ -1369,40 +1398,37 @@ class InferenceEngine:
         in-graph CoW copy (`paged_kv.paged_splice_tail`) — positions
         below ``start`` stay in the refcounted shared blocks the table
         row references."""
-        sub = self._next_key()
-        first = sampling.sample(
-            last, sub,
-            jnp.asarray([req.temperature], jnp.float32),
-            jnp.asarray([req.top_k], jnp.int32),
-            jnp.asarray([req.top_p], jnp.float32))
-        if self._prefix_insert_jitted is None:
-            from ..observability import ledger as _ledger
+        first = self._first_token(slot, req, last)
+        with _prof.phase("engine.insert", rid=req.rid, slot=slot):
+            if self._prefix_insert_jitted is None:
+                from ..observability import ledger as _ledger
 
-            donate = (0,)
-            self._prefix_insert_jitted = _ledger.instrument(
-                jax.jit(_paged_prefix_insert_fn, donate_argnums=donate),
-                label="CacheInsert", donate=donate)
-        st = self._state
-        (caches, pos, tok, done, temp, top_k, top_p, eos, budget,
-         adapter) = self._prefix_insert_jitted(
-            st.caches, slot_raws, jnp.asarray(slot, jnp.int32),
-            table_row,
-            jnp.asarray(start, jnp.int32),
-            jnp.asarray(length, jnp.int32),
-            jnp.asarray(cow_src, jnp.int32),
-            jnp.asarray(cow_dst, jnp.int32),
-            st.pos, st.tok, st.done, st.temperature, st.top_k,
-            st.top_p, st.eos, st.budget, st.adapter,
-            first[0],
-            jnp.asarray(req.temperature, jnp.float32),
-            jnp.asarray(req.top_k, jnp.int32),
-            jnp.asarray(req.top_p, jnp.float32),
-            jnp.asarray(req.eos_id, jnp.int32),
-            jnp.asarray(req.max_new_tokens - 1, jnp.int32),
-            jnp.asarray(req.adapter, jnp.int32))
-        self._state = DecodeState(caches, pos, tok, done, st.key, temp,
-                                  top_k, top_p, eos, budget, adapter)
-        return int(np.asarray(first)[0])
+                donate = (0,)
+                self._prefix_insert_jitted = _ledger.jit(
+                    _paged_prefix_insert_fn, "CacheInsert",
+                    donate_argnums=donate)
+            st = self._state
+            (caches, pos, tok, done, temp, top_k, top_p, eos, budget,
+             adapter) = self._prefix_insert_jitted(
+                st.caches, slot_raws, jnp.asarray(slot, jnp.int32),
+                table_row,
+                jnp.asarray(start, jnp.int32),
+                jnp.asarray(length, jnp.int32),
+                jnp.asarray(cow_src, jnp.int32),
+                jnp.asarray(cow_dst, jnp.int32),
+                st.pos, st.tok, st.done, st.temperature, st.top_k,
+                st.top_p, st.eos, st.budget, st.adapter,
+                first[0],
+                jnp.asarray(req.temperature, jnp.float32),
+                jnp.asarray(req.top_k, jnp.int32),
+                jnp.asarray(req.top_p, jnp.float32),
+                jnp.asarray(req.eos_id, jnp.int32),
+                jnp.asarray(req.max_new_tokens - 1, jnp.int32),
+                jnp.asarray(req.adapter, jnp.int32))
+            self._state = DecodeState(caches, pos, tok, done, st.key,
+                                      temp, top_k, top_p, eos, budget,
+                                      adapter)
+        return self._read_first(slot, req, first)
 
     def poison_prefix(self, k: Optional[int] = None) -> bool:
         """Corrupt the ``k``-th oldest prefix-cache entry's key (the
