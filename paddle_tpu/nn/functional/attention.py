@@ -189,9 +189,9 @@ def _interpret_forced() -> bool:
 
 
 def _flash_block(s: int) -> int:
-    """Largest power-of-two tile <= 256 dividing s (kernel contract:
+    """Largest power-of-two tile <= 512 dividing s (kernel contract:
     S % block == 0)."""
-    b = 256
+    b = 512
     while b > 1 and s % b:
         b //= 2
     return b

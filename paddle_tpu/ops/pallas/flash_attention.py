@@ -5,18 +5,32 @@ path (reference gap: the CUDA side fuses attention via
 operators/fused/fused_attention pieces and math/bert_encoder_functor.cu —
 here the fusion is an explicit VMEM-resident online-softmax kernel).
 
-Round-5 design (VERDICT r4 missing #3 / weak #3):
+Design:
   - K/V STREAM through the grid: grid = (batch*heads, q blocks, k blocks)
     with the online-softmax state (acc, m, l) in VMEM scratch carried
     across the innermost k iterations. Per-program VMEM is
     O(block_q*D + 2*block_k*D) — sequence length is bounded by HBM, not
-    by the old full-KV-per-head VMEM residency (S ≤ 16k at D=128).
+    by VMEM. K/V of one head that fit `_RESIDENT_KV_BYTES` take the
+    resident forward instead (one grid step a q block, a fori_loop over
+    the key blocks).
   - the forward also emits the per-row logsumexp; backward is TWO Pallas
     kernels (FlashAttention-2 recompute form): a dq kernel streaming K/V
     per q block, and a dk/dv kernel streaming Q/dO per k block, both
-    using p = exp(s - lse) and delta = rowsum(dO * O).
+    using p = exp(s - lse) and delta = rowsum(dO * O); the dk/dv kernel
+    computes its tiles transposed (`_dkv_kernel`).
   - causal masking by global positions; fully-future blocks are skipped
     arithmetically (guarded compute) in fwd and bwd.
+
+Precision follows the input: every `dot_general` takes its stored tiles
+(q, k, v, dO) in the dtype the refs hold and accumulates in float32, so
+bf16 inputs are single-pass bf16 MXU matmuls and float32 inputs are the
+float32 matmuls they always were. The computed operands of the other
+four matmuls (p for P·V and Pᵀ·dO, ds for dS·K and dSᵀ·Q) are rounded to
+the stored dtype of the tile they meet just before the dot — a no-op for
+float32. Everything else is float32 whatever comes in: the scores s, the
+softmax state m, l and lse, delta, p, dp and ds as computed, the o carry
+and the acc / dk / dv accumulators; outputs are cast to the input dtype
+once, on the way out.
 
 `q_offset` / `kv_offset` shift the global positions — the seam ring
 attention uses to run this kernel on a rotated KV shard (its causal mask
@@ -50,6 +64,20 @@ def _tpu_params(*sem):
     return pltpu.CompilerParams(dimension_semantics=tuple(sem))
 
 
+def _dot(a, b, contract_a, contract_b):
+    """One MXU matmul of two tiles as they are stored, float32 out.
+    float32 tiles keep the ambient matmul precision (today's kernel);
+    for anything narrower a precision says nothing, and Mosaic refuses a
+    bf16 operand under an ambient `highest`, so those ask for the
+    default."""
+    both_f32 = a.dtype == b.dtype == jnp.float32
+    return jax.lax.dot_general(
+        a, b, (((contract_a,), (contract_b,)), ((), ())),
+        precision=None if both_f32 else jax.lax.Precision.DEFAULT,
+        preferred_element_type=jnp.float32,
+    )
+
+
 def _fwd_kernel_resident(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k,
                          causal, scale, seq_k, q_offset, kv_offset):
     """Fast path for K/V that fit VMEM (`_RESIDENT_KV_BYTES`): this
@@ -59,7 +87,7 @@ def _fwd_kernel_resident(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k,
     """
     from jax.experimental import pallas as pl
 
-    q = q_ref[0].astype(jnp.float32)              # [block_q, D]
+    q = q_ref[0]                                  # [block_q, D]
     block_q, d = q.shape
     qi = pl.program_id(1)
     q_pos = q_offset + qi * block_q + jax.lax.broadcasted_iota(
@@ -69,12 +97,9 @@ def _fwd_kernel_resident(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k,
 
     def body(j, carry):
         o, m, l = carry
-        k = k_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
+        k = k_ref[0, pl.ds(j * block_k, block_k), :]
+        v = v_ref[0, pl.ds(j * block_k, block_k), :]
+        s = _dot(q, k, 1, 1) * scale
         if causal:
             k_pos = kv_offset + j * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1
@@ -85,10 +110,7 @@ def _fwd_kernel_resident(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k,
         p = jnp.where(alive[:, None], jnp.exp(s - m_new[:, None]), 0.0)
         corr = jnp.where(alive, jnp.exp(m - m_new), 1.0)
         l_new = l * corr + p.sum(axis=1)
-        o_new = o * corr[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        o_new = o * corr[:, None] + _dot(p.astype(v.dtype), v, 1, 0)
         return o_new, m_new, l_new
 
     o = jnp.zeros((block_q, d), jnp.float32)
@@ -140,13 +162,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
 
     @pl.when(visible)
     def _step():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale                                   # [bq, bk]
+        q = q_ref[0]
+        k = k_ref[0]
+        v = v_ref[0]
+        s = _dot(q, k, 1, 1) * scale                # [bq, bk]
         if causal:
             q_pos = q_offset + qi * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
@@ -161,10 +180,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         p = jnp.where(alive[:, None], jnp.exp(s - m_new[:, None]), 0.0)
         corr = jnp.where(alive, jnp.exp(m_prev - m_new), 1.0)
         l_new = l_prev * corr + p.sum(axis=1)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        acc_ref[...] = (acc_ref[...] * corr[:, None]
+                        + _dot(p.astype(v.dtype), v, 1, 0))
         m_ref[...] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
         l_ref[...] = jnp.broadcast_to(l_new[:, None], l_ref.shape)
 
@@ -197,16 +214,13 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
     @pl.when(visible)
     def _step():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
+        q = q_ref[0]
+        k = k_ref[0]
+        v = v_ref[0]
+        do = do_ref[0]
         lse = lse_ref[0, :, 0]
         delta = delta_ref[0, :, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
+        s = _dot(q, k, 1, 1) * scale
         if causal:
             q_pos = q_offset + qi * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
@@ -216,15 +230,9 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         # masked entries must stay 0 even for fully-masked rows where
         # lse == _NEG too (exp(_NEG - _NEG) would be 1)
         p = jnp.where(s <= _NEG / 2, 0.0, jnp.exp(s - lse[:, None]))
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        dp = _dot(do, v, 1, 1)
         ds = p * (dp - delta[:, None]) * scale
-        acc_ref[...] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        acc_ref[...] += _dot(ds.astype(k.dtype), k, 1, 0)
 
     @pl.when(kj == n_k - 1)
     def _finalize():
@@ -234,6 +242,12 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 def _dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, dk_acc, dv_acc, *, block_q, block_k, n_q,
                 causal, scale, q_offset, kv_offset):
+    """Works on the TRANSPOSED tile [bk, bq] (sᵀ = K·Qᵀ), so pᵀ and dsᵀ
+    come out as computed and all four dots are plain: contracting dim 0
+    of both operands makes Mosaic transpose a whole [bq, bk] tile for
+    each of Pᵀ·dO and dSᵀ·Q. What is transposed instead is lse and
+    delta, [bq, 128] into rows [1, bq]: the same arrays the dq kernel
+    reads, so the program around the kernels is the one it was."""
     from jax.experimental import pallas as pl
 
     kj = pl.program_id(1)
@@ -253,36 +267,24 @@ def _dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(visible)
     def _step():
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        q = q_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0, :, 0]
-        delta = delta_ref[0, :, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale                                   # [bq, bk]
+        k = k_ref[0]
+        v = v_ref[0]
+        q = q_ref[0]
+        do = do_ref[0]
+        lse = lse_ref[0].T[:1]                      # [1, bq]
+        delta = delta_ref[0].T[:1]
+        st = _dot(k, q, 1, 1) * scale               # [bk, bq]
         if causal:
-            q_pos = q_offset + qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
             k_pos = kv_offset + kj * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(k_pos > q_pos, _NEG, s)
-        p = jnp.where(s <= _NEG / 2, 0.0, jnp.exp(s - lse[:, None]))
-        dv_acc[...] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                            # [bk, D]
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta[:, None]) * scale       # [bq, bk]
-        dk_acc[...] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+                jnp.int32, (block_k, block_q), 0)
+            q_pos = q_offset + qi * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, (block_k, block_q), 1)
+            st = jnp.where(k_pos > q_pos, _NEG, st)
+        pt = jnp.where(st <= _NEG / 2, 0.0, jnp.exp(st - lse))
+        dv_acc[...] += _dot(pt.astype(do.dtype), do, 1, 0)    # [bk, D]
+        dpt = _dot(v, do, 1, 1)
+        dst = pt * (dpt - delta) * scale
+        dk_acc[...] += _dot(dst.astype(q.dtype), q, 1, 0)
 
     @pl.when(qi == n_q - 1)
     def _finalize():

@@ -224,3 +224,129 @@ class TestOffsetCausal:
                                   0, 0)
         np.testing.assert_array_equal(np.asarray(out_old),
                                       np.asarray(out_new))
+
+
+# ---------------------------------------------------------------------------
+# the MXU operands follow the input dtype (ISSUE 28): bf16 tiles reach
+# dot_general unwidened; the softmax state and the accumulators are f32
+# ---------------------------------------------------------------------------
+
+#: chip_smoke.KERNEL_TOL_BF16 and its measure (max|a - ref| / max|ref|)
+_TOL_BF16 = 2e-2
+_DH = 64
+#: (Sq, Sk, q_offset): the aligned diagonal, and a decode-append suffix
+_BF16_SHAPES = {"aligned": (256, 256, 0), "append": (128, 256, 128)}
+
+
+def _flash_module():
+    import importlib
+
+    return importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+
+
+def _route(monkeypatch, route):
+    """The streaming forward takes over past `_RESIDENT_KV_BYTES`, which
+    no interpreter-sized K/V reaches: steer the budget, not the kernel."""
+    if route == "streaming":
+        monkeypatch.setattr(_flash_module(), "_RESIDENT_KV_BYTES", 0)
+
+
+@pytest.fixture(scope="module", ids="-".join, params=[
+    (route, shape) for route in ("resident", "streaming")
+    for shape in sorted(_BF16_SHAPES)])
+def bf16_errs(request):
+    """out/dq/dk/dv of the bf16 kernels against the dense f32 reference
+    at `highest` on the same bf16-rounded inputs; one run a (route, shape)."""
+    route, shape = request.param
+    Sq, Sk, q_off = _BF16_SHAPES[shape]
+    ks = jax.random.split(jax.random.PRNGKey(7), 4)
+    q, k, v, g = (
+        jax.random.normal(kk, (1, 2, s, _DH), jnp.float32)
+        .astype(jnp.bfloat16)
+        for kk, s in zip(ks, (Sq, Sk, Sk, Sq))
+    )
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, True, 128, 128, None, True,
+                               q_off, 0)
+
+    def dense(q, k, v):
+        return TestOffsetCausal()._dense_offset(q, k, v, q_off, 0)
+
+    with pytest.MonkeyPatch.context() as mp:
+        _route(mp, route)
+        out, vjp = jax.vjp(flash, q, k, v)
+        got = (out,) + vjp(g)
+    with jax.default_matmul_precision("highest"):
+        ref, rvjp = jax.vjp(
+            dense, *(a.astype(jnp.float32) for a in (q, k, v)))
+        want = (ref,) + rvjp(g.astype(jnp.float32))
+    errs = {}
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+        assert a.dtype == jnp.bfloat16, (name, a.dtype)
+        a, b = np.asarray(a, np.float32), np.asarray(b)
+        errs[name] = float(np.abs(a - b).max() / np.abs(b).max())
+    return errs
+
+
+@pytest.mark.parametrize("tensor", ["out", "dq", "dk", "dv"])
+def test_bf16_kernels_match_dense_f32(bf16_errs, tensor):
+    err = bf16_errs[tensor]
+    assert np.isfinite(err) and err < _TOL_BF16, (tensor, err)
+
+
+def _dots(jaxpr, out):
+    """Every dot_general under `jaxpr`, through pl.when's cond branches
+    and the resident forward's fori_loop."""
+    for e in jaxpr.eqns:
+        if e.primitive.name == "dot_general":
+            out.append(e)
+        for p in e.params.values():
+            for sub in (p if isinstance(p, (tuple, list)) else (p,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _dots(sub, out)
+    return out
+
+
+#: kernel -> its matmuls: QK^T and PV; QK^T, dOV^T, dSK; QK^T, P^TdO,
+#: dOV^T, dS^TQ
+_KERNEL_DOTS = {"flash_fwd": 2, "flash_dq": 3, "flash_dkv": 4}
+
+
+@pytest.mark.parametrize("kernel,route", [
+    ("flash_fwd", "resident"), ("flash_fwd", "streaming"),
+    ("flash_dq", "resident"), ("flash_dkv", "resident"),
+])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_dot_operands_follow_input_dtype(monkeypatch, dtype, kernel, route):
+    """bf16 in -> every MXU operand bf16, f32 in -> f32 (today's kernel:
+    every cast a no-op); the result is always f32. Under an ambient
+    `highest` the f32 dots keep it and the bf16 dots ask for the default:
+    Mosaic refuses a bf16 operand at fp32 contract precision."""
+    _route(monkeypatch, route)
+    x = jnp.zeros((1, 2, 256, _DH), dtype)
+    P = jax.lax.Precision
+    precision = P.DEFAULT if dtype == jnp.bfloat16 else P.HIGHEST
+
+    def f(q, k, v, g):
+        out, vjp = jax.vjp(
+            lambda a, b, c: flash_attention(a, b, c, True, 128, 128,
+                                            None, True), q, k, v)
+        return (out,) + vjp(g)
+
+    with jax.default_matmul_precision("highest"):
+        eqns = jax.make_jaxpr(f)(x, x, x, x).jaxpr.eqns
+    calls = [e for e in eqns if e.primitive.name == "pallas_call"
+             and e.params["name"] == kernel]
+    assert len(calls) == 1, [e.params["name"] for e in calls]
+    if kernel == "flash_fwd":      # the streaming grid adds the k axis
+        grid = calls[0].params["grid_mapping"].grid
+        assert len(grid) == (3 if route == "streaming" else 2), grid
+    dots = _dots(calls[0].params["jaxpr"], [])
+    assert len(dots) == _KERNEL_DOTS[kernel]
+    for e in dots:
+        assert [a.aval.dtype for a in e.invars] == [dtype, dtype], e
+        assert e.outvars[0].aval.dtype == jnp.float32, e
+        assert e.params["precision"] == (precision, precision), e

@@ -273,6 +273,28 @@ def test_flash_kernel_names_compiled_for_v5e(one_chip):
     assert got == {"flash_fwd", "flash_dq", "flash_dkv"}, got
 
 
+@pytest.mark.parametrize("seq,dtype", [
+    (1024, jnp.bfloat16),   # gpt2-medium.train, at the routed tile
+    (1024, jnp.float32),
+    (192, jnp.bfloat16),    # 64-row tiles: under a lane's 128
+], ids=["1024-bf16", "1024-f32", "192-bf16"])
+def test_flash_kernels_compile_for_v5e_at_routed_tiles(one_chip, seq, dtype):
+    """Mosaic takes the three kernels at the tile `_flash_block` routes,
+    under the suite's ambient `highest` matmul precision."""
+    from paddle_tpu.nn.functional.attention import _flash_block
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention
+
+    qkv = _aval(one_chip, 2, 16, seq, 64, dtype=dtype)
+    blk = _flash_block(seq)
+
+    def attn(q, k, v):
+        return flash_attention(q, k, v, True, blk, blk, None, False,
+                               0, 0).astype(jnp.float32).sum()
+
+    got = _custom_calls(jax.grad(attn, argnums=(0, 1, 2)), qkv, qkv, qkv)
+    assert len(got) == 3, got
+
+
 def test_layer_norm_kernel_names_compiled_for_v5e(one_chip):
     from paddle_tpu.ops.pallas.layer_norm import (fused_add_layer_norm,
                                                   fused_layer_norm)
