@@ -17,15 +17,23 @@ import time
 import numpy as np
 
 import checks
-import counters
 import device as device_mod
-import reference
+import find
 import tracing
 import traffic
-import weights
 
-#: how long past the close a due answer is waited for
+#: how long past the close a due answer is waited for; in a traced run
+#: counted from the return of the profiler's stop, which is the harness's
+#: own time and not the engine's (it took 25-35 s of the 60, PERF.md)
 _DRAIN_S = 60.0
+
+#: the mix's sizes under `--rehearse`
+TOY = {
+    "prompt_len": {"dist": "lognormal", "median": 24, "sigma": 0.7,
+                   "min": 4, "max": 64},
+    "output_len": {"dist": "lognormal", "median": 20, "sigma": 0.6,
+                   "min": 4, "max": 40},
+    "max_total": 128, "engine": {"slots": 4, "max_length": 128}}
 
 
 _T0 = time.perf_counter()     # `run` sets it to the process's start
@@ -39,22 +47,11 @@ def log(msg: str) -> None:
 def build(cfg: dict, mix: dict, seed: int):
     import paddle_tpu as paddle
     from paddle_tpu.distributed import comm
-    from paddle_tpu.serving import InferenceEngine, TransformerLM
+    from paddle_tpu.serving import InferenceEngine
 
-    if mix["weights"] != "float32":
-        raise ValueError("only float32 serving has run on this chip; a "
-                         "mix in another precision needs its own proof")
-    s = weights.sizes(cfg)
     paddle.seed(0)
     comm.set_hybrid_mesh(None)
-    lm = TransformerLM(s["vocab"], d_model=s["d"], num_heads=s["heads"],
-                       num_layers=s["layers"], max_position=s["positions"],
-                       dim_feedforward=s["ffn"])
-    lm.eval()
-    w = weights.make(cfg, seed)
-    for name, p in lm.named_parameters():
-        p._data = w[name].astype(p._data.dtype)
-    del w
+    lm = find.family(cfg).serving_model(cfg, mix, seed)
     # the mix's `engine` group is the engine's own keyword arguments:
     # slots and max_length always, and whichever of block_size,
     # pool_blocks, prefill_chunk, prefix_cache, sync_every a mix sets
@@ -104,7 +101,7 @@ def pump(engine, sched: list, seconds: float, *, withdraw_at_close: bool,
     turns = []       # (t_end, inflight, queue_depth, live_kv, tokens_total)
     finished_tokens = 0
     nxt = 0
-    closed = None
+    closed = drain_from = None
     t0 = clock()
     while True:
         now = clock() - t0
@@ -131,10 +128,12 @@ def pump(engine, sched: list, seconds: float, *, withdraw_at_close: bool,
                     if not toks and rec[rid_of[rid]]["first"] is None:
                         engine.cancel(rid)
                         rec[rid_of[rid]]["withdrawn"] = True
+            drain_from = closed
             if tracer is not None:
                 tracer.finish(now)
+                drain_from = clock() - t0
         busy = engine.queue_depth() or engine.inflight()
-        if closed is not None and (not busy or now > closed + _DRAIN_S):
+        if closed is not None and (not busy or now > drain_from + _DRAIN_S):
             break
         if not busy:
             wait = (sched[nxt]["due"] - now) if nxt < n else (seconds - now)
@@ -168,10 +167,11 @@ def pump(engine, sched: list, seconds: float, *, withdraw_at_close: bool,
         turns.append((t, engine.inflight() / slots, engine.queue_depth(),
                       live, finished_tokens + running))
     end = clock() - t0
-    return {"rec": rec, "turns": turns, "closed": closed, "end": end}
+    return {"rec": rec, "turns": turns, "closed": closed, "end": end,
+            "drain_s": end - drain_from}
 
 
-def summarize(p: dict, sizes: dict) -> dict:
+def summarize(p: dict, sizes: dict, kv_bytes_per_token: int) -> dict:
     """The pump's clocks -> the numbers the metric readers read."""
     rec, closed = p["rec"], p["closed"]
     offered = [r for r in rec if not r.get("withdrawn")]
@@ -209,8 +209,8 @@ def summarize(p: dict, sizes: dict) -> dict:
         dec_pairs += (m - 1) * n0 + m * (m - 1) // 2
     in_window = [t for t in p["turns"] if t[0] <= closed]
     return {
-        # float32 keys and values of the tokens the active slots hold
-        "live_kv_bytes_mean": counters.kv_bytes_per_token(sizes) * float(
+        # the cached bytes of the tokens the active slots hold
+        "live_kv_bytes_mean": kv_bytes_per_token * float(
             np.mean([t[3] for t in in_window])) if in_window else None,
         "offered": len(offered), "unfinished": unfinished, "wrong": wrong,
         "ttft_ms": ttft, "tpot_ms": tpot, "lateness_ms": late,
@@ -253,11 +253,12 @@ def gaps_of_sample(cfg, mix, seed, sched, rec, sample,
     tokens; how far below the reference's best each served token lies.
     Returns the program's numbers and, with `control`, the same numbers
     of the token that the lower precision puts first at each position."""
-    params = weights.make(cfg, seed, form="stacked")
+    family = find.family(cfg)
+    params = family.make(cfg, seed, form="stacked")
     cap = int(mix["engine"]["max_length"])
     g, c, s = [], [], []
     for i in sample:
-        gap, cgap, spread = reference.served_gaps(
+        gap, cgap, spread = family.served_gaps(
             params, sched[i]["prompt"], rec[i]["tokens"], cfg=cfg,
             pad_to=cap, control=control)
         g.append(gap), c.append(cgap), s.append(spread)
@@ -283,7 +284,8 @@ def run(cell, cfg, mix, args, device, t_start, control=None):
 
     global _T0
     _T0 = t_start
-    s = weights.sizes(cfg)
+    family = find.family(cfg)
+    s = family.sizes(cfg)
     sched = traffic.schedule(mix, args.seed, args.seconds, s["vocab"])
     log("imports and schedule done")
     prog = build(cfg, mix, args.seed)
@@ -292,16 +294,18 @@ def run(cell, cfg, mix, args, device, t_start, control=None):
     compiles = ledger.compile_count()
     log(f"set-up done: {len(sched)} requests, buckets {warmed['buckets']}")
     tracer = tracing.Tracer(args) if args.trace else None
-    over = mix["arrivals"]["process"] == "all_at_zero"
     setup_s = time.perf_counter() - t_start
-    p = pump(prog["engine"], sched, args.seconds, withdraw_at_close=over,
+    p = pump(prog["engine"], sched, args.seconds,
+             withdraw_at_close=traffic.withdraws_at_close(mix),
              tracer=tracer)
     compiled_in_window = ledger.compile_count() - compiles
     peak = device_mod.memory_peak(int(cell["chips"]))
     free(prog)
-    log(f"window and drain done after {p['end']:.1f} s"
-        + (f"; profiler start/stop took {tracer.stall_s}" if tracer else ""))
-    summ = summarize(p, s)
+    log(f"window and drain done after {p['end']:.1f} s, the drain "
+        f"{p['drain_s']:.1f} s of {_DRAIN_S:.0f}"
+        + (f", counted from the return of the profiler's stop; profiler "
+           f"start/stop took {tracer.stall_s}" if tracer else ""))
+    summ = summarize(p, s, family.kv_bytes_per_token(s))
     log(f"live K/V, mean over the window's turns: "
         f"{summ['live_kv_bytes_mean']} bytes")
     sample = pick_sample(p["rec"], int(mix["check_requests"]), args.seed)
@@ -365,7 +369,8 @@ def sweep(cell, cfg, mix, args, device) -> None:
     """Builder-only: offer each rate of `--sweep r1,r2,...` for
     `--seconds` to one engine and print what came of it: the knee is the
     highest rate the engine sustains without a growing backlog."""
-    s = weights.sizes(cfg)
+    family = find.family(cfg)
+    s = family.sizes(cfg)
     rates = [float(r) for r in args.sweep.split(",")]
     prog = build(cfg, mix, args.seed)
     top = dict(mix, arrivals={"process": "poisson", "rate_per_s": max(rates)})
@@ -378,7 +383,7 @@ def sweep(cell, cfg, mix, args, device) -> None:
                  withdraw_at_close=False)
         for rid in list(prog["engine"].progress()):
             prog["engine"].cancel(rid)     # what this rate left behind
-        summ = summarize(p, s)
+        summ = summarize(p, s, family.kv_bytes_per_token(s))
         at_close = [t for t in p["turns"] if t[0] <= p["closed"]]
         half = [t for t in at_close if t[0] >= p["closed"] / 2]
         q = lambda xs, f: float(np.percentile(xs, f)) if xs else None

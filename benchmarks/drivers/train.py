@@ -1,4 +1,4 @@
-"""The training cells: `jit.TrainStep` over `serving.TransformerLM`.
+"""The training cells: `jit.TrainStep` over the family's model.
 
 Set-up builds ONE compiled step with its state, drives it from the seed
 through its first `check_steps` steps through the window's own call and
@@ -16,16 +16,18 @@ import numpy as np
 
 import checks
 import device as device_mod
-import reference
+import find
 import tracing
 import traffic
-import weights
 
 #: steps in flight before the host waits for the oldest: keeps the device
 #: fed and gives every step an end on the host's clock
 _IN_FLIGHT = 2
 #: batches made before the window; the feed cycles if a window outlasts it
 _POOL = 512
+
+#: the mix's sizes under `--rehearse`
+TOY = {"batch": 4, "seq": 64}
 
 
 _T0 = time.perf_counter()     # `run` sets it to the process's start
@@ -37,17 +39,15 @@ def log(msg: str) -> None:
 
 
 def build(cfg: dict, mix: dict, seed: int):
-    """The program: model, optimizer and the compiled step, loaded with
-    the seed's weights. Returns a dict so that `free` can drop it all."""
+    """The program: the family's model, the optimizer and the compiled
+    step, loaded with the seed's weights. Returns a dict so that `free`
+    can drop it all."""
     import paddle_tpu as paddle
-    from paddle_tpu import nn, optimizer
+    from paddle_tpu import optimizer
     from paddle_tpu.distributed import comm, fleet
     from paddle_tpu.distributed.fleet import DistributedStrategy
     from paddle_tpu.jit import TrainStep
-    from paddle_tpu.ops.creation import arange
-    from paddle_tpu.serving import TransformerLM
 
-    s = weights.sizes(cfg)
     paddle.seed(0)
     strategy = DistributedStrategy()
     strategy.amp = mix["amp"] == "bfloat16"
@@ -55,76 +55,19 @@ def build(cfg: dict, mix: dict, seed: int):
     # fleet.init lets dp fill every visible device; a one-chip cell is a
     # one-chip program on any host
     comm.set_hybrid_mesh(None)
-    lm = TransformerLM(s["vocab"], d_model=s["d"], num_heads=s["heads"],
-                       num_layers=s["layers"], max_position=s["positions"],
-                       dim_feedforward=s["ffn"])
-
-    class Trunk(nn.Layer):
-        """The model's own parts up to the final LayerNorm: the head sits
-        in the loss, where the blockwise cross-entropy streams it."""
-
-        def __init__(self, lm):
-            super().__init__()
-            self.lm = lm
-
-        def forward(self, ids):
-            lm = self.lm
-            h = lm.embed(ids) + lm.pos_embed(
-                arange(int(ids.shape[1]), dtype="int64"))
-            for blk in lm.blocks:
-                h = blk(h)
-            return lm.ln_f(h)
-
-    w = weights.make(cfg, seed)
-    names = {}
-    for name, p in lm.named_parameters():
-        p._data = w[name].astype(p._data.dtype)
-        names[id(p)] = name
-    del w
-    trunk = Trunk(lm)
-    trunk.train()
+    model = find.family(cfg).training_model(cfg, mix, seed)
     o = mix["optimizer"]
     opt = fleet.distributed_optimizer(optimizer.AdamW(
         learning_rate=o["learning_rate"], beta1=o["beta1"], beta2=o["beta2"],
         epsilon=o["epsilon"], weight_decay=o["weight_decay"],
-        parameters=lm.parameters()))
-
-    def lm_loss(h, labels):
-        d = h.shape[-1]
-        return nn.functional.fused_linear_cross_entropy(
-            h.reshape([-1, d]), lm.head.weight, lm.head.bias,
-            labels.reshape([-1]))
-
-    step = TrainStep(trunk, lm_loss, opt)
-    return {"lm": lm, "step": step, "names": names}
+        parameters=model["parameters"]))
+    step = TrainStep(model["layer"], model["loss"], opt)
+    return {"model": model, "step": step}
 
 
-def assert_routes(prog: dict, cfg: dict, mix: dict, rehearse: bool) -> None:
-    """The cell's shapes must take the Pallas kernels, as chip_smoke
-    asserts: a cell that falls off the kernel path measures another
-    program."""
-    import jax.numpy as jnp
-
-    from paddle_tpu.nn.functional.attention import flash_plan
-    from paddle_tpu.nn.functional.norm import _fused_ln_route
-
-    s = weights.sizes(cfg)
-    plan = flash_plan(mix["seq"], mix["seq"], causal=True, mesh=None,
-                      batch=mix["batch"], heads=s["heads"])
-    blk = prog["lm"].blocks[0]
-    route = _fused_ln_route(
-        jnp.zeros((mix["batch"], mix["seq"], s["d"]), jnp.bfloat16),
-        (s["d"],), blk.ln1.weight, blk.ln1.bias, mesh=blk.mesh)
-    if plan is None or plan[0] != "plain":
-        raise RuntimeError(f"flash_plan is {plan}, the cell expects plain")
-    if route is None or route[0] is not rehearse:
-        raise RuntimeError(f"_fused_ln_route is {route}: the cell's "
-                           "LayerNorm is off the kernel path")
-
-
-def _norms(named: dict, base: dict = None) -> dict:
+def _norms(family, named: dict, base: dict = None) -> dict:
     """{leaf: L2 norm} of the arrays (or of their distance from `base`),
-    a fused QKV leaf as its three projections, in one jitted call."""
+    a fused leaf as the family splits it, in one jitted call."""
     import jax
     import jax.numpy as jnp
 
@@ -132,7 +75,7 @@ def _norms(named: dict, base: dict = None) -> dict:
         if ys is not None:
             xs = {n: x.astype(jnp.float32) - ys[n] for n, x in xs.items()}
         return {n: jnp.sqrt(jnp.sum(x.astype(jnp.float32) ** 2))
-                for n, x in weights.split_fused(xs).items()}
+                for n, x in family.split_fused(xs).items()}
 
     return {n: float(v) for n, v in jax.jit(f)(named, base).items()}
 
@@ -142,8 +85,9 @@ def first_steps(prog, call, feed, cfg, mix, seed):
     and read what is compared: each loss, the first gradient's norm a
     leaf (from AdamW's first moment after one step: m1 = (1 - beta1) g),
     and the norm of every leaf's change after the steps."""
+    family = find.family(cfg)
     step = prog["step"]
-    names = [prog["names"][id(p)] for p in step._p_objs]
+    names = [prog["model"]["names"][id(p)] for p in step._p_objs]
     losses, grad = [], None
     for i in range(int(mix["check_steps"])):
         losses.append(float(call(*feed(i)).numpy()))
@@ -151,9 +95,9 @@ def first_steps(prog, call, feed, cfg, mix, seed):
             m1 = step.opt._functional_state(step._p_objs)["moment1"]
             scale = 1.0 / (1.0 - mix["optimizer"]["beta1"])
             grad = {n: v * scale
-                    for n, v in _norms(dict(zip(names, m1))).items()}
-    delta = _norms({n: p._data for n, p in zip(names, step._p_objs)},
-                   weights.make(cfg, seed))
+                    for n, v in _norms(family, dict(zip(names, m1))).items()}
+    delta = _norms(family, {n: p._data for n, p in zip(names, step._p_objs)},
+                   family.make(cfg, seed))
     return {"losses": losses, "grad": grad, "delta": delta}
 
 
@@ -208,10 +152,11 @@ def run(cell, cfg, mix, args, device, t_start):
 
     global _T0
     _T0 = t_start
-    s = weights.sizes(cfg)
+    family = find.family(cfg)
+    s = family.sizes(cfg)
     log("imports done")
     prog = build(cfg, mix, args.seed)
-    assert_routes(prog, cfg, mix, args.rehearse)
+    family.assert_routes(prog["model"], cfg, mix, args.rehearse)
     log("program built")
     pool = traffic.train_batches(mix, args.seed, _POOL, s["vocab"])
     batches = [(pool[i, :, :-1], pool[i, :, 1:]) for i in range(_POOL)]
@@ -239,7 +184,7 @@ def run(cell, cfg, mix, args, device, t_start):
     del batches
     gc.collect()
 
-    ref_losses, ref_grad, ref_delta = reference.train_steps(
+    ref_losses, ref_grad, ref_delta = family.train_steps(
         cfg, args.seed, check_ids, mix["optimizer"])
     ref = {"losses": ref_losses, "grad": ref_grad, "delta": ref_delta}
     nums = checks.train_numbers(measured, ref)
@@ -267,7 +212,8 @@ def control(cell, cfg, mix, args) -> None:
     have to come out as not correct. Prints one JSON line a seed."""
     import json
 
-    s = weights.sizes(cfg)
+    family = find.family(cfg)
+    s = family.sizes(cfg)
     n = int(mix["check_steps"])
     pool = traffic.train_batches(mix, args.seed, n, s["vocab"])
     ids = [(np.asarray(pool[i, :, :-1]), np.asarray(pool[i, :, 1:]))
@@ -275,7 +221,7 @@ def control(cell, cfg, mix, args) -> None:
     opt = mix["optimizer"]
 
     def three(**kw):
-        l, g, d = reference.train_steps(cfg, args.seed, ids, opt, **kw)
+        l, g, d = family.train_steps(cfg, args.seed, ids, opt, **kw)
         return {"losses": l, "grad": g, "delta": d}
 
     ref = three()

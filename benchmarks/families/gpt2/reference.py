@@ -23,7 +23,7 @@ import json
 import jax
 import jax.numpy as jnp
 
-import weights as W
+from . import weights as W
 
 _HI = jax.lax.Precision.HIGHEST
 

@@ -1,6 +1,8 @@
 """What the per-metric readers share: percentiles, and the reduction from
 a traced run's kernels and programs to roofline and utilization shares.
-Every function returns None where it finds nothing to read."""
+What a count needs of the architecture it asks of `ctx["family"]`, the
+family of the cell's configuration. Every function returns None where it
+finds nothing to read."""
 from __future__ import annotations
 
 import numpy as np
@@ -29,7 +31,8 @@ def train_tokens_per_s(ctx):
 
 
 def mfu_train(ctx):
-    per_token = counters.train_flops_per_token(ctx["sizes"], ctx["seq"])
+    per_token = ctx["family"].train_flops_per_token(ctx["sizes"],
+                                                    ctx["seq"])
     return 100.0 * per_token * train_tokens_per_s(ctx) / (
         chips(ctx) * peak(ctx)["flops_per_s"])
 
@@ -56,9 +59,9 @@ def kernel_roofline(ctx, kernel):
     calls = (ctx.get("trace") or {}).get("kernels", {}).get(kernel)
     if not calls:
         return None
-    s, pk = ctx["sizes"], peak(ctx)
-    b, h = ctx["batch"], s["heads"]
-    seq, dh = ctx["seq"], s["d"] // s["heads"]
+    pk = peak(ctx)
+    b, h, seq, dh = ctx["family"].flash_call_shape(
+        ctx["sizes"], ctx["batch"], ctx["seq"])
     least = spent = 0.0
     for c in calls:
         rule = c["rule"]
@@ -88,7 +91,7 @@ def serve_tokens_per_s(ctx):
 
 def mfu_serve(ctx):
     sv = ctx["serve"]
-    flops = counters.serve_flops(ctx["sizes"], **sv["flops"])
+    flops = ctx["family"].serve_flops(ctx["sizes"], **sv["flops"])
     return 100.0 * flops / (sv["closed"] * chips(ctx)
                             * peak(ctx)["flops_per_s"])
 
@@ -118,5 +121,6 @@ def decode_hbm_roofline(ctx):
     live = [x[3] for x in ctx["pump"]["turns"] if on <= x[0] <= off]
     if not live:
         return None
-    nbytes = counters.decode_step_bytes(ctx["sizes"], float(np.mean(live)))
+    nbytes = ctx["family"].decode_step_bytes(ctx["sizes"],
+                                             float(np.mean(live)))
     return 100.0 * (nbytes / peak(ctx)["hbm_bytes_per_s"]) / (ms / 1e3)

@@ -7,23 +7,11 @@ and counters, and in a traced run the reduced trace under `ctx["trace"]`.
 """
 from __future__ import annotations
 
-import importlib.util
-import os
-
-_HERE = os.path.dirname(os.path.abspath(__file__))
+import find
 
 
 def reader(name: str):
-    path = os.path.join(_HERE, f"{name}.py")
-    if not os.path.exists(path):
-        raise FileNotFoundError(
-            f"BENCHMARK.json names the metric {name!r} but "
-            f"benchmarks/metrics/{name}.py does not exist")
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return find.load("metrics", name).read
 
 
 def read_all(entries: list, workload: str, ctx: dict) -> dict:

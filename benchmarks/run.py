@@ -9,8 +9,10 @@ path produced against the plain reference, and prints the contract's one
 JSON line last on standard output.
 
 Everything that belongs to one configuration, one traffic mix, one cell
-or one per-layer metric is a file found by its name in BENCHMARK.json;
-see README.md beside this file.
+or one per-layer metric is a file found by its name in BENCHMARK.json,
+and so is the code behind them (`find.py`): the family of a configuration,
+the driver of a mix's kind, the mix's generators; see README.md beside
+this file.
 
 Builder-only switches (the driver never passes them; none prints a result
 line): `--rehearse` runs the same control flow on the CPU at a toy size
@@ -34,17 +36,6 @@ ROOT = os.path.dirname(HERE)
 for _p in (HERE, ROOT):
     if _p not in sys.path:
         sys.path.insert(0, _p)
-
-#: toy sizes of the CPU rehearsal; widths here have no meaning
-_TOY_CFG = {"vocab_size": 503, "n_positions": 128, "n_ctx": 128,
-            "n_embd": 128, "n_layer": 2, "n_head": 4, "n_inner": 512}
-_TOY_TRAIN = {"batch": 4, "seq": 64}
-_TOY_SERVE = {
-    "prompt_len": {"dist": "lognormal", "median": 24, "sigma": 0.7,
-                   "min": 4, "max": 64},
-    "output_len": {"dist": "lognormal", "median": 20, "sigma": 0.6,
-                   "min": 4, "max": 40},
-    "max_total": 128, "engine": {"slots": 4, "max_length": 128}}
 
 
 def parse(argv):
@@ -76,26 +67,22 @@ def main(argv) -> int:
         os.environ.setdefault("PADDLE_FUSED_LN", "interpret")
     bench, cell = load_cell(args.workload)
     import device as device_mod
+    import find
     import traffic
     import weights
 
     cfg = weights.load_config(cell["config"])
     mix = traffic.load_mix(cell["traffic"])
-    if args.rehearse:
-        cfg.update(_TOY_CFG)
-        mix.update(_TOY_TRAIN if mix["kind"] == "train" else _TOY_SERVE)
     device = device_mod.require(int(cell["chips"]), args.rehearse)
 
     import checks
     import metrics as metric_readers
 
-    if mix["kind"] == "train":
-        import train as driver
-    elif mix["kind"] == "serve":
-        import serve as driver
-    else:
-        sys.exit(f"run.py: mix {mix['name']!r} has unknown kind "
-                 f"{mix['kind']!r}")
+    family = find.family(cfg)
+    driver = find.load("drivers", mix["kind"])
+    if args.rehearse:
+        cfg.update(family.TOY_CFG)
+        mix.update(driver.TOY)
     if args.control:
         driver.control(cell, cfg, mix, args)
         return 3
@@ -106,7 +93,8 @@ def main(argv) -> int:
     # run: {"ctx": what the readers read, "attempted", "failed",
     #       "compared": [...], "device": extra keys of `device`}
     ctx = run["ctx"]
-    ctx.update(cell=cell, cfg=cfg, mix=mix, device=device, bench=bench)
+    ctx.update(cell=cell, cfg=cfg, mix=mix, device=device, bench=bench,
+               family=family)
     which = "per_layer" if args.trace else "end_to_end"
     values = metric_readers.read_all(bench[which], cell["name"], ctx)
     compared = run["compared"]

@@ -14,9 +14,11 @@ import numpy as np
 import pytest
 
 import checks
-import reference as R
+import find
 import traffic
-import weights as W
+
+G = find.load("families", "gpt2")
+R, W = G.reference, G.weights
 
 CFG = {"vocab_size": 503, "n_positions": 128, "n_embd": 128, "n_layer": 4,
        "n_head": 4, "n_inner": 512, "layer_norm_epsilon": 1e-5,

@@ -52,13 +52,19 @@ def test_lengths_and_arrivals_inside_their_bounds():
     assert abs(med - chat["prompt_len"]["median"]) <= 4
 
 
-@pytest.mark.parametrize("change", [
-    {"arrivals": {"process": "bursty", "rate_per_s": 8.0, "burst": 4}},
-    {"prompt_len": {"dist": "fixed", "value": 64, "min": 16, "max": 768}},
-    {"order": "shuffled"},
+@pytest.mark.parametrize("change,error,says", [
+    ({"arrivals": {"process": "bursty", "rate_per_s": 8.0, "burst": 4}},
+     FileNotFoundError, r"generators/arrivals/bursty\.py"),
+    ({"prompt_len": {"dist": "fixed", "value": 64, "min": 16, "max": 768}},
+     FileNotFoundError, r"generators/lengths/fixed\.py"),
+    ({"schedule": "sessions"},
+     FileNotFoundError, r"generators/schedules/sessions\.py"),
+    ({"order": "shuffled"}, ValueError, "unknown order"),
 ])
-def test_a_mix_the_generator_does_not_know_is_refused(change):
-    with pytest.raises(ValueError, match="unknown"):
+def test_a_mix_the_generator_does_not_know_is_refused(change, error, says):
+    """A process, a distribution or a schedule with no file says which
+    file it looked for, so that a mix can bring it."""
+    with pytest.raises(error, match=says):
         T.schedule(dict(T.load_mix("chat"), **change), 1, 30, 50257)
 
 

@@ -5,14 +5,20 @@ Every seed gets the same multiset of prompt lengths, output lengths and
 arrival gaps — the stratified quantiles of the mix's distributions — with
 other token ids, and in another order unless the mix fixes the order, so
 that two seeds offer the same work and differ only in how it falls.
+
+The parts are found by the names the mix gives them (`find.load`): the
+arrival process, each length's distribution, and the schedule that puts
+them together. A mix that needs another brings it as a new file under
+`generators/`.
 """
 from __future__ import annotations
 
 import json
 import os
-import statistics
 
 import numpy as np
+
+import find
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 
@@ -25,57 +31,38 @@ def load_mix(name: str) -> dict:
     return mix
 
 
-def _quantiles(n: int) -> np.ndarray:
+def quantiles(n: int) -> np.ndarray:
+    """The middles of `n` equal strata of (0, 1): a generator that takes
+    its values at these gives every seed the same multiset."""
     return (np.arange(n) + 0.5) / n
 
 
 def lengths(spec: dict, n: int) -> np.ndarray:
-    """`n` lengths: the stratified quantiles of the distribution, clipped."""
-    if spec["dist"] != "lognormal":
-        raise ValueError(f"unknown length distribution {spec['dist']!r}")
-    nd = statistics.NormalDist()
-    z = np.array([nd.inv_cdf(q) for q in _quantiles(n)])
-    x = spec["median"] * np.exp(spec["sigma"] * z)
-    return np.clip(np.rint(x), spec["min"], spec["max"]).astype(np.int64)
+    """`n` lengths of the distribution `generators/lengths/<dist>.py`."""
+    return find.load("generators/lengths", spec["dist"]).lengths(spec, n)
 
 
 def arrivals(spec: dict, seconds: float, rng) -> np.ndarray:
-    """Due times in [0, seconds), in arrival order."""
-    if spec["process"] == "all_at_zero":
-        return np.zeros(int(spec["count"]))
-    if spec["process"] != "poisson":
-        raise ValueError(f"unknown arrival process {spec['process']!r}")
-    n = max(int(round(spec["rate_per_s"] * seconds)), 1)
-    gaps = -np.log1p(-_quantiles(n))[rng.permutation(n)]
-    return (np.cumsum(gaps) - gaps) * (seconds / gaps.sum())
+    """Due times in [0, seconds), in arrival order, of the process
+    `generators/arrivals/<process>.py`."""
+    return find.load("generators/arrivals", spec["process"]).due(
+        spec, seconds, rng)
+
+
+def withdraws_at_close(mix: dict) -> bool:
+    """Whether the mix's arrival process offers more than a window can
+    finish, so that what is still queued at the close is withdrawn."""
+    process = find.load("generators/arrivals", mix["arrivals"]["process"])
+    return bool(getattr(process, "withdraw_at_close", False))
 
 
 def schedule(mix: dict, seed: int, seconds: float, vocab: int) -> list:
-    """[{due, prompt, max_new}] in arrival order, all from the seed. A
-    mix's `order` is `seeded` (every seed another order of the same
-    lengths and gaps) or `fixed`: every seed the same lengths at the same
-    due times (the order drawn once, from `order_seed`) and only the
-    token ids from the seed, for a tail over few requests, which the
-    order alone moves by more than a change to the program would."""
-    rng = np.random.default_rng(int(seed))
-    if mix["order"] not in ("seeded", "fixed"):
-        raise ValueError(f"unknown order {mix['order']!r}")
-    order = rng if mix["order"] == "seeded" \
-        else np.random.default_rng(int(mix["order_seed"]))
-    due = arrivals(mix["arrivals"], seconds, order)
-    n = len(due)
-    plen = lengths(mix["prompt_len"], n)[order.permutation(n)]
-    olen = lengths(mix["output_len"], n)[order.permutation(n)]
-    cap = int(mix["max_total"])
-    out = []
-    for i in range(n):
-        p, o = int(plen[i]), int(olen[i])
-        if p + o > cap:
-            o = cap - p
-        out.append({"due": float(due[i]),
-                    "prompt": rng.integers(0, vocab, size=p, dtype=np.int32),
-                    "max_new": o})
-    return out
+    """[{due, prompt, max_new}] in arrival order, all from the seed, by
+    `generators/schedules/<schedule>.py`; a mix that names none gets
+    `standard`."""
+    name = mix.get("schedule", "standard")
+    return find.load("generators/schedules", name).schedule(
+        mix, seed, seconds, vocab)
 
 
 def train_batches(mix: dict, seed: int, n: int, vocab: int):
