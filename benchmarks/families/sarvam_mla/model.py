@@ -1,0 +1,89 @@
+"""The program's model of a `sarvam_mla` configuration:
+`serving.LatentMoELM` (latent attention over a 576-wide cache row, one
+dense layer, then routed experts beside a shared expert), loaded with the
+seed's weights a leaf at a time."""
+from __future__ import annotations
+
+from . import weights
+
+#: the values of a serving mix's `weights` that this family has proven on
+#: the chip, with limits and a control (PERF.md)
+PROVEN_WEIGHTS = ("bfloat16",)
+
+
+def build(cfg: dict, dtype: str = "bfloat16", first_held: int = 0):
+    """An unloaded `LatentMoELM` of the configuration's sizes (zeros)."""
+    from paddle_tpu.serving import LatentMoELM  # a program without it stops here
+
+    from paddle_tpu.nn import ParamAttr
+    from paddle_tpu.nn.initializer import Constant
+
+    s = weights.sizes(cfg)
+    return LatentMoELM(
+        s["vocab"], s["d"], s["heads"], s["layers"], nope_dim=s["nope"],
+        rope_dim=s["rope"], v_dim=s["v"], kv_rank=s["kv_rank"],
+        dense_ffn=s["dense_ffn"], expert_ffn=s["expert_ffn"],
+        num_experts=s["experts"], top_k=s["top_k"],
+        held=(first_held, s["held"]), shared_ffn=s["shared_ffn"],
+        routed_scaling=s["scaling"], dense_layers=s["dense_layers"],
+        rope=s["rope_cfg"], qk_norm=s["qk_norm"],
+        max_position=s["positions"], epsilon=s["eps"],
+        key_block=s["key_block"], dtype=dtype,
+        weight_attr=ParamAttr(initializer=Constant(0.0)))
+
+
+def load(lm, leaves, dtype=None) -> None:
+    """Give every parameter of `lm` the leaf of its name; `leaves` is a
+    dict or an iterator of (name, array), taken one at a time so that a
+    9 GB model never holds two copies."""
+    named = dict(lm.named_parameters())
+    seen = set()
+    for name, w in (leaves.items() if isinstance(leaves, dict) else leaves):
+        if name not in named:
+            raise KeyError(f"the seed has a leaf {name!r} the model lacks")
+        p = named[name]
+        if tuple(w.shape) != tuple(p._data.shape):
+            raise ValueError(f"{name}: {w.shape} for {p._data.shape}")
+        p._data = w.astype(dtype or p._data.dtype)
+        seen.add(name)
+    if seen != set(named):
+        raise KeyError(f"the model has leaves the seed lacks: "
+                       f"{sorted(set(named) - seen)}")
+
+
+def serving_model(cfg: dict, mix: dict, seed: int):
+    """The `Layer` a serving driver hands to `InferenceEngine`."""
+    lm = build(cfg)
+    if mix["weights"] not in PROVEN_WEIGHTS:
+        raise ValueError("only bfloat16 serving of this family has run on "
+                         "this chip; a mix in another precision needs its "
+                         "own proof")
+    load(lm, weights.each_leaf(cfg, seed))
+    lm.eval()
+    assert_routes(lm, cfg, mix, rehearse=False)
+    return lm
+
+
+def assert_routes(model, cfg: dict, mix: dict, rehearse: bool) -> None:
+    """Set-up fails if the cell's prefill would score densely over the
+    capacity, a decode step would not take the absorbed form, or the
+    experts are not the dropless grouped product."""
+    from paddle_tpu.nn.functional.latent import latent_attend_plan
+    from paddle_tpu.nn.layers.latent import RoutedExperts
+
+    eng = mix["engine"]
+    cap = int(eng["max_length"])
+    attn = model.blocks[0].attn
+    chunk = int(eng.get("prefill_chunk") or 0)
+    if chunk:
+        plan = latent_attend_plan(chunk, cap, attn.key_block)
+        if plan != ("expanded", "blockwise"):
+            raise RuntimeError(f"a {chunk}-token chunk over {cap} rows "
+                               f"would attend as {plan}")
+    plan = latent_attend_plan(1, cap, attn.key_block)
+    if plan[0] != "absorbed":
+        raise RuntimeError(f"a decode step would attend as {plan}")
+    routed = [b.mlp for b in model.blocks if b.routed]
+    if not routed or not all(type(m) is RoutedExperts for m in routed):
+        raise RuntimeError("the expert layers are not nn.RoutedExperts "
+                           "(dropless, one grouped product)")

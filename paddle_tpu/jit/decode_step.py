@@ -205,6 +205,11 @@ class _CompiledDecodeBase:
                     getattr(o._data, "sharding", None), NamedSharding
                 ):
                     o._data = jax.device_put(o._data, repl)
+        # a buffer the forward replaces (a device counter) is loop-carried
+        # like the state: commit it, or its second call flips the
+        # signature and the step compiles twice
+        for o in self._b_objs:
+            o._data = _commit_tree(o._data)
         self._donate = donate
         # STATIC at construction (like the model objects themselves):
         # a model with an AdapterSet attached threads per-slot adapter
@@ -214,6 +219,7 @@ class _CompiledDecodeBase:
         self._use_adapters = (
             getattr(model, "_serve_adapters", None) is not None)
         self._jitted = None
+        self._carried_idx = ()
         self._n_steps = 0
         from ..observability import bus as _bus, ledger as _ledger
 
@@ -222,13 +228,17 @@ class _CompiledDecodeBase:
 
     # -- the pure forward segment -----------------------------------------
     def _fwd_objs(self, model, p_objs, b_objs, p_raws, b_raws, ids,
-                  cache_raws, pos, label=None, adapter=None):
+                  cache_raws, pos, label=None, adapter=None, carried=None):
         """A model forward with the KV-cache seam as a pure function of
         (params, buffers, ids, caches, pos) -> (logits, new caches).
         Parameterized over the model so SpeculativeDecodeStep can run
         the draft AND the target inside one program. ``adapter`` ([B]
         int32 per-slot ids) is forwarded only when the model carries an
-        AdapterSet — a bare model's call signature stays untouched."""
+        AdapterSet — a bare model's call signature stays untouched.
+        ``carried`` (a list) collects (buffer index, new raw) of every
+        buffer the forward replaced — a device counter such as
+        `nn.RoutedExperts.load`; a model that replaces none leaves it
+        empty and its program as it was."""
         from .. import profiler as _prof
 
         objs = p_objs + b_objs
@@ -246,12 +256,26 @@ class _CompiledDecodeBase:
             )
             logits = out._data if isinstance(out, Tensor) else out
             new_raws = _raw_tree(new_caches)
+            if carried is not None:
+                carried.extend(
+                    (i, b._data) for i, (b, r) in
+                    enumerate(zip(b_objs, b_raws)) if b._data is not r)
         return logits, new_raws
 
     def _fwd(self, p_raws, b_raws, ids, cache_raws, pos, adapter=None):
-        return self._fwd_objs(self.model, self._p_objs, self._b_objs,
-                              p_raws, b_raws, ids, cache_raws, pos,
-                              adapter=adapter)
+        """-> (logits, new caches, the replaced buffers' new values);
+        which buffers those are is noted at trace time for `_rebind`."""
+        carried = []
+        logits, new_raws = self._fwd_objs(
+            self.model, self._p_objs, self._b_objs, p_raws, b_raws, ids,
+            cache_raws, pos, adapter=adapter, carried=carried)
+        self._carried_idx = tuple(i for i, _ in carried)
+        return logits, new_raws, tuple(r for _, r in carried)
+
+    def _rebind(self, carried) -> None:
+        """Hand the step's replaced buffers back to their objects."""
+        for i, raw in zip(self._carried_idx, carried):
+            self._b_objs[i]._data = raw
 
     def _instrumented(self, donate, out_shardings):
         from ..observability import ledger as _ledger
@@ -287,7 +311,7 @@ class DecodeStep(_CompiledDecodeBase):
                  temp, top_k, top_p, eos, budget, adapter):
         from ..serving import sampling as _sampling
 
-        logits, new_caches = self._fwd(
+        logits, new_caches, carried = self._fwd(
             p_raws, b_raws, tok[:, None], cache_raws, pos,
             adapter=adapter if self._use_adapters else None,
         )
@@ -308,7 +332,7 @@ class DecodeStep(_CompiledDecodeBase):
         feed = jnp.where(new_done, jnp.int32(0), nxt)
         new_pos = pos + jnp.where(done, 0, 1).astype(pos.dtype)
         return emit, last, (new_caches, new_pos, feed, new_done, key,
-                            new_budget)
+                            new_budget), carried
 
     def __call__(self, state: DecodeState):
         # commit EVERY call, not just the first: a fresh generate()
@@ -334,11 +358,13 @@ class DecodeStep(_CompiledDecodeBase):
                 None,                       # step logits
                 (_pin(state.caches), _pin(state.pos), _pin(state.tok),
                  _pin(state.done), _pin(state.key), _pin(state.budget)),
+                None,                       # buffers the forward replaced
             )
             self._jitted = self._instrumented(donate, out_sh)
         self._n_steps += 1
-        emit, logits, (caches, pos, tok, done, key, budget) = \
+        emit, logits, (caches, pos, tok, done, key, budget), carried = \
             self._jitted(*args)
+        self._rebind(carried)
         new_state = DecodeState(
             caches, pos, tok, done, key, state.temperature, state.top_k,
             state.top_p, state.eos, budget, state.adapter,
@@ -371,7 +397,7 @@ class PrefillStep(_CompiledDecodeBase):
 
     def _step_fn(self, p_raws, b_raws, cache_raws, ids, length, start,
                  adapter):
-        logits, new_caches = self._fwd(
+        logits, new_caches, carried = self._fwd(
             p_raws, b_raws, ids, cache_raws,
             jnp.asarray(start, jnp.int32),
             adapter=adapter if self._use_adapters else None,
@@ -380,7 +406,8 @@ class PrefillStep(_CompiledDecodeBase):
         last = jnp.take_along_axis(
             logits, idx[:, None, None], axis=1
         )[:, 0, :].astype(jnp.float32)
-        return last, new_caches, jnp.asarray(start + length, jnp.int32)
+        return (last, new_caches, jnp.asarray(start + length, jnp.int32),
+                carried)
 
     def __call__(self, caches, ids, lengths, start=None, adapter=None):
         """-> (last_logits [B, V] f32, new cache pytree, pos [B]).
@@ -404,10 +431,12 @@ class PrefillStep(_CompiledDecodeBase):
         )
         if self._jitted is None:
             donate = (2,) if self._donate else ()
-            out_sh = (None, _pin(cache_raws), None)
+            out_sh = (None, _pin(cache_raws), None, None)
             self._jitted = self._instrumented(donate, out_sh)
         self._n_steps += 1
-        return self._jitted(*args)
+        last, new_caches, pos, carried = self._jitted(*args)
+        self._rebind(carried)
+        return last, new_caches, pos
 
 
 class MigrateInsert:
@@ -439,6 +468,7 @@ class MigrateInsert:
     def __init__(self, *, donate: bool = True):
         self._donate = donate
         self._jitted = None
+        self._carried_idx = ()
         self._n_steps = 0
         from ..observability import bus as _bus, ledger as _ledger
 
@@ -628,7 +658,9 @@ class SpeculativeDecodeStep(_CompiledDecodeBase):
         drafts = jnp.stack(drafts, axis=1)  # [B, K]
         # -- target: ONE forward over all K+1 inputs -------------------
         inputs = jnp.concatenate([tok[:, None], drafts], axis=1)
-        tlogits, new_caches = self._fwd(
+        # (a speculative step hands no replaced buffer on: its models
+        # carry no device counter)
+        tlogits, new_caches, _ = self._fwd(
             p_raws, b_raws, inputs, cache_raws, pos
         )
         g = jnp.argmax(

@@ -23,3 +23,16 @@ from .layers.transformer import *  # noqa: F401,F403
 # importable module names as well as the flat layer namespace)
 from .layers import common, container, loss, norm, pooling, rnn, vision  # noqa: F401,E402
 from .layers import conv  # noqa: F401,E402
+
+
+# the latent-attention / routed-expert layers resolve LAZILY: no program
+# that does not name them pays their import
+_LATENT = ("RMSNorm", "GatedMLP", "LatentAttention", "RoutedExperts")
+
+
+def __getattr__(name):
+    if name in _LATENT:
+        from .layers import latent
+
+        return getattr(latent, name)
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

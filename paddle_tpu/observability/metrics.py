@@ -289,3 +289,29 @@ class DecodeMetricsSampler:
             return
         bus.emit("span", {"name": "decode_window", "trace_ids": ids,
                           "steps": int(steps)}, step=self._windows)
+
+
+# ---------------------------------------------------------------------------
+# routed-expert load: device counters read at the engine's readback
+# ---------------------------------------------------------------------------
+
+_expert_load: dict = {}
+
+
+def record_expert_load(loads: dict) -> None:
+    """Keep the host copy of a routed model's counters
+    (`serving.LatentMoELM.expert_load()`), as the serving engine reads
+    them with each readback. The counters are running totals on the
+    device, so the newest reading replaces the last."""
+    _expert_load.clear()
+    _expert_load.update({int(k): v for k, v in loads.items()})
+
+
+def expert_load() -> dict:
+    """{block index: [2, held + 1] int array} — per routed layer, the
+    assignments that fell on each expert held here and, in the last
+    column, those routed to experts not held; row 0 counted in prefill
+    programs, row 1 in decode steps. Process-wide and readable after the
+    engine and its model are gone, as `ledger.compile_seconds()` is;
+    empty when no routed model was served."""
+    return dict(_expert_load)

@@ -64,14 +64,14 @@ from .adapters import AdapterSet  # noqa: F401
 from .engine import (  # noqa: F401
     GeneratedResult, GenerationConfig, InferenceEngine, Request, generate,
 )
-from .model import TransformerLM  # noqa: F401
+from .model import LatentMoELM, TransformerLM  # noqa: F401
 from .prefix_cache import PrefixCache  # noqa: F401
 from .router import (  # noqa: F401
     FileHost, FilePrefillHost, LocalHost, PrefillHost, Router,
 )
 
 __all__ = [
-    "sampling", "TransformerLM", "generate", "GenerationConfig",
+    "sampling", "TransformerLM", "LatentMoELM", "generate", "GenerationConfig",
     "Request", "InferenceEngine", "GeneratedResult", "paged_kv",
     "Router", "LocalHost", "FileHost", "PrefillHost", "FilePrefillHost",
     "PrefixCache", "AdapterSet",

@@ -474,6 +474,8 @@ class InferenceEngine:
                               else prefill_chunk_default())
         self._prefill = PrefillStep(model)
         self._decode = DecodeStep(model)
+        #: a routed model's device counters, read with every readback
+        self._expert_load = getattr(model, "expert_load", None)
         self._insert_jitted = None
         self._migrate = None  # lazy jit.MigrateInsert (ISSUE 17)
         #: resident fine-tune fleet, if the model carries one (attach
@@ -527,6 +529,8 @@ class InferenceEngine:
         # bitwise. Needs the paged pool (the share unit is a block).
         use_px = (prefix_cache if prefix_cache is not None
                   else prefix_cache_enabled())
+        if use_px:
+            pk.refuse_latent(caches, "the prefix cache")
         self._prefix: Optional[PrefixCache] = (
             PrefixCache(self.block_size)
             if use_px and self._pool is not None else None)
@@ -541,9 +545,11 @@ class InferenceEngine:
         from ..jit.decode_step import _commit_tree
 
         self._state = DecodeState(*_commit_tree(self._state.astuple()))
-        from ..observability.metrics import DecodeMetricsSampler
+        from ..observability.metrics import (DecodeMetricsSampler,
+                                             record_expert_load)
 
         self._metrics = DecodeMetricsSampler()
+        self._record_expert_load = record_expert_load
 
     # -- public API --------------------------------------------------------
     def needed_blocks(self, req: Request) -> int:
@@ -1046,6 +1052,10 @@ class InferenceEngine:
         with _prof.phase("engine.readback"):
             tok_block = np.asarray(jnp.stack(emits, axis=0))
             done = np.asarray(self._state.done)
+            if self._expert_load is not None:
+                # the device is already drained by the token read: the
+                # counters ride it, no new sync point
+                self._record_expert_load(self._expert_load())
         dt = time.perf_counter() - t0
         with _prof.phase("engine.collect"):
             # decode-window span for traced requests: emitted on the
@@ -1189,8 +1199,15 @@ class InferenceEngine:
                 continue
             L = req.prefill_ids.size
             if self.prefill_chunk > 0 and L > self.prefill_chunk:
+                # committed like every later chunk's cache (a step's
+                # output): a fresh uncommitted scratch would give the
+                # first chunk a signature of its own, and the chunk
+                # program a second compile
+                from ..jit.decode_step import _commit_tree, _raw_tree
+
                 self._pending[slot] = _Pending(
-                    req, slot, blocks, self._slot_cache(req, slot),
+                    req, slot, blocks,
+                    _commit_tree(_raw_tree(self._slot_cache(req, slot))),
                     time.perf_counter())
                 continue
             t0 = time.perf_counter()

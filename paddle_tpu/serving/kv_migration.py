@@ -132,6 +132,8 @@ def gather_leaves(cache_tree, blocks: Sequence[int]) -> List[Tuple]:
 
     from . import paged_kv as pk
 
+    pk.refuse_latent(cache_tree, "kv_migration.gather_leaves")
+
     idx = np.asarray(list(blocks), np.int32)
     out: List[Tuple] = []
     for leaf in jax.tree_util.tree_leaves(

@@ -8,7 +8,12 @@ consume any Layer with this surface::
     model(ids, cache=cs, pos=pos)    -> ([B, Sq, V] logits, new caches)
     model.gen_cache(B, cap[, dtype]) -> per-layer static-capacity caches
 
-`TransformerLM` is the in-repo implementation: token + learned position
+`LatentMoELM` is the second decoder of that contract: a pattern of layers
+(leading dense gated-SiLU layers, then routed-expert layers) under latent
+attention, whose `gen_cache` returns one `[B, cap, kv_rank + rope]` row
+store a layer instead of per-head K and V.
+
+`TransformerLM` is the first in-repo implementation: token + learned position
 embeddings, a `ParallelGPTBlock` stack (tensor-parallel attention/MLP —
 trivial on one chip, sharded over 'mp' on a hybrid mesh, same code
 path), final LayerNorm and an untied vocab head — the same shape
@@ -22,7 +27,7 @@ from ..distributed import comm
 from ..distributed.meta_parallel import ParallelGPTBlock
 from ..ops.creation import arange
 
-__all__ = ["TransformerLM"]
+__all__ = ["TransformerLM", "LatentMoELM"]
 
 
 class TransformerLM(nn.Layer):
@@ -89,3 +94,142 @@ class TransformerLM(nn.Layer):
                               block_size=block_size,
                               pool_blocks=pool_blocks)
                 for blk in self.blocks]
+
+
+class _LatentBlock(nn.Layer):
+    """Pre-norm residual block: latent attention, then a dense gated MLP
+    or routed experts."""
+
+    def __init__(self, attn, mlp, d_model, eps, weight_attr, dtype):
+        super().__init__()
+        from ..nn.layers.latent import RMSNorm
+
+        self.norm1 = RMSNorm(d_model, eps, weight_attr=weight_attr,
+                           dtype=dtype)
+        self.attn = attn
+        self.norm2 = RMSNorm(d_model, eps, weight_attr=weight_attr,
+                           dtype=dtype)
+        self.mlp = mlp
+        self.routed = hasattr(mlp, "held")
+
+    def forward(self, h, cache=None, pos=None):
+        if cache is None:
+            h = h + self.attn(self.norm1(h))
+            return h + self.mlp(self.norm2(h))
+        a, new_cache = self.attn(self.norm1(h), cache=cache, pos=pos)
+        h = h + a
+        x = self.norm2(h)
+        return h + (self.mlp(x, count=True) if self.routed
+                    else self.mlp(x)), new_cache
+
+
+class LatentMoELM(nn.Layer):
+    """A causal LM of latent-attention blocks (`nn.LatentAttention`): the
+    first `dense_layers` blocks carry a dense gated-SiLU MLP, the rest
+    `nn.RoutedExperts` over the `held` = (first, count) experts this chip
+    holds of `num_experts`, beside a shared expert. RMSNorm before each
+    sublayer and at the end, rotary positions inside the attention (no
+    position table), an untied head, no biases. Parameters and cache are
+    `dtype` (bfloat16 by default); norm and softmax statistics, the
+    router and the logits are float32.
+
+    The serving contract of this module: `model(ids)`, `model(ids,
+    cache=, pos=)`, `gen_cache(B, cap[, dtype], block_size=,
+    pool_blocks=)`; a paged pool is refused (`block_size` > 0 raises).
+    `expert_load()` reads the routed layers' device counters."""
+
+    def __init__(self, vocab_size, d_model, num_heads, num_layers, *,
+                 nope_dim, rope_dim, v_dim, kv_rank, dense_ffn,
+                 expert_ffn, num_experts, top_k, held=None,
+                 shared_ffn=None, routed_scaling=1.0, dense_layers=1,
+                 rope=None, qk_norm=True, max_position=131072,
+                 epsilon=1e-6, key_block=None, weight_attr=None,
+                 dtype="bfloat16"):
+        super().__init__()
+        from ..nn.initializer import Normal
+        from ..nn.layers.latent import (GatedMLP, LatentAttention, RMSNorm,
+                                        RoutedExperts)
+
+        if comm.hybrid_mesh() is None:
+            comm.init_hybrid_mesh(dp=1, mp=1, pp=1, sp=1)
+        self.vocab_size, self.d_model = int(vocab_size), int(d_model)
+        self.max_position = int(max_position)
+        kb = {} if key_block is None else {"key_block": key_block}
+
+        # nn.Embedding makes its table in the default dtype
+        self.embedding = nn.Embedding(vocab_size, d_model,
+                                      weight_attr=weight_attr)
+        self.embedding.weight._data = \
+            self.embedding.weight._data.astype(dtype)
+        blocks = []
+        for i in range(int(num_layers)):
+            attn = LatentAttention(
+                d_model, num_heads, nope_dim=nope_dim, rope_dim=rope_dim,
+                v_dim=v_dim, kv_rank=kv_rank, rope=rope, qk_norm=qk_norm,
+                epsilon=epsilon, weight_attr=weight_attr, dtype=dtype, **kb)
+            if i < int(dense_layers):
+                mlp = GatedMLP(d_model, dense_ffn, weight_attr, dtype)
+            else:
+                mlp = RoutedExperts(
+                    d_model, expert_ffn, num_experts, top_k, held=held,
+                    scaling=routed_scaling, shared_hidden=shared_ffn,
+                    weight_attr=weight_attr, dtype=dtype)
+            blocks.append(_LatentBlock(attn, mlp, d_model, epsilon,
+                                       weight_attr, dtype))
+        self.blocks = nn.LayerList(blocks)
+        self.norm_f = RMSNorm(d_model, epsilon, weight_attr=weight_attr,
+                            dtype=dtype)
+        self.head = self.create_parameter(
+            shape=[d_model, vocab_size], attr=weight_attr, dtype=dtype,
+            default_initializer=Normal(0.0, 0.02))
+
+    def _logits(self, h):
+        """The head's product leaves the accumulator as float32: logits
+        rounded to the parameters' bfloat16 would tie at the top of a
+        65,536-way row (the best logit's neighbours lie 2^-6 apart)."""
+        import jax.numpy as jnp
+
+        from ..core import autograd as AG
+
+        return AG.apply(
+            lambda a, w: jnp.matmul(a, w,
+                                    preferred_element_type=jnp.float32),
+            (self.norm_f(h), self.head), name="lm_head")
+
+    def forward(self, ids, cache=None, pos=None, adapter=None):
+        h = self.embedding(ids)
+        if cache is None:
+            for blk in self.blocks:
+                h = blk(h)
+            return self._logits(h)
+        if pos is None:
+            raise ValueError("cache decoding needs `pos` ([B] int32)")
+        new_caches = []
+        for blk, c in zip(self.blocks, cache):
+            h, nc = blk(h, cache=c, pos=pos)
+            new_caches.append(nc)
+        return self._logits(h), new_caches
+
+    def gen_cache(self, batch_size, max_length, dtype=None,
+                  block_size=None, pool_blocks=None):
+        if int(max_length) > self.max_position:
+            raise ValueError(
+                f"cache capacity {max_length} exceeds max_position="
+                f"{self.max_position}")
+        return [blk.attn.gen_cache(batch_size, max_length, dtype,
+                                   block_size=block_size,
+                                   pool_blocks=pool_blocks)
+                for blk in self.blocks]
+
+    def expert_load(self):
+        """{block index: [2, held + 1] int numpy array} of the routed
+        layers' `load` counters (rows prefill, decode; the last column is
+        the assignments routed to experts not held): one device read."""
+        import jax.numpy as jnp
+        import numpy as np
+
+        routed = [(i, b.mlp) for i, b in enumerate(self.blocks) if b.routed]
+        if not routed:
+            return {}
+        stacked = np.asarray(jnp.stack([m.load._data for _, m in routed]))
+        return {i: stacked[n] for n, (i, _) in enumerate(routed)}

@@ -71,6 +71,21 @@ def block_size_default() -> int:
         return 0
 
 
+def refuse_latent(cache_tree, what: str) -> None:
+    """Blocks, block tables, shared prefixes and migration bundles are
+    all made of per-head K and V `[.., H, rows, Dh]`; a latent cache
+    (`nn.functional.latent.LatentCache`: one `[c | k_rope]` row a token,
+    no head axis) would be mis-spliced by them, so `what` refuses it."""
+    from ..nn.functional.latent import is_latent
+
+    if is_latent(cache_tree):
+        raise TypeError(
+            f"{what} handles per-head K and V only and refuses a latent "
+            "cache (LatentCache: one [c | k_rope] row a token, no head "
+            "axis); serve it from the contiguous pool (block_size=0, no "
+            "prefix cache, no migration)")
+
+
 def is_paged(cache) -> bool:
     return isinstance(cache, PagedKV)
 
@@ -202,6 +217,7 @@ def paged_splice(paged, slot_kv, slot, table_row):
     the slot's allocation) and point slot ``slot``'s table row at them.
     One scatter per leaf; ``slot`` and ``table_row`` ride as traced
     values so every slot/allocation shares one compile."""
+    refuse_latent(slot_kv, "paged_kv.paged_splice")
     import jax
 
     def leaf(pool, contiguous):
@@ -228,6 +244,7 @@ def paged_fetch(paged, slot_kv, table_row):
     trash-mapped entries are garbage, which the position mask
     (``kpos > qpos``) blinds. One gather per leaf; ``table_row`` rides
     traced so every admission shares one compile."""
+    refuse_latent(slot_kv, "paged_kv.paged_fetch")
     import jax
 
     def leaf(pool, contiguous):
@@ -257,6 +274,7 @@ def paged_splice_tail(paged, slot_kv, slot, table_row, start, length,
     self-copy) is the no-CoW case. Dead positions collide on the trash
     block. All scalars ride traced — one compile covers every
     admission."""
+    refuse_latent(slot_kv, "paged_kv.paged_splice_tail")
     import jax
     import jax.numpy as jnp
 
