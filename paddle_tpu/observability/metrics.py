@@ -315,3 +315,29 @@ def expert_load() -> dict:
     engine and its model are gone, as `ledger.compile_seconds()` is;
     empty when no routed model was served."""
     return dict(_expert_load)
+
+
+# ---------------------------------------------------------------------------
+# sampler engagement: which side of `sampling.sample`'s branch a decode
+# step took, counted from host values at the engine's readback
+# ---------------------------------------------------------------------------
+
+_sampler_steps = {"greedy": 0, "sampling": 0}
+
+
+def record_sampler_steps(greedy: int, sampling: int) -> None:
+    """Add one readback window's decode steps: ``greedy`` steps in which
+    every active request had ``temperature <= 0`` (the sampler returned
+    the argmax and ran no filter), ``sampling`` steps in which at least
+    one drew. Counted by `InferenceEngine.turn` from the requests it
+    holds — no device read."""
+    _sampler_steps["greedy"] += int(greedy)
+    _sampler_steps["sampling"] += int(sampling)
+
+
+def sampler_steps() -> dict:
+    """{"greedy": n, "sampling": n} — running totals of the decode steps
+    every engine of this process dispatched, by the side of the
+    sampler's branch their active requests call for. Process-wide and
+    readable after the engine is gone, as :func:`expert_load` is."""
+    return dict(_sampler_steps)

@@ -546,10 +546,12 @@ class InferenceEngine:
 
         self._state = DecodeState(*_commit_tree(self._state.astuple()))
         from ..observability.metrics import (DecodeMetricsSampler,
-                                             record_expert_load)
+                                             record_expert_load,
+                                             record_sampler_steps)
 
         self._metrics = DecodeMetricsSampler()
         self._record_expert_load = record_expert_load
+        self._record_sampler_steps = record_sampler_steps
 
     # -- public API --------------------------------------------------------
     def needed_blocks(self, req: Request) -> int:
@@ -1063,6 +1065,12 @@ class InferenceEngine:
             self._metrics.window_span(
                 [s.req.trace_id for s in self._active.values()],
                 steps=window)
+            # which side of the sampler's branch this window's steps
+            # took, by what the active requests asked for
+            draws = any(s.req.temperature > 0
+                        for s in self._active.values())
+            self._record_sampler_steps(0 if draws else window,
+                                       window if draws else 0)
             self._collect(tok_block, done, results)
         with _prof.phase("engine.turn_tail"):
             if self._retiring:

@@ -14,6 +14,18 @@ Filter semantics match the numpy references in tests/test_serving.py:
 top-k keeps every logit >= the k-th largest (ties at the threshold are
 kept); top-p keeps the shortest prefix of the descending-probability
 sort whose mass reaches p (the argmax token is always kept).
+
+:func:`sample` decides IN THE GRAPH whether any row samples at all: one
+``lax.cond`` on ``any(temperature > 0)`` over the vector it is handed.
+All-greedy batches return the argmax and run no sort, mask or draw;
+a batch with one sampling row runs exactly the filters and the draw it
+always ran (inside that side, a filter no sampling row turns on is
+skipped by the same kind of branch). With a ``temperature`` the entry
+is ONE cached program (a module-level ``jax.jit``): inside
+``DecodeStep`` it inlines into the step's program, and an eager caller
+(the engine's first token, ``generate``) compiles it once a shape —
+an eager ``lax.cond`` would otherwise be traced and compiled anew on
+every call.
 """
 from __future__ import annotations
 
@@ -75,25 +87,49 @@ def top_p_mask(logits, p):
     return jnp.where(keep, logits, _NEG)
 
 
+def _rows(x, dtype, logits):
+    """A scalar or [B] vector as a [B] vector of ``dtype``."""
+    return jnp.broadcast_to(jnp.asarray(x, dtype), logits.shape[:1])
+
+
+@jax.jit
+def _sample_rows(logits, key, t, top_k, top_p):
+    """The cached program behind :func:`sample`: ``t`` is a [B] float32
+    vector, ``top_k`` / ``top_p`` are [B] vectors or None (a filter the
+    caller left out: a static choice)."""
+    g = greedy(logits)
+    draws = t > 0.0
+
+    def draw():
+        filtered = apply_temperature(logits.astype(jnp.float32), t)
+        # a filter that no sampling row turns on leaves those rows as
+        # they are, and greedy rows are replaced below: skip its sorts
+        if top_k is not None:
+            filtered = jax.lax.cond(
+                jnp.any(draws & (top_k > 0)),
+                lambda x: top_k_mask(x, top_k), lambda x: x, filtered)
+        if top_p is not None:
+            filtered = jax.lax.cond(
+                jnp.any(draws & (top_p < 1.0)),
+                lambda x: top_p_mask(x, top_p), lambda x: x, filtered)
+        drawn = jax.random.categorical(key, filtered, axis=-1).astype(
+            jnp.int32)
+        return jnp.where(t <= 0.0, g, drawn)
+
+    return jax.lax.cond(jnp.any(draws), draw, lambda: g)
+
+
 def sample(logits, key, temperature=None, top_k=None, top_p=None):
     """One sampling step: [B, V] logits -> [B] int32 token ids.
 
     Greedy rows (``temperature`` None, or <= 0 per slot) take the
     argmax; the rest draw from the temperature-scaled, top-k- then
     top-p-filtered categorical using ``key`` (caller splits it per
-    step — the standard decode-loop threading)."""
-    g = greedy(logits)
+    step — the standard decode-loop threading). When no row draws,
+    neither the filters nor the draw run (see the module docstring)."""
     if temperature is None:
-        return g
-    lg = logits.astype(jnp.float32)
-    t = jnp.broadcast_to(
-        jnp.asarray(temperature, jnp.float32), lg.shape[:1]
-    )
-    filtered = apply_temperature(lg, t)
-    if top_k is not None:
-        filtered = top_k_mask(filtered, top_k)
-    if top_p is not None:
-        filtered = top_p_mask(filtered, top_p)
-    drawn = jax.random.categorical(key, filtered, axis=-1).astype(
-        jnp.int32)
-    return jnp.where(t <= 0.0, g, drawn)
+        return greedy(logits)
+    return _sample_rows(
+        logits, key, _rows(temperature, jnp.float32, logits),
+        None if top_k is None else _rows(top_k, jnp.int32, logits),
+        None if top_p is None else _rows(top_p, jnp.float32, logits))

@@ -16,6 +16,7 @@ Acceptance contracts tested here:
 - decode_metrics telemetry rides the engine readback cadence with zero
   extra device reads.
 """
+import contextlib
 import os
 
 import numpy as np
@@ -167,6 +168,167 @@ class TestSamplingOps:
                 jnp.asarray(lg), jax.random.PRNGKey(seed), 1.5, 3, 1.0))
             for b in range(2):
                 assert got[b] in top3[b]
+
+    # -- the in-graph greedy branch (ISSUE 31) --------------------------
+
+    @pytest.mark.parametrize("top_k,top_p", [
+        ("vec", "vec"), ("vec", None), (None, "vec"), (None, None),
+        (0, 1.0)])
+    def test_sample_sorts_only_inside_the_branch(self, top_k, top_p):
+        """Structure: with a temperature vector, every sort, cumsum and
+        random draw of `sample` sits inside a `cond`'s branches, so an
+        all-greedy step runs none of them."""
+        B, V = 4, 33
+        k = jnp.zeros((B,), jnp.int32) if top_k == "vec" else top_k
+        p = jnp.ones((B,), jnp.float32) if top_p == "vec" else top_p
+        jaxpr = jax.make_jaxpr(sampling.sample)(
+            jnp.zeros((B, V), jnp.float32), jax.random.PRNGKey(0),
+            jnp.zeros((B,), jnp.float32), k, p).jaxpr
+        heavy = {"sort", "argsort", "cumsum", "random_bits",
+                 "threefry2x32"}
+        outside = set(_primitives(jaxpr, into_cond=False))
+        everywhere = set(_primitives(jaxpr, into_cond=True))
+        assert not (outside & heavy), outside & heavy
+        assert "cond" in outside and "argmax" in outside
+        # not vacuous: the draw is still in the program, in the branch
+        assert {"random_bits", "threefry2x32"} & everywhere
+        if top_k is not None or top_p is not None:
+            assert "sort" in everywhere
+
+    @pytest.mark.parametrize("temp", [0.0, -1.0, "mixed_nonpositive"])
+    @pytest.mark.parametrize("B,V", [(1, 17), (5, 17), (3, 301)])
+    def test_sample_all_greedy_rows_return_argmax(self, temp, B, V):
+        """Greedy rows that also carry top_k > 0 and top_p < 1 (a
+        request may set them and still ask for temperature 0)."""
+        lg = self._logits(B=B, V=V)
+        t = (np.linspace(-2.0, 0.0, B) if temp == "mixed_nonpositive"
+             else np.full((B,), temp)).astype(np.float32)
+        k = np.arange(1, B + 1, dtype=np.int32)
+        p = np.linspace(0.1, 0.9, B).astype(np.float32)
+        for seed in range(3):
+            got = np.asarray(sampling.sample(
+                jnp.asarray(lg), jax.random.PRNGKey(seed), t, k, p))
+            np.testing.assert_array_equal(got, lg.argmax(-1))
+            assert got.dtype == np.int32
+
+    @pytest.mark.parametrize("rows", [
+        # (temperature, top_k, top_p) a row
+        pytest.param([(1.0, 0, 1.0), (0.7, 0, 1.0), (1.5, 0, 1.0)],
+                     id="all_sampling_filters_off"),
+        pytest.param([(1.0, 3, 1.0), (0.7, 1, 1.0), (2.0, 40, 1.0)],
+                     id="all_sampling_top_k"),
+        pytest.param([(1.0, 0, 0.9), (0.7, 0, 0.3), (2.0, 0, 0.0)],
+                     id="all_sampling_top_p"),
+        pytest.param([(1.0, 5, 0.9), (0.5, 2, 0.5), (1.3, 0, 1.0),
+                      (0.9, 7, 1.0), (1.1, 0, 0.6)],
+                     id="all_sampling_both"),
+        pytest.param([(0.0, 4, 0.5), (1.0, 0, 1.0), (0.0, 0, 1.0),
+                      (0.8, 0, 1.0)],
+                     id="mixed_filters_on_greedy_rows_only"),
+        pytest.param([(0.0, 0, 1.0), (1.2, 6, 0.8), (-1.0, 2, 0.2),
+                      (0.6, 3, 1.0), (1.0, 0, 0.7)],
+                     id="mixed_both"),
+        pytest.param([(0.0, 0, 1.0), (0.0, 0, 1.0), (0.9, 0, 0.5)],
+                     id="mixed_one_sampling_row"),
+    ])
+    def test_sample_draws_match_the_unbranched_formula(self, rows):
+        """For the same key a sampling row gets exactly the token the
+        sampler gave before the branch: temperature -> top-k -> top-p
+        -> categorical, greedy rows swapped in by `where`."""
+        t, k, p = (np.asarray(c, d) for c, d in zip(
+            zip(*rows), (np.float32, np.int32, np.float32)))
+        lg = jnp.asarray(self._logits(B=len(rows), V=57))
+        for seed in range(6):
+            key = jax.random.PRNGKey(seed)
+            want = _unbranched_sample(lg, key, t, k, p)
+            got = sampling.sample(lg, key, jnp.asarray(t),
+                                  jnp.asarray(k), jnp.asarray(p))
+            np.testing.assert_array_equal(np.asarray(got),
+                                          np.asarray(want))
+        assert (np.asarray(got)[t <= 0]
+                == np.asarray(lg).argmax(-1)[t <= 0]).all()
+
+    @pytest.mark.parametrize("temperature,top_k,top_p", [
+        (None, None, None), (None, 3, 0.5), (1.0, None, None),
+        (0.0, None, None), (0.0, 3, 0.5), (1.0, 1, 1.0),
+        (0.8, None, 0.9), (0.8, 4, None), (1.3, 0, 1.0)])
+    def test_sample_scalars_and_nones(self, temperature, top_k, top_p):
+        lg = jnp.asarray(self._logits(B=4, V=23))
+        key = jax.random.PRNGKey(11)
+        got = np.asarray(sampling.sample(lg, key, temperature, top_k,
+                                         top_p))
+        if temperature is None:
+            want = np.asarray(lg).argmax(-1)
+        else:
+            want = np.asarray(_unbranched_sample(
+                lg, key, temperature, top_k, top_p))
+        np.testing.assert_array_equal(got, want)
+        assert got.dtype == np.int32 and got.shape == (4,)
+
+    def test_eager_sample_compiles_once(self):
+        """The eager callers (the engine's first token, `generate`) get
+        ONE cached program a shape: a second and third call on fresh
+        arrays compile nothing."""
+        V = 29
+
+        def call(seed):
+            return sampling.sample(
+                jnp.asarray(rng.randn(1, V).astype(np.float32)),
+                jax.random.PRNGKey(seed),
+                jnp.asarray([0.0 if seed % 2 else 0.7], jnp.float32),
+                jnp.asarray([seed], jnp.int32),
+                jnp.asarray([0.9], jnp.float32))
+
+        call(0).block_until_ready()
+        with _count_backend_compiles() as compiled:
+            call(1).block_until_ready()
+            call(2).block_until_ready()
+        assert compiled == [], compiled
+
+
+def _primitives(jaxpr, into_cond):
+    """Names of every equation's primitive in ``jaxpr`` and the jaxprs
+    nested in it; a ``cond``'s branches only when ``into_cond``."""
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        yield name
+        if name == "cond" and not into_cond:
+            continue
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _primitives(sub, into_cond)
+
+
+def _unbranched_sample(lg, key, t, k, p):
+    """`sampling.sample` as it was before it branched, from its public
+    pieces (a filter given as None is left out, as it was)."""
+    tt = jnp.broadcast_to(jnp.asarray(t, jnp.float32), lg.shape[:1])
+    f = sampling.apply_temperature(lg.astype(jnp.float32), tt)
+    if k is not None:
+        f = sampling.top_k_mask(f, k)
+    if p is not None:
+        f = sampling.top_p_mask(f, p)
+    drawn = jax.random.categorical(key, f, axis=-1).astype(jnp.int32)
+    return jnp.where(tt <= 0.0, sampling.greedy(lg), drawn)
+
+
+@contextlib.contextmanager
+def _count_backend_compiles():
+    """The `jax.monitoring` duration events of XLA backend compiles
+    inside the block, as a list of their keys."""
+    keys = []
+
+    def on_duration(key, _secs, **kw):
+        if "backend_compile" in key:
+            keys.append(key)
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    try:
+        yield keys
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_duration)
 
 
 # ---------------------------------------------------------------------------
@@ -466,6 +628,61 @@ class TestInferenceEngine:
         results = engine.run()
         assert results["stopped"].tokens == row[: j + 1]
         assert results["full"].tokens == row
+
+    def test_greedy_requests_compile_nothing_after_the_first(
+            self, trivial_mesh):
+        """The first request compiles what serving needs (its bucket's
+        prefill, the insert, the decode step, the eager [1, V] sampler);
+        two more greedy requests of that bucket compile nothing, the
+        first-token sample included."""
+        paddle.seed(67)
+        model = _tiny_lm(cap=32)
+        engine = InferenceEngine(model, slots=2, max_length=32,
+                                 sync_every=3)
+        engine.submit(Request(rng.randint(0, 48, size=(5,)),
+                              max_new_tokens=5))
+        engine.run()
+        assert engine._decode.compiles == 1
+        with _count_backend_compiles() as compiled:
+            for n in (5, 5):
+                engine.submit(Request(rng.randint(0, 48, size=(n,)),
+                                      max_new_tokens=6))
+            results = engine.run()
+        assert len(results) == 2
+        assert compiled == [], compiled
+        assert engine._decode.compiles == 1
+
+    @pytest.mark.parametrize("sampled", [False, True])
+    def test_sampler_steps_counter(self, trivial_mesh, sampled):
+        """`observability.metrics.sampler_steps()` says which side of
+        the sampler's branch the dispatched decode steps called for:
+        all on `greedy` while every active request is greedy; a
+        request with temperature > 0 moves the windows it is active in
+        to `sampling`."""
+        from paddle_tpu.observability import metrics
+
+        paddle.seed(71)
+        model = _tiny_lm(cap=32)
+        engine = InferenceEngine(model, slots=2, max_length=32,
+                                 sync_every=2)
+        dispatched = []
+        decode = engine._decode
+        engine._decode = lambda st: (dispatched.append(1), decode(st))[1]
+        # 12 decoded tokens after the first: 6 windows of 2 steps
+        engine.submit(Request(rng.randint(0, 48, size=(4,)),
+                              max_new_tokens=13))
+        # 4 decoded tokens after the first: active for 2 windows
+        engine.submit(Request(rng.randint(0, 48, size=(3,)),
+                              max_new_tokens=5,
+                              temperature=0.8 if sampled else 0.0,
+                              top_k=5))
+        before = metrics.sampler_steps()
+        engine.run()
+        after = metrics.sampler_steps()
+        moved = {k: after[k] - before[k] for k in after}
+        assert sorted(moved) == ["greedy", "sampling"]
+        assert moved["greedy"] + moved["sampling"] == len(dispatched) == 12
+        assert moved["sampling"] == (4 if sampled else 0)
 
     @pytest.mark.slow
     def test_insert_on_free_many_requests(self, trivial_mesh):
