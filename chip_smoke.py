@@ -37,6 +37,8 @@ import time
 import numpy as np
 
 VOCAB, D_MODEL, HEADS, FULL_LAYERS = 32000, 1024, 16, 24
+#: the trainer's position table, and the sequence length run on the chip
+SEQ = 1024
 #: depth actually run on the chip. Cut depth before a width if the
 #: 1200 s contract ever bites, and the cut is printed.
 LAYERS = 24
@@ -116,7 +118,6 @@ def phase_device(rehearse: bool) -> dict:
             "(--rehearse is the CPU control-flow rehearsal)")
     # importing the package is what places the compile cache
     from paddle_tpu.core import compile_cache
-    from paddle_tpu.observability import mfu
 
     env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     print(f"compile cache: {compile_cache.cache_dir} "
@@ -125,12 +126,16 @@ def phase_device(rehearse: bool) -> dict:
     check(jax.config.jax_compilation_cache_dir == compile_cache.cache_dir,
           "jax is not using the cache directory the program reports")
     if not rehearse:
-        check(mfu.peak_flops() is not None,
-              f"observability.mfu has no peak for device_kind "
+        # the repo's one table of peaks is the benchmark's, read as data
+        with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                               "benchmarks", "peaks.json")) as f:
+            peaks = json.load(f)
+        check(d0.device_kind in peaks,
+              f"benchmarks/peaks.json has no peak for device_kind "
               f"{d0.device_kind!r}: on the chip an unknown device is an "
               "error, not a missing key")
-        print(f"peak flops/s for this device_kind: {mfu.peak_flops():.3g}",
-              flush=True)
+        print("peak flops/s for this device_kind: "
+              f"{peaks[d0.device_kind]['flops_per_s']:.3g}", flush=True)
     return device
 
 
@@ -229,22 +234,28 @@ def phase_kernels(batch: int, seq: int, rehearse: bool) -> None:
 # phase 2 / 4: the trainer
 # ---------------------------------------------------------------------------
 
+#: the lowered step names a Mosaic call by its `pallas_call`'s `name=`
 _MOSAIC_KERNELS = {
-    "flash fwd": ("_fwd_kernel_resident", "_fwd_kernel"),
-    "flash dq": ("_dq_kernel",),
-    "flash dk/dv": ("_dkv_kernel",),
-    "LN / add-LN fwd": ("_ln_fwd_kernel", "_add_ln_fwd_kernel"),
-    "LN bwd": ("_ln_bwd_kernel",),
+    "flash fwd": ("flash_fwd",),
+    "flash dq": ("flash_dq",),
+    "flash dk/dv": ("flash_dkv",),
+    "LN / add-LN fwd": ("ln_fwd", "ln_residual_fwd"),
+    "LN bwd": ("ln_bwd",),
 }
+
+
+def _mosaic_calls(text: str):
+    """(all Mosaic custom calls, calls by kernel) in a lowered program."""
+    names = re.findall(r'kernel_name = "(\w+)"', text)
+    return text.count("@tpu_custom_call"), {
+        label: sum(names.count(k) for k in kernels)
+        for label, kernels in _MOSAIC_KERNELS.items()}
 
 
 def _check_mosaic_calls(step, layers: int) -> None:
     """Count the Mosaic custom calls in the lowered step, by kernel."""
-    text = step._jitted.lower(*step._lower_avals).as_text()
-    names = re.findall(r'kernel_name = "(\w+)"', text)
-    total = text.count("@tpu_custom_call")
-    per = {label: sum(names.count(k) for k in kernels)
-           for label, kernels in _MOSAIC_KERNELS.items()}
+    total, per = _mosaic_calls(
+        step._jitted.lower(*step._lower_avals).as_text())
     log(f"Mosaic custom calls in the lowered TrainStep: {total} {per}")
     check(total > 0, "the lowered step holds no Mosaic custom call: the "
                      "Pallas kernels did not reach the chip's compiler")
@@ -253,12 +264,43 @@ def _check_mosaic_calls(step, layers: int) -> None:
         check(n == want, f"{label}: {n} Mosaic calls, expected {want}")
 
 
-def _build_trainer(layers: int, hybrid=None):
-    """bench.py's GPT-medium trainer (`_bench_gpt` / `_bench_gpt_multichip`):
-    `_gpt_medium` is the repo's training decoder; the loss beside it in
-    bench.py is a closure, so its five lines are repeated here."""
+def _gpt(layers: int):
+    """The smoke's training decoder at GPT-medium's widths: token and
+    learned position embeddings, `layers` ParallelGPTBlocks (a trivial
+    one-chip mesh, the same code the hybrid shards) and an untied head.
+    `forward` stops before the head: the loss streams it over vocabulary
+    chunks (the blockwise fused cross-entropy)."""
     import paddle_tpu as paddle
-    from bench import _gpt_medium
+    from paddle_tpu import nn
+    from paddle_tpu.distributed import ParallelGPTBlock, comm
+
+    if comm.hybrid_mesh() is None:
+        comm.init_hybrid_mesh(dp=1, mp=1, pp=1, sp=1)
+
+    class GPT(nn.Layer):
+        def __init__(self):
+            super().__init__()
+            self.embed = nn.Embedding(VOCAB, D_MODEL)
+            self.pos = nn.Embedding(SEQ, D_MODEL)
+            self.blocks = nn.LayerList([
+                ParallelGPTBlock(D_MODEL, HEADS, dropout=0.0)
+                for _ in range(layers)])
+            self.head = nn.Linear(D_MODEL, VOCAB)
+
+        def forward(self, ids):
+            pos_ids = paddle.arange(ids.shape[1], dtype="int64")
+            h = self.embed(ids) + self.pos(pos_ids)
+            for blk in self.blocks:
+                h = blk(h)
+            return h
+
+    return GPT()
+
+
+def _build_trainer(layers: int, hybrid=None):
+    """`fleet` bf16 amp + AdamW + `jit.TrainStep` over `_gpt(layers)`,
+    the loss through the blockwise cross-entropy on the pre-head state."""
+    import paddle_tpu as paddle
     from paddle_tpu import nn, optimizer
     from paddle_tpu.distributed import comm, fleet
     from paddle_tpu.distributed.fleet import DistributedStrategy
@@ -273,12 +315,9 @@ def _build_trainer(layers: int, hybrid=None):
     if not hybrid:
         # fleet.init lets dp fill every visible device; the one-chip
         # phase is a one-chip program on a four-chip host too
-        # (_gpt_medium then declares the trivial mesh itself)
+        # (_gpt then declares the trivial mesh itself)
         comm.set_hybrid_mesh(None)
-    model = _gpt_medium()
-    if layers < len(model.blocks):
-        # _gpt_medium takes no depth; cut it before the optimizer sees it
-        model.blocks = nn.LayerList(list(model.blocks)[:layers])
+    model = _gpt(layers)
     wrapped = fleet.distributed_model(model) if hybrid else model
     opt = fleet.distributed_optimizer(
         optimizer.AdamW(learning_rate=1e-4, weight_decay=0.01,
@@ -518,7 +557,7 @@ def main(argv) -> int:
         os.environ["PADDLE_FLASH_DEFAULT"] = "interpret"
         os.environ["PADDLE_FUSED_LN"] = "interpret"
     device = phase_device(rehearse)
-    layers, batch, seq = (2, 4, 128) if rehearse else (LAYERS, 4, 1024)
+    layers, batch, seq = (2, 4, 128) if rehearse else (LAYERS, 4, SEQ)
     if layers != FULL_LAYERS or rehearse:
         log(f"CUT: depth {layers} of {FULL_LAYERS}, batch {batch}, "
             f"seq {seq}; every width is full")

@@ -44,7 +44,6 @@ BLACK_LIST = {"softmax", "log_softmax", "cross_entropy", "mean", "sum",
 # batch_norm is deliberately NOT black-listed: the functional keeps its
 # stat accumulation in f32 internally while applying in the input dtype,
 # so casting bf16 activations up before it would only double HBM traffic
-# (round-5 perf work, tools/PERF.md)
 
 
 class _AmpState(threading.local):

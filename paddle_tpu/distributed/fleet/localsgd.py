@@ -263,12 +263,6 @@ class LocalSGDStep:
             self._guard.observe(self._guard_state)
         return Tensor._wrap(loss, stop_gradient=True)
 
-    def flops_per_step(self):
-        """Cost-analysis FLOPs are not derived for the LocalSGD program
-        (two cached compilations, stacked-replica operands) — report
-        None rather than a wrong number."""
-        return None
-
     def _after_rollback(self):
         """Guard rollback hook: the checkpoint restored the LAYER's
         params; rebuild the per-worker replicas and guard carry."""

@@ -14,7 +14,7 @@
    each chunk's all-gather issued while the next chunk computes.
    Enabled by `PADDLE_TP_OVERLAP=1` (default off: the r6 GSPMD
    sharding-propagation form stays the default until the overlap win is
-   measured on a pod — bench.py's dp x mp pair tracks it).
+   measured across chips: no cell of the benchmark runs a mesh yet).
 
 2. Async DCN-hop gradient reduction ("EQuARX" motivation: the dcn hop
    is the slow, overlappable piece). The r6 hierarchical mesh leaves the

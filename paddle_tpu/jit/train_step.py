@@ -337,11 +337,10 @@ class TrainStep:
         # once — tens of seconds on a large model.
         self._jitted = None
         # observability (ISSUE 8): monotonic step index for the bus, arg
-        # avals kept for the cost-analysis lowering, cached per-step
-        # FLOPs; the jitted program is wrapped by the recompile ledger
+        # avals kept so the step can be lowered again without its
+        # arrays; the jitted program is wrapped by the recompile ledger
         self._n_steps = 0
         self._lower_avals = None
-        self._flops = None
         from ..observability import bus as _bus, ledger as _ledger
 
         # quantized-compute byte attribution (ISSUE 19): resident matmul-
@@ -710,38 +709,6 @@ class TrainStep:
         self._refresh_zero_pads()
         self._jitted = None
         self._lower_avals = None
-        self._flops = None
-
-    # -- achieved-FLOPs accounting (observability/mfu.py) ------------------
-    def flops_per_step(self):
-        """Per-device FLOPs of ONE compiled step — forward + backward +
-        optimizer update, priced by XLA's own cost model over the exact
-        program this step dispatches (re-lowered from the stored arg
-        avals: one re-trace, no compile, no device work). None before
-        the first call or when the backend has no cost model."""
-        if self._delegate is not None:
-            return self._delegate.flops_per_step()
-        if self._flops is not None:
-            return self._flops
-        if self._jitted is None or self._lower_avals is None:
-            return None
-        from ..observability import mfu as _mfu
-
-        self._flops = _mfu.flops_of_lowered(
-            self._jitted.lower(*self._lower_avals))
-        return self._flops
-
-    def mfu_pct(self, step_seconds: float):
-        """Model-FLOPs utilization of a measured step time, percent of
-        this device kind's peak (None off-TPU without the
-        ``PADDLE_OBS_PEAK_FLOPS`` override). The peak check runs FIRST:
-        without a denominator the cost-analysis re-trace would be paid
-        only to discard its result (bench.py asks per benched model)."""
-        from ..observability import mfu as _mfu
-
-        if _mfu.peak_flops() is None:
-            return None
-        return _mfu.mfu_pct(self.flops_per_step(), step_seconds)
 
     # -- persisted step state (the auto_checkpoint `extras` contract) -----
     def state_dict(self):
@@ -885,9 +852,11 @@ class TrainStep:
                 in_raws, label_raws,
             )
             if self._lower_avals is None:
-                # shape/dtype skeleton of the call signature, kept for the
-                # cost-analysis lowering (flops_per_step): donated buffers
-                # are invalidated after dispatch, avals hold no storage
+                # shape/dtype skeleton of the call signature, kept so the
+                # step can be lowered again without its arrays
+                # (chip_smoke.py counts the Mosaic calls of the lowered
+                # text): donated buffers are invalidated after dispatch,
+                # avals hold no storage
                 self._lower_avals = jax.tree_util.tree_map(
                     lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)
                     if hasattr(x, "shape") and hasattr(x, "dtype") else x,

@@ -1,11 +1,11 @@
 """Attention routing policy — flash attention by DEFAULT on the causal
 decoder hot path (ISSUE 4 tentpole).
 
-The Pallas flash kernel (ops/pallas/flash_attention.py) has been the
-measured-faster path since round 5 (1.3 ms vs 3.6 ms dense at S=2048
-causal) but was only reachable through an opt-in flag plus the
-`PADDLE_BENCH_GPT_FLASH` bench side channel. This module centralizes the
-routing decision so `nn.MultiHeadAttention` and
+The Pallas flash kernel (ops/pallas/flash_attention.py) never writes
+the [S, S] scores to HBM; in the `gpt2-medium.train` cell its three
+kernels are 24.9 % of the step at 14.25 / 10.72 % of their rooflines
+(PERF.md §5; ledger, PR 31). This module centralizes the routing
+decision so `nn.MultiHeadAttention` and
 `distributed.ParallelMultiHeadAttention` pick the kernel automatically
 whenever it computes the same function as the dense path:
 

@@ -45,7 +45,8 @@ def batch_norm(
     bshape[ch] = x._data.shape[ch]
 
     if use_batch_stats:
-        # TPU-first formulation (round-5 perf work, tools/PERF.md):
+        # TPU-first formulation (not measured on the chip: no cell runs
+        # a batch norm):
         #  - stats accumulate in f32 but the normalization APPLIES in the
         #    input dtype, so bf16 activations are never round-tripped
         #    through f32 HBM writes (the reference's CUDA kernel does the

@@ -1,4 +1,4 @@
-"""Observability plane (ISSUE 8): unified telemetry bus, step/MFU
+"""Observability plane (ISSUE 8): unified telemetry bus, step
 metrics, recompile ledger.
 
 - :mod:`.bus` — the one per-rank JSONL event schema every runtime
@@ -13,9 +13,6 @@ metrics, recompile ledger.
 - :mod:`.ledger` — jit cache misses as ``recompile`` records with arg
   shape/dtype/donation fingerprints, compile seconds, and a
   recompile-storm detector naming the changing fingerprint field.
-- :mod:`.mfu` — achieved-FLOPs from ``lowered.cost_analysis()`` against
-  a per-device peak table (the PERF.md attribution protocol,
-  mechanized).
 - :mod:`.monitor` — the LIVE fleet monitor (ISSUE 14): incremental
   per-rank stream cursors, straggler ranking, online percentile
   digests, and the incident correlator; embedded in the elastic
@@ -28,12 +25,12 @@ the per-rank streams into a chrome trace + summary.
 """
 from __future__ import annotations
 
-from . import bus, ledger, metrics, mfu, monitor
+from . import bus, ledger, metrics, monitor
 from .bus import current_step, emit, emit_span, read_stream, set_step
 from .monitor import FleetMonitor
 
 __all__ = [
-    "bus", "metrics", "ledger", "mfu", "monitor",
+    "bus", "metrics", "ledger", "monitor",
     "emit", "emit_span", "set_step", "current_step", "read_stream",
     "FleetMonitor",
 ]

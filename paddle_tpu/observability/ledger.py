@@ -29,9 +29,7 @@ event-duration stream for backend compile keys, so compiles that happen
 OUTSIDE an instrumented wrapper (eager ops, collectives) still land on
 the bus as ``backend_compile`` rows with their true compile seconds.
 
-``compile_count()`` is the process-wide miss total — ``bench.py``
-records it per round so compile-count drift is tracked next to the
-compile-time drift table (report-only, tools/bench_continuity.py).
+``compile_count()`` is the process-wide miss total.
 ``compile_seconds()`` is the wall time of those compiling calls, summed:
 the part of a process's set-up that went into compiling its steps.
 
@@ -143,7 +141,9 @@ class LedgeredFunction:
         self._seen: set = set()
         self.compiles = 0
 
-    # the lower/cost-analysis surface stays reachable (mfu.py)
+    # lowering stays reachable through the wrapper: chip_smoke.py counts
+    # the Mosaic calls of a lowered TrainStep, tests/test_trace_names.py
+    # reads the module's name from the lowered text
     def lower(self, *args, **kwargs):
         return self._jitted.lower(*args, **kwargs)
 

@@ -1,7 +1,7 @@
 """Fused LayerNorm — forward AND backward — as Pallas TPU kernels, plus
 the fused residual-add+LayerNorm the pre-LN decoder block wants.
 
-Why a hand kernel (tools/PERF.md GPT chapter): under bf16 amp the dense
+Why a hand kernel: under bf16 amp the dense
 `layer_norm` functional sits on the AMP black list, so every decoder LN
 round-trips its activation through f32 HBM (cast up, two reduction
 passes, cast down) — 2 LNs x 24 layers x [B*S, 1024] per step. The

@@ -16,9 +16,8 @@ store a layer instead of per-head K and V.
 `TransformerLM` is the first in-repo implementation: token + learned position
 embeddings, a `ParallelGPTBlock` stack (tensor-parallel attention/MLP —
 trivial on one chip, sharded over 'mp' on a hybrid mesh, same code
-path), final LayerNorm and an untied vocab head — the same shape
-bench.py's GPT-medium proxy uses, so serving benches and training
-benches price the same decoder.
+path), final LayerNorm and an untied vocab head. The benchmark's
+`gpt2` family trains and serves this class (`benchmarks/families/gpt2/`).
 """
 from __future__ import annotations
 

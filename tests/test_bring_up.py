@@ -5,6 +5,7 @@ process does BEFORE and WHILE it first touches jax, which the suite's own
 process (conftest has long forced the CPU backend) cannot show.
 """
 import json
+import math
 import os
 import subprocess
 import sys
@@ -128,3 +129,26 @@ class TestNoSilentFallback:
             == ("parallel", "arbitrary")
         with pytest.raises(ValueError, match="dimension_semantics"):
             _tpu_params("parallel", "sequential")
+
+
+_SMOKE_TRAINER = """
+import json, sys
+import chip_smoke
+model, _, step = chip_smoke._build_trainer(2)
+ids, labels = chip_smoke._batch(2, 128)
+loss = float(step(ids, labels).numpy())
+print(json.dumps([loss, len(model.blocks), "bench" in sys.modules]))
+"""
+
+
+def test_smoke_trainer_stands_on_the_program():
+    """`chip_smoke._build_trainer` builds its decoder from the program's
+    own parts and takes a finite step (the interpreter standing in for
+    Mosaic, as `--rehearse` sets it), with no module named `bench`."""
+    r = _py(_SMOKE_TRAINER, timeout=60,
+            env={"PADDLE_FLASH_DEFAULT": "interpret",
+                 "PADDLE_FUSED_LN": "interpret"})
+    assert r.returncode == 0, r.stderr[-2000:]
+    loss, layers, has_bench = json.loads(r.stdout.splitlines()[-1])
+    assert math.isfinite(loss) and 0 < loss < 20, loss
+    assert layers == 2 and not has_bench

@@ -46,9 +46,9 @@ import pytest  # noqa: E402
 @pytest.mark.slow
 def test_dryrun_multichip_32():
     """Pod-scale factorings (ISSUE 6 / ROADMAP 3): dp8 x mp2 x pp2 and the
-    32-device sharded-flash dp16 x mp2 step, with per-phase compile_s
-    stamps for the bench_continuity report-only drift check. Subprocess:
-    the in-process harness is pinned to 8 virtual devices."""
+    32-device sharded-flash dp16 x mp2 step, each phase printing its
+    compile_s stamp (for a person reading the dryrun: no tool reads it).
+    Subprocess: the in-process harness is pinned to 8 virtual devices."""
     import os
     import subprocess
     import sys

@@ -1,4 +1,8 @@
 """Round-4 hygiene coverage (VERDICT r3 item 10 + weak #5/#7/#8)."""
+import json
+import os
+import re
+
 import numpy as np
 import pytest
 
@@ -525,260 +529,6 @@ class TestTpulintGate:
         assert not rule.default_enabled  # CLI opt-in (--alias)
 
 
-class TestBenchContinuity:
-    """tools/bench_continuity.py (ISSUE 4 satellite, VERDICT weak #2
-    made enforceable): the latest BENCH_r*.json pair must not hide a
-    >10% per-metric median regression that the newer round left
-    unannotated."""
-
-    @staticmethod
-    def _tool():
-        import importlib.util
-        import os
-
-        path = os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            "tools", "bench_continuity.py",
-        )
-        spec = importlib.util.spec_from_file_location(
-            "bench_continuity", path
-        )
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod
-
-    def _write_pair(self, tmp_path, prev_extra, cur_extra):
-        import json
-
-        for n, extra in (("04", prev_extra), ("05", cur_extra)):
-            rec = {"parsed": {
-                "metric": "resnet50_bf16_train_imgs_per_sec",
-                "value": extra.pop("_value", 100.0),
-                "extra": extra,
-            }}
-            (tmp_path / f"BENCH_r{n}.json").write_text(json.dumps(rec))
-
-    def test_repo_pair_passes(self):
-        import os
-
-        bc = self._tool()
-        root = os.path.dirname(
-            os.path.dirname(os.path.abspath(__file__))
-        )
-        rc, lines = bc.check(root)
-        assert rc == 0, "\n".join(lines)
-
-    def test_unannotated_regression_fails(self, tmp_path):
-        bc = self._tool()
-        self._write_pair(
-            tmp_path,
-            {"_value": 100.0, "gpt_medium_bf16_tokens_per_sec": 27000.0},
-            {"_value": 100.0, "gpt_medium_bf16_tokens_per_sec": 20000.0,
-             "gpt_medium_bf16_tokens_per_sec_spread":
-                 {"n": 3, "median": 20000.0}},
-        )
-        rc, lines = bc.check(str(tmp_path))
-        assert rc == 1
-        assert any("gpt_medium_bf16_tokens_per_sec" in l
-                   and "REGRESS" in l for l in lines)
-
-    def test_note_annotation_waives(self, tmp_path):
-        bc = self._tool()
-        self._write_pair(
-            tmp_path,
-            {"_value": 100.0, "gpt_medium_bf16_tokens_per_sec": 27000.0},
-            {"_value": 100.0, "gpt_medium_bf16_tokens_per_sec": 20000.0,
-             "gpt_medium_bf16_tokens_per_sec_spread":
-                 {"n": 3, "median": 20000.0},
-             "note": "gpt_medium_bf16_tokens_per_sec regressed: seq "
-                     "doubled to 2048 this round"},
-        )
-        rc, lines = bc.check(str(tmp_path))
-        assert rc == 0, "\n".join(lines)
-        assert any("waived" in l for l in lines)
-
-    def test_guard_overhead_gate(self, tmp_path):
-        """ISSUE 5: the sentinel-on vs sentinel-off GPT pair is gated at
-        <2% overhead; a breach fails like any unannotated regression,
-        and a note naming guard_overhead_pct waives it."""
-        bc = self._tool()
-        base = {"_value": 100.0,
-                "gpt_medium_bf16_tokens_per_sec": 27000.0}
-        ok_cur = {"_value": 100.0,
-                  "gpt_medium_bf16_tokens_per_sec": 27000.0,
-                  "gpt_medium_bf16_tokens_per_sec_spread":
-                      {"n": 3, "median": 27000.0},
-                  "guard_overhead_pct": 1.4}
-        self._write_pair(tmp_path, dict(base), dict(ok_cur))
-        rc, lines = bc.check(str(tmp_path))
-        assert rc == 0, "\n".join(lines)
-        assert any("guard_overhead_pct" in l and "ok" in l
-                   for l in lines)
-        bad_cur = dict(ok_cur)
-        bad_cur["guard_overhead_pct"] = 4.2
-        self._write_pair(tmp_path, dict(base), bad_cur)
-        rc, lines = bc.check(str(tmp_path))
-        assert rc == 1
-        assert any("guard_overhead_pct" in l and "REGRESS" in l
-                   for l in lines)
-        waived_cur = dict(ok_cur)
-        waived_cur["guard_overhead_pct"] = 4.2
-        waived_cur["note"] = ("guard_overhead_pct over budget: "
-                              "PADDLE_GUARD_CHECK_PARAMS=1 this round")
-        self._write_pair(tmp_path, dict(base), waived_cur)
-        rc, lines = bc.check(str(tmp_path))
-        assert rc == 0, "\n".join(lines)
-
-    def test_prefix_sibling_annotation_does_not_waive(self, tmp_path):
-        """Annotating x_per_sec_dense must NOT waive its prefix sibling
-        x_per_sec — whole-name matching only."""
-        bc = self._tool()
-        self._write_pair(
-            tmp_path,
-            {"_value": 100.0, "gpt_medium_bf16_tokens_per_sec": 27000.0},
-            {"_value": 100.0, "gpt_medium_bf16_tokens_per_sec": 20000.0,
-             "gpt_medium_bf16_tokens_per_sec_spread":
-                 {"n": 3, "median": 20000.0},
-             "note": "gpt_medium_bf16_tokens_per_sec_dense regressed: "
-                     "escape hatch re-measured"},
-        )
-        rc, lines = bc.check(str(tmp_path))
-        assert rc == 1, "\n".join(lines)
-
-    def test_incomparable_declaration_waives_all(self, tmp_path):
-        bc = self._tool()
-        self._write_pair(
-            tmp_path,
-            {"_value": 200.0, "bert_base_bf16_samples_per_sec": 1300.0},
-            {"_value": 100.0, "bert_base_bf16_samples_per_sec": 900.0,
-             "bert_base_bf16_samples_per_sec_spread":
-                 {"n": 3, "median": 900.0},
-             "incomparable_to_prev": "methodology change"},
-        )
-        rc, lines = bc.check(str(tmp_path))
-        assert rc == 0, "\n".join(lines)
-
-    def test_quant_byte_keys_are_gated(self, tmp_path):
-        """Round-19 checkpoint/moment byte keys are static arithmetic
-        (zero noise): a >10% payload growth means a layer silently fell
-        off the narrow path and must fail the gate, unlike the timed
-        report-only byte keys of round 11."""
-        bc = self._tool()
-        assert bc.metric_direction("q_ckpt_payload_mb") == -1
-        assert bc.metric_direction("q_ckpt_reduction_x") == 1
-        assert bc.metric_direction(
-            "gpt_medium_bf16_dp_q8_comm_mb") is None  # r11: report-only
-        self._write_pair(
-            tmp_path,
-            {"q_ckpt_payload_mb": 100.0},
-            {"q_ckpt_payload_mb": 130.0,
-             "serve_gpt_medium_tokens_per_sec_b8_q8w_spread":
-                 {"n": 3, "median": 900.0}},
-        )
-        rc, lines = bc.check(str(tmp_path))
-        assert rc != 0
-        assert any("q_ckpt_payload_mb" in ln for ln in lines)
-
-    # -- MULTICHIP compile-time drift: report-only -> GATED (ISSUE 14
-    # satellite, the ROADMAP item-2 carry-over) -------------------------
-    def _write_multichip_pair(self, tmp_path, prev_phases, cur_phases,
-                              **cur_top):
-        import json
-
-        def tail(phases):
-            return "\n".join(
-                f"dryrun_multichip(8): {name} loss=2.5000 "
-                f"compile_s={v} OK" for name, v in phases.items())
-
-        for n, phases, top in (("04", prev_phases, {}),
-                               ("05", cur_phases, cur_top)):
-            rec = {"n_devices": 8, "rc": 0, "ok": True,
-                   "tail": tail(phases)}
-            rec.update(top)
-            (tmp_path / f"MULTICHIP_r{n}.json").write_text(
-                json.dumps(rec))
-
-    def test_compile_drift_within_budget_passes(self, tmp_path):
-        bc = self._tool()
-        self._write_multichip_pair(
-            tmp_path, {"dp8xmp2 TrainStep": 10.0},
-            {"dp8xmp2 TrainStep": 12.0})
-        rc, lines = bc.check(str(tmp_path))
-        assert rc == 0, "\n".join(lines)
-        assert any("ok      compile_s[dp8xmp2 TrainStep]" in l
-                   for l in lines)
-
-    def test_unannotated_compile_regression_fails(self, tmp_path):
-        bc = self._tool()
-        self._write_multichip_pair(
-            tmp_path, {"dp8xmp2 TrainStep": 10.0, "dp GPT": 5.0},
-            {"dp8xmp2 TrainStep": 14.0, "dp GPT": 5.1})
-        rc, lines = bc.check(str(tmp_path))
-        assert rc == 1, "\n".join(lines)
-        assert any("REGRESS compile_s[dp8xmp2 TrainStep]" in l
-                   for l in lines)
-        assert any("FAIL" in l for l in lines)
-
-    def test_compile_regression_waived_by_note_or_declaration(
-            self, tmp_path):
-        bc = self._tool()
-        # phase named in the MULTICHIP note — same mechanism as the
-        # perf gate's extra.note
-        self._write_multichip_pair(
-            tmp_path, {"dp8xmp2 TrainStep": 10.0},
-            {"dp8xmp2 TrainStep": 14.0},
-            note="dp8xmp2 TrainStep compile grew: zero1 padding "
-                 "constraint added this round")
-        rc, lines = bc.check(str(tmp_path))
-        assert rc == 0, "\n".join(lines)
-        assert any("waived  compile_s[dp8xmp2 TrainStep]" in l
-                   for l in lines)
-        # whole-record incomparable declaration
-        self._write_multichip_pair(
-            tmp_path, {"dp8xmp2 TrainStep": 10.0},
-            {"dp8xmp2 TrainStep": 20.0},
-            incomparable_to_prev="xla version bumped")
-        rc, lines = bc.check(str(tmp_path))
-        assert rc == 0, "\n".join(lines)
-
-    def test_compile_prefix_sibling_annotation_does_not_waive(
-            self, tmp_path):
-        """Whole-name matching, like the perf gate: a note naming
-        'dp GPT flash' must NOT waive its prefix sibling 'dp GPT'."""
-        bc = self._tool()
-        self._write_multichip_pair(
-            tmp_path,
-            {"dp GPT": 10.0, "dp GPT flash": 10.0},
-            {"dp GPT": 14.0, "dp GPT flash": 14.0},
-            note="dp GPT flash: new flash kernel this round")
-        rc, lines = bc.check(str(tmp_path))
-        assert rc == 1, "\n".join(lines)
-        assert any("REGRESS compile_s[dp GPT]" in l for l in lines)
-        assert any("waived  compile_s[dp GPT flash]" in l
-                   for l in lines)
-
-    def test_new_phase_stays_report_only(self, tmp_path):
-        bc = self._tool()
-        self._write_multichip_pair(
-            tmp_path, {"dp GPT": 5.0},
-            {"dp GPT": 5.0, "dp16xmp2 flash": 30.0})
-        rc, lines = bc.check(str(tmp_path))
-        assert rc == 0, "\n".join(lines)
-        assert any("report  compile_s[dp16xmp2 flash]" in l and
-                   "(new)" in l for l in lines)
-
-    def test_improvements_and_small_deltas_pass(self, tmp_path):
-        bc = self._tool()
-        self._write_pair(
-            tmp_path,
-            {"_value": 100.0, "x_per_sec": 1000.0, "y_ms": 10.0},
-            {"_value": 108.0, "x_per_sec": 950.0, "y_ms": 9.0,
-             "x_per_sec_spread": {"n": 3, "median": 950.0}},
-        )
-        rc, lines = bc.check(str(tmp_path))
-        assert rc == 0, "\n".join(lines)
-
-
 class TestDatasetTensorNamespaces:
     def test_tensor_module_paths(self):
         import paddle_tpu as paddle
@@ -803,3 +553,108 @@ class TestDatasetTensorNamespaces:
         assert feat.shape == (13,)
         batches = list(paddle.batch(reader, 4)())
         assert len(batches[0]) == 4
+
+
+class TestOneYardstick:
+    """Since PR 32 the only code that measures speed is under
+    `benchmarks/`, the only table of peaks is `benchmarks/peaks.json`,
+    and the documents name only what the tree holds."""
+
+    ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    #: distinct `PADDLE_<FAMILY>_*` names under `paddle_tpu/` (ROADMAP
+    #: D2). A pin moves one way: a removed knob lowers it in the same PR.
+    KNOB_PINS = {
+        "SERVE": 23, "CTL": 12, "OBS": 10, "GUARD": 9, "COLL": 7,
+        "MON": 6, "RESHARD": 3, "FLASH+FUSED+CE": 5, "the rest": 23,
+    }
+
+    @classmethod
+    def _py_files(cls, *tops):
+        for top in tops:
+            path = os.path.join(cls.ROOT, top)
+            if os.path.isfile(path):
+                yield path
+            for r, dirs, fns in os.walk(path):
+                dirs[:] = [d for d in dirs if d != "__pycache__"]
+                yield from (os.path.join(r, fn) for fn in sorted(fns)
+                            if fn.endswith(".py"))
+
+    @classmethod
+    def _knobs(cls, *tops):
+        from tools.tpulint.rules.env_knobs import _KNOB_RE
+
+        found = set()
+        for fp in cls._py_files(*tops):
+            with open(fp, encoding="utf-8") as fh:
+                found.update(_KNOB_RE.findall(fh.read()))
+        return found
+
+    @pytest.mark.parametrize("family", list(KNOB_PINS))
+    def test_knob_families_only_go_down(self, family):
+        by_pin = {}
+        for k in self._knobs("paddle_tpu"):
+            fam = k.split("_")[1]
+            key = next((key for key in self.KNOB_PINS
+                        if fam in key.split("+")), "the rest")
+            by_pin.setdefault(key, []).append(k)
+        names, pin = sorted(by_pin.get(family, [])), self.KNOB_PINS[family]
+        assert len(names) <= pin, (
+            f"{len(names)} PADDLE_* names of {family}, pinned at {pin}: a "
+            f"new knob needs two callers that differ: {names}")
+        assert len(names) == pin, (
+            f"{len(names)} PADDLE_* names of {family}, pinned at {pin}: "
+            "lower the pin")
+
+    def test_readme_documents_no_dead_knob(self):
+        """The reverse of tpulint's `env-knob-docs`: a name the README
+        documents is read by some program file."""
+        from tools.tpulint.rules.env_knobs import _KNOB_RE
+
+        with open(os.path.join(self.ROOT, "README.md")) as fh:
+            documented = set(_KNOB_RE.findall(fh.read()))
+        read = self._knobs("paddle_tpu", "paddle", "tools", "benchmarks",
+                           "chip_smoke.py", "__graft_entry__.py")
+        dead = sorted(
+            k for k in documented - read
+            # a documented prefix (`PADDLE_SERVE_ADMIT_*`) stands for
+            # the names read under it
+            if not (k.endswith("_") and any(r.startswith(k) for r in read)))
+        assert not dead, f"README.md documents knobs nothing reads: {dead}"
+
+    @pytest.mark.parametrize(
+        "doc", ["README.md", ".claude/skills/verify/SKILL.md"])
+    def test_cited_files_exist(self, doc):
+        """Every back-ticked path ending in .py, .md or .json that starts
+        with a top-level name of the repo is there."""
+        with open(os.path.join(self.ROOT, doc)) as fh:
+            text = fh.read()
+        tops = set(os.listdir(self.ROOT))
+        cited = {
+            m for m in re.findall(r"`([\w./-]+\.(?:py|md|json))", text)
+            if m.split("/")[0] in tops}
+        assert cited, f"{doc} cites no file: the pattern is broken"
+        gone = sorted(
+            c for c in cited
+            if not os.path.exists(os.path.join(self.ROOT, c)))
+        assert not gone, f"{doc} cites files that do not exist: {gone}"
+
+    def test_one_table_of_peaks(self):
+        """No `.py` outside `benchmarks/` holds a TPU peak (FLOP/s or
+        bytes/s of any generation the deleted table had), and
+        `benchmarks/peaks.json` is what `chip_smoke.py` checks its
+        device kind against."""
+        peak = re.compile(
+            r"\b(?:918|459|197|275|123|45)e12\b|\b(?:819|1640|2765)e9\b")
+        held = []
+        for fp in self._py_files("paddle_tpu", "paddle", "tools", "tests",
+                                 "chip_smoke.py", "__graft_entry__.py"):
+            with open(fp, encoding="utf-8") as fh:
+                if peak.search(fh.read()):
+                    held.append(os.path.relpath(fp, self.ROOT))
+        assert not held, f"a second table of peaks: {held}"
+        with open(os.path.join(self.ROOT, "benchmarks", "peaks.json")) as fh:
+            peaks = json.load(fh)
+        # the kind a v5e chip reports, and the key the smoke reads
+        assert peaks["TPU v5 lite"]["flops_per_s"] > 0
+        with open(os.path.join(self.ROOT, "chip_smoke.py")) as fh:
+            assert "peaks.json" in fh.read()

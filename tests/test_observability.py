@@ -1,6 +1,6 @@
 """Observability plane tests (ISSUE 8): unified bus schema + compat
 aliases, step-metrics cadence (zero extra host syncs), recompile
-ledger + storm detector, MFU accounting, timeline merge, trace-window
+ledger + storm detector, timeline merge, trace-window
 arm/disarm."""
 import json
 import os
@@ -10,11 +10,11 @@ import pytest
 
 import jax
 
-from paddle_tpu.observability import bus, ledger, metrics, mfu
+from paddle_tpu.observability import bus, ledger, metrics
 
 _OBS_KNOBS = (
     "PADDLE_OBS_DIR", "PADDLE_OBS_BUS_FILE", "PADDLE_OBS_STEP_METRICS",
-    "PADDLE_OBS_STORM_N", "PADDLE_OBS_PEAK_FLOPS",
+    "PADDLE_OBS_STORM_N",
     "PADDLE_OBS_TRACE_AT_STEP", "PADDLE_OBS_TRACE_STEPS",
     "PADDLE_OBS_TRACE_DIR", "PADDLE_OBS_TRACE_MAX",
     "PADDLE_OBS_TRACE_ON_TRIP",
@@ -319,35 +319,6 @@ class TestRecompileLedger:
         joined = "\n".join(lines)
         assert "float32[4] -> bfloat16[4]" in joined
         assert "(gone)" in joined and "(new)" in joined
-
-
-# ---------------------------------------------------------------------------
-# MFU accounting
-# ---------------------------------------------------------------------------
-
-
-class TestMfu:
-    def test_flops_and_mfu(self, obs_env):
-        _, step = _mk_step()
-        step(_X, _Y)
-        flops = step.flops_per_step()
-        assert flops is not None and flops > 0
-        # cached: second ask returns the same object without re-lowering
-        assert step.flops_per_step() == flops
-        obs_env.setenv("PADDLE_OBS_PEAK_FLOPS", str(flops * 100.0))
-        # peak = 100x the per-step flops per second; a 10ms step does
-        # flops/0.01 = 100x flops per second -> exactly 100% MFU
-        assert step.mfu_pct(0.01) == pytest.approx(100.0, abs=0.5)
-
-    def test_no_peak_no_mfu(self, obs_env):
-        if jax.default_backend() != "cpu":
-            pytest.skip("device peak known")
-        assert mfu.peak_flops() is None
-        assert mfu.mfu_pct(1e9, 0.01) is None
-
-    def test_peak_table_match(self, obs_env):
-        obs_env.setenv("PADDLE_OBS_PEAK_FLOPS", "2.5e13")
-        assert mfu.peak_flops() == 2.5e13
 
 
 # ---------------------------------------------------------------------------

@@ -1,5 +1,6 @@
 """Pallas flash-attention kernel, interpreter mode (CPU CI; the compiled
-kernel runs on real TPU — bench.py carries its timing)."""
+kernel runs on the chip: `flash_fwd_roofline` / `flash_bwd_roofline` of
+the `gpt2-medium.train` cell carry its timing)."""
 import numpy as np
 import pytest
 
@@ -95,9 +96,9 @@ def test_mha_blockwise_stays_on_xla_path_on_cpu():
 
 
 class TestParallelMHAFlashRouting:
-    """ParallelMultiHeadAttention(use_flash_attention=True): the GPT
-    bench routing (PADDLE_BENCH_GPT_FLASH) — flash core must match the
-    dense softmax path, forward and backward, on shared weights."""
+    """ParallelMultiHeadAttention(use_flash_attention=True): the flash
+    core must match the dense softmax path, forward and backward, on
+    shared weights."""
 
     def _pair(self, T=128, d=32, heads=2):
         import paddle_tpu as paddle

@@ -312,3 +312,30 @@ def test_layer_norm_kernel_names_compiled_for_v5e(one_chip):
 
     got = _custom_calls(jax.grad(ln, argnums=(0, 1, 2, 3)), x, x, w, w)
     assert got == {"ln_fwd", "ln_bwd", "ln_residual_fwd"}, got
+
+
+def test_smoke_counts_the_kernels_lowered_for_v5e(one_chip):
+    """`chip_smoke.py` finds each kernel in a program lowered for the
+    chip by the name its `pallas_call` carries: one flash forward, dq and
+    dk/dv, and for a LayerNorm and an add-LayerNorm two forwards and two
+    backwards."""
+    import chip_smoke
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention
+    from paddle_tpu.ops.pallas.layer_norm import (fused_add_layer_norm,
+                                                  fused_layer_norm)
+
+    qkv = _aval(one_chip, 2, 16, 1024, 64)
+    x = _aval(one_chip, 2048, 1024)
+    w = _aval(one_chip, 1024, dtype=jnp.float32)
+
+    def block(q, k, v, x, y, w, b):
+        o = flash_attention(q, k, v, True, 256, 256, None, False, 0, 0)
+        s, out = fused_add_layer_norm(
+            fused_layer_norm(x, w, b, 1e-5, False), y, w, b, 1e-5, False)
+        return sum(t.astype(jnp.float32).sum() for t in (o, s, out))
+
+    text = jax.jit(jax.grad(block, argnums=tuple(range(7)))).lower(
+        qkv, qkv, qkv, x, x, w, w).as_text()
+    assert chip_smoke._mosaic_calls(text) == (7, {
+        "flash fwd": 1, "flash dq": 1, "flash dk/dv": 1,
+        "LN / add-LN fwd": 2, "LN bwd": 2})
