@@ -391,12 +391,49 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
 # ---------------------------------------------------------------------------
 
 
+def _kv_append_route(c, u):
+    """Does this append take the in-place `kv_append` kernel
+    (ops/pallas/kv_append.py)? None = XLA's slot-by-slot scatter, else
+    the kernel's `interpret` flag. Decided from what the write is handed
+    and nothing else: one new row a slot (`u` is [B, H, 1, D]: the decode
+    step) into a float32 or bfloat16 [B, H, cap, D] array whose capacity
+    is whole 128-lane tiles and whose head_dim is under a tile's 128
+    lanes — only then does the chip store capacity in the lanes, so that
+    the kernel's `[B, H, D, cap]` view is a bitcast; at D >= 128 the view
+    would be a copy of the whole cache tensor. A non-trivial mesh keeps
+    the scatter (GSPMD partitions it; a bare pallas_call it would not).
+    Off the TPU the kernel runs only in the interpreter, for tests, by
+    `PADDLE_FLASH_DEFAULT=interpret`."""
+    import jax.numpy as jnp
+
+    from ...ops.pallas.kv_append import LANES
+
+    if c.ndim != 4 or c.dtype not in (jnp.float32, jnp.bfloat16):
+        return None
+    B, H, cap, D = c.shape
+    if u.shape != (B, H, 1, D) or cap % LANES or D >= LANES:
+        return None
+    on_tpu = jax.default_backend() == "tpu"
+    if not on_tpu and not _interpret_forced():
+        return None
+    mesh = _routing_mesh()
+    if mesh is not None and mesh.size > 1:
+        return None
+    return not on_tpu
+
+
 def cache_update(cache, new, pos):
     """Write the [B, H, Sq, D] new K or V rows into the static-capacity
     [B, H, cap, D] `cache` Tensor at per-slot write positions ``pos``
     ([B] int32 Tensor): one vmapped dynamic_update_slice — no concat, no
     shape change, so the compiled decode program is traced ONCE and the
     cache buffer can be donated. Inference-only (no VJP).
+
+    The decode step's write (one row a slot into a plain float cache, on
+    the chip) is the same write as one in-place Pallas kernel instead
+    (`_kv_append_route`, ops/pallas/kv_append.py): XLA:TPU expands the
+    vmapped slice into a serial loop of B iterations a cache tensor.
+    `observability.metrics.kv_append_routes()` counts both ways.
 
     A block-quantized cache (``quantized_comm.QuantKV`` — int8/fp8
     payload at the full cache shape + per-row-block f32 scales, ISSUE
@@ -415,11 +452,14 @@ def cache_update(cache, new, pos):
     import jax.numpy as jnp
 
     from ...distributed import quantized_comm as qc
+    from ...observability.metrics import (
+        record_kv_append_route as _count_route)
     from ...serving import paged_kv as pk
 
     if isinstance(cache, pk.PagedKV):
         if isinstance(cache.kv, qc.QuantKV):
             def fpq(kq, ks, tab, u, p):
+                _count_route("scatter")
                 out = pk.paged_write(qc.QuantKV(kq, ks), tab, u,
                                      jnp.asarray(p, jnp.int32))
                 return out.q, out.scale
@@ -430,25 +470,37 @@ def cache_update(cache, new, pos):
             return pk.PagedKV(qc.QuantKV(oq, osc), cache.table)
 
         def fp(kv, tab, u, p):
+            _count_route("scatter")
             return pk.paged_write(kv, tab, u, jnp.asarray(p, jnp.int32))
 
         out = AG.apply_nondiff(fp, (cache.kv, cache.table, new, pos))
         return pk.PagedKV(out, cache.table)
 
-    def write(c, u, p):
+    def scatter(c, u, p):
         return jax.vmap(
             lambda cb, ub, pb: jax.lax.dynamic_update_slice_in_dim(
                 cb, ub.astype(cb.dtype), pb, axis=1
             )
         )(c, u, jnp.asarray(p, jnp.int32))
 
+    def write(c, u, p):
+        interpret = _kv_append_route(c, u)
+        if interpret is None:
+            _count_route("scatter")
+            return scatter(c, u, p)
+        from ...ops.pallas.kv_append import kv_append
+
+        _count_route("kernel")
+        return kv_append(c, u, p, interpret)
+
     if isinstance(cache, qc.QuantKV):
         bs = int(cache.q.shape[-1]) // int(cache.scale.shape[-1])
         qdtype = "int8" if cache.q.dtype == jnp.int8 else "fp8"
 
         def fq(cq, cs, u, p):
+            _count_route("scatter")
             uq, us = qc.quantize_lastaxis(u, dtype=qdtype, block=bs)
-            return write(cq, uq, p), write(cs, us, p)
+            return scatter(cq, uq, p), scatter(cs, us, p)
 
         out = AG.apply_nondiff(fq, (cache.q, cache.scale, new, pos))
         return qc.QuantKV(out[0], out[1])

@@ -341,3 +341,29 @@ def sampler_steps() -> dict:
     sampler's branch their active requests call for. Process-wide and
     readable after the engine is gone, as :func:`expert_load` is."""
     return dict(_sampler_steps)
+
+
+# ---------------------------------------------------------------------------
+# K/V append engagement: which way each `cache_update` call was lowered,
+# counted where the call is traced
+# ---------------------------------------------------------------------------
+
+_kv_append_routes = {"kernel": 0, "scatter": 0}
+
+
+def record_kv_append_route(route: str) -> None:
+    """Count one `nn.functional.attention.cache_update` call: ``kernel``
+    when it became the in-place `kv_append` Pallas kernel, ``scatter``
+    when it kept XLA's write (prefill, speculative steps, quantized and
+    paged caches, a sharded cache, the CPU). Called as the write is
+    traced, so a compiled step counts once however often it runs."""
+    _kv_append_routes[route] += 1
+
+
+def kv_append_routes() -> dict:
+    """{"kernel": n, "scatter": n} — running totals of the
+    `cache_update` calls traced in this process, by the way each was
+    lowered: a `DecodeStep` over a plain float cache on the chip adds
+    2 x layers to ``kernel``, a `PrefillStep` as many to ``scatter``.
+    Process-wide, as :func:`sampler_steps` is."""
+    return dict(_kv_append_routes)

@@ -684,6 +684,43 @@ class TestInferenceEngine:
         assert moved["greedy"] + moved["sampling"] == len(dispatched) == 12
         assert moved["sampling"] == (4 if sampled else 0)
 
+    def test_kv_append_kernel_serves_the_same_tokens(
+            self, trivial_mesh, monkeypatch):
+        """With the `kv_append` kernel forced in the interpreter a greedy
+        run emits the tokens of XLA's scatter, `DecodeStep` compiles
+        once, and `kv_append_routes()` says that the decode program's
+        2 x layers appends took the kernel and none of prefill's did."""
+        from paddle_tpu.nn.functional import attention as attn_route
+        from paddle_tpu.observability import metrics
+
+        monkeypatch.setenv("PADDLE_FLASH_DEFAULT", "interpret")
+        layers = 2
+        prompts = [rng.randint(0, 48, size=(n,)) for n in (5, 11, 3)]
+
+        def serve():
+            paddle.seed(73)
+            engine = InferenceEngine(_tiny_lm(cap=128, layers=layers),
+                                     slots=2, max_length=128, sync_every=3)
+            reqs = [Request(p, max_new_tokens=m)
+                    for p, m in zip(prompts, (7, 4, 6))]
+            for q in reqs:
+                engine.submit(q)
+            before = metrics.kv_append_routes()
+            results = engine.run()
+            after = metrics.kv_append_routes()
+            return (engine, [results[q.rid].tokens for q in reqs],
+                    {k: after[k] - before[k] for k in after})
+
+        engine, tokens, routes = serve()
+        assert engine._decode.compiles == 1
+        assert routes == {"kernel": 2 * layers,
+                          "scatter": 2 * layers * engine._prefill.compiles}
+        with monkeypatch.context() as m:
+            m.setattr(attn_route, "_kv_append_route", lambda c, u: None)
+            _, want, old = serve()
+        assert old["kernel"] == 0 and old["scatter"] > routes["scatter"]
+        assert tokens == want and [len(t) for t in tokens] == [7, 4, 6]
+
     @pytest.mark.slow
     def test_insert_on_free_many_requests(self, trivial_mesh):
         """More requests than slots with heterogeneous lengths, budgets

@@ -339,3 +339,52 @@ def test_smoke_counts_the_kernels_lowered_for_v5e(one_chip):
     assert chip_smoke._mosaic_calls(text) == (7, {
         "flash fwd": 1, "flash dq": 1, "flash dk/dv": 1,
         "LN / add-LN fwd": 2, "LN bwd": 2})
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_kv_append_stays_in_place_compiled_for_v5e(one_chip, dtype,
+                                                   monkeypatch):
+    """A `DecodeStep`-shaped append + attention at the chat cell's cache
+    (32 slots x 16 heads x 1,024 x 64, two layers, donated): one
+    `kv_append` custom call a cache tensor on the `[B, H, D, cap]` view,
+    which has to stay a bitcast of what the chip stores: no `while`, no
+    `dynamic-update-slice`, no temporary the size of a cache tensor, and
+    every cache aliased in to out. (The route asks `jax.default_backend()`,
+    which is the CPU here, so the test answers for it.)"""
+    from paddle_tpu.core.tensor import Tensor
+    from paddle_tpu.nn.functional import attention as attn_route
+    from paddle_tpu.observability.metrics import kv_append_routes
+
+    monkeypatch.setattr(attn_route, "_kv_append_route", lambda c, u: False)
+    B, H, cap, D, layers = 32, 16, 1024, 64, 2
+    T = Tensor._wrap
+
+    def step(caches, q, u, pos):
+        x, out = q, []
+        for k, v in caches:
+            k = attn_route.cache_update(T(k), T(u + x), T(pos))
+            v = attn_route.cache_update(T(v), T(u - x), T(pos))
+            x = x + attn_route.cached_attention(T(q + x), k, v, T(pos))._data
+            out.append((k._data, v._data))
+        return out, x
+
+    cache = _aval(one_chip, B, H, cap, D, dtype=dtype)
+    row = _aval(one_chip, B, H, 1, D, dtype=dtype)
+    before = kv_append_routes()["kernel"]
+    compiled = jax.jit(step, donate_argnums=(0,)).lower(
+        [(cache, cache)] * layers, row, row,
+        _aval(one_chip, B, dtype=jnp.int32)).compile()
+    assert kv_append_routes()["kernel"] - before == 2 * layers
+    text = compiled.as_text()
+    calls = re.findall(r"= ([^\n]*?) custom-call\([^\n]*"
+                       r"custom_call_target=\"tpu_custom_call\"[^\n]*"
+                       r"kv_append", text)
+    assert len(calls) == 2 * layers, calls
+    view = f"[{B},{H},{D},{cap}]{{3,2,1,0:"
+    assert all(view in c for c in calls), calls
+    assert " while(" not in text and "dynamic-update-slice" not in text
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 16 << 20, mem.temp_size_in_bytes
+    assert mem.alias_size_in_bytes == (
+        2 * layers * B * H * cap * D * jnp.dtype(dtype).itemsize)
