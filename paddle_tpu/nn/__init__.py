@@ -28,6 +28,7 @@ from .layers import conv  # noqa: F401,E402
 # the latent-attention / routed-expert layers resolve LAZILY: no program
 # that does not name them pays their import
 _LATENT = ("RMSNorm", "GatedMLP", "LatentAttention", "RoutedExperts")
+_DSA = ("IndexedAttention",)
 
 
 def __getattr__(name):
@@ -35,4 +36,8 @@ def __getattr__(name):
         from .layers import latent
 
         return getattr(latent, name)
+    if name in _DSA:
+        from .layers import dsa
+
+        return getattr(dsa, name)
     raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

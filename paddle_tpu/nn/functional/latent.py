@@ -35,7 +35,7 @@ import numpy as np
 from ...core import autograd as AG
 
 __all__ = ["rms_norm", "swiglu", "yarn_inv_freq", "yarn_mscale",
-           "LatentCache", "is_latent",
+           "LatentCache",
            "latent_cache_update", "latent_attend_plan", "latent_attention",
            "route_top_k", "routed_experts"]
 
@@ -47,13 +47,6 @@ LatentCache = collections.namedtuple("LatentCache", ["rows"])
 KEY_BLOCK = 512
 
 _NEG = -1e30
-
-
-def is_latent(cache_tree) -> bool:
-    """Whether a cache pytree holds a `LatentCache` anywhere."""
-    return any(isinstance(leaf, LatentCache) for leaf in
-               jax.tree_util.tree_leaves(
-                   cache_tree, is_leaf=lambda v: isinstance(v, LatentCache)))
 
 
 # ---------------------------------------------------------------------------
@@ -257,14 +250,21 @@ def latent_attention(query, rows, w_ukv, start, *, kv_rank, nope_dim, scale,
 # ---------------------------------------------------------------------------
 
 
-def route_top_k(x, gate_w, bias, top_k: int, scaling: float):
+#: the scoring rules of `route_top_k`: the router's logits -> scores
+SCORES = {"sigmoid": jax.nn.sigmoid,
+          "softmax": lambda z: jax.nn.softmax(z, axis=-1)}
+
+
+def route_top_k(x, gate_w, bias, top_k: int, scaling: float,
+                score: str = "sigmoid"):
     """Raw arrays. x [N, D], gate_w [D, E], bias [E] or None -> (idx
-    [N, k] int32, weights [N, k] float32): scores are sigmoid(x W) in
-    float32, the chosen set is the k largest of score + bias (the bias
+    [N, k] int32, weights [N, k] float32): scores are `score` of x W in
+    float32 (``sigmoid`` each logit alone, ``softmax`` over the E
+    logits), the chosen set is the k largest of score + bias (the bias
     selects and does not weigh), the weights are scaling * score over the
     chosen scores' sum."""
-    s = jax.nn.sigmoid(jnp.dot(x, gate_w,
-                               preferred_element_type=jnp.float32))
+    s = SCORES[score](jnp.dot(x, gate_w,
+                              preferred_element_type=jnp.float32))
     pick = s if bias is None else s + bias.astype(jnp.float32)
     _, idx = jax.lax.top_k(pick, top_k)
     chosen = jnp.take_along_axis(s, idx, axis=-1)
@@ -273,19 +273,20 @@ def route_top_k(x, gate_w, bias, top_k: int, scaling: float):
 
 
 def routed_experts(x, gate_w, bias, w_in, w_out, *, top_k, scaling,
-                   first_held=0):
+                   first_held=0, score="sigmoid"):
     """Raw arrays. The routed part of an expert layer on a chip that
     holds experts ``first_held .. first_held + H - 1`` of the router's E:
     x [N, D], gate_w [D, E], w_in [H, D, 2F] (gate | up), w_out [H, F, D].
-    Every token is routed over all E experts (dropless: no capacity); the
-    result is the sum over the chosen experts *held here* of weight *
-    FFN_e(x), what the absent experts would add is left out. Returns
+    `score` is `route_top_k`'s scoring rule. Every token is routed over
+    all E experts (dropless: no capacity); the result is the sum over the
+    chosen experts *held here* of weight * FFN_e(x), what the absent
+    experts would add is left out. Returns
     (y [N, D], load [H + 1] int32: the assignments that fell on each held
     expert, and last those routed to experts not held)."""
     N, D = x.shape
     H = w_in.shape[0]
     with jax.named_scope("moe.route"):
-        idx, w = route_top_k(x, gate_w, bias, top_k, scaling)
+        idx, w = route_top_k(x, gate_w, bias, top_k, scaling, score=score)
         local = idx - first_held
         held = (local >= 0) & (local < H)
         group = jnp.where(held, local, H).reshape(-1)        # [N * k]

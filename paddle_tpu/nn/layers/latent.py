@@ -176,9 +176,11 @@ class RoutedExperts(Layer):
     """An expert layer that is told which experts it holds.
 
     The router keeps all `num_experts` outputs and its `top_k`; scores
-    are sigmoid in float32, the chosen set is the top-k of score +
-    `select_bias` (which selects and does not weigh), the weights are
-    `scaling` * score over the chosen scores' sum. No capacity: every
+    are `score` of the logits in float32 (``sigmoid``, or ``softmax``
+    over the `num_experts` logits), the chosen set is the top-k of score
+    + `select_bias` (which selects and does not weigh; a layer built with
+    `select_bias=False` has none), the weights are `scaling` * score
+    over the chosen scores' sum. No capacity: every
     assignment to a held expert is computed (rows sorted by expert, one
     `jax.lax.ragged_dot` over the held experts' stacked weights). The
     layer returns the sum over the chosen experts held here plus the
@@ -194,9 +196,12 @@ class RoutedExperts(Layer):
     `jit.decode_step`)."""
 
     def __init__(self, d_model, d_hidden, num_experts, top_k, *, held=None,
-                 scaling=1.0, shared_hidden=None, weight_attr=None,
-                 dtype=None):
+                 scaling=1.0, shared_hidden=None, score="sigmoid",
+                 select_bias=True, weight_attr=None, dtype=None):
         super().__init__()
+        if score not in L.SCORES:
+            raise ValueError(f"score={score!r}: one of {sorted(L.SCORES)}")
+        self.score = score
         self.num_experts, self.top_k = int(num_experts), int(top_k)
         first, count = held if held is not None else (0, self.num_experts)
         if first < 0 or count < 1 or first + count > self.num_experts:
@@ -213,7 +218,7 @@ class RoutedExperts(Layer):
         self.gate = mat([d_model, self.num_experts])
         self.select_bias = self.create_parameter(
             shape=[self.num_experts], attr=weight_attr, dtype="float32",
-            default_initializer=Constant(0.0))
+            default_initializer=Constant(0.0)) if select_bias else None
         self.w_in = mat([count, d_model, 2 * d_hidden])
         self.w_out = mat([count, d_hidden, d_model])
         self.shared = GatedMLP(d_model, shared_hidden, weight_attr, dtype) \
@@ -226,13 +231,14 @@ class RoutedExperts(Layer):
         B, T, D = (int(s) for s in x.shape)
         first = self.held[0]
 
-        def f(xr, wg, b, wi, wo):
+        def f(xr, wg, wi, wo, b=None):
             return L.routed_experts(xr, wg, b, wi, wo, top_k=self.top_k,
-                                    scaling=self.scaling, first_held=first)
+                                    scaling=self.scaling, first_held=first,
+                                    score=self.score)
 
+        bias = () if self.select_bias is None else (self.select_bias,)
         y, load = AG.apply_nondiff(f, (
-            x.reshape([B * T, D]), self.gate, self.select_bias, self.w_in,
-            self.w_out))
+            x.reshape([B * T, D]), self.gate, self.w_in, self.w_out) + bias)
         if count:
             row = 0 if T > 1 else 1
             self.load._data = self.load._data.at[row].add(load._data)

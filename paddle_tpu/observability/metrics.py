@@ -317,6 +317,27 @@ def expert_load() -> dict:
     return dict(_expert_load)
 
 
+_selected_keys: dict = {}
+
+
+def record_selected_keys(keys: dict) -> None:
+    """Keep the host copy of a sparse-attention model's counters
+    (`serving.SparseMoELM.selected_keys()`), read with each readback
+    beside the expert load; running totals, so the newest reading
+    replaces the last."""
+    _selected_keys.clear()
+    _selected_keys.update({int(k): v for k, v in keys.items()})
+
+
+def selected_keys() -> dict:
+    """{block index: [2, 2] int64 array} — per learned-sparse-attention
+    layer, the (query, key) pairs visible and the pairs its indexer
+    selected; row 0 counted in prefill programs, row 1 in decode steps.
+    Process-wide and readable after the engine is gone, as
+    :func:`expert_load` is; empty when no such model was served."""
+    return dict(_selected_keys)
+
+
 # ---------------------------------------------------------------------------
 # sampler engagement: which side of `sampling.sample`'s branch a decode
 # step took, counted from host values at the engine's readback

@@ -476,6 +476,7 @@ class InferenceEngine:
         self._decode = DecodeStep(model)
         #: a routed model's device counters, read with every readback
         self._expert_load = getattr(model, "expert_load", None)
+        self._selected_keys = getattr(model, "selected_keys", None)
         self._insert_jitted = None
         self._migrate = None  # lazy jit.MigrateInsert (ISSUE 17)
         #: resident fine-tune fleet, if the model carries one (attach
@@ -547,10 +548,12 @@ class InferenceEngine:
         self._state = DecodeState(*_commit_tree(self._state.astuple()))
         from ..observability.metrics import (DecodeMetricsSampler,
                                              record_expert_load,
-                                             record_sampler_steps)
+                                             record_sampler_steps,
+                                             record_selected_keys)
 
         self._metrics = DecodeMetricsSampler()
         self._record_expert_load = record_expert_load
+        self._record_selected_keys = record_selected_keys
         self._record_sampler_steps = record_sampler_steps
 
     # -- public API --------------------------------------------------------
@@ -1058,6 +1061,8 @@ class InferenceEngine:
                 # the device is already drained by the token read: the
                 # counters ride it, no new sync point
                 self._record_expert_load(self._expert_load())
+            if self._selected_keys is not None:
+                self._record_selected_keys(self._selected_keys())
         dt = time.perf_counter() - t0
         with _prof.phase("engine.collect"):
             # decode-window span for traced requests: emitted on the

@@ -73,17 +73,19 @@ def block_size_default() -> int:
 
 def refuse_latent(cache_tree, what: str) -> None:
     """Blocks, block tables, shared prefixes and migration bundles are
-    all made of per-head K and V `[.., H, rows, Dh]`; a latent cache
-    (`nn.functional.latent.LatentCache`: one `[c | k_rope]` row a token,
-    no head axis) would be mis-spliced by them, so `what` refuses it."""
-    from ..nn.functional.latent import is_latent
+    all made of per-head K and V `[.., H, rows, Dh]`; a cache made of
+    rows with no head axis (`nn.functional.dsa.ROW_CACHES`: a latent
+    cache's one `[c | k_rope]` row a token, an indexed cache's K rows, V
+    rows and indexer-key rows side by side) would be mis-spliced by them,
+    so `what` refuses it by name."""
+    from ..nn.functional.dsa import is_row_cache
 
-    if is_latent(cache_tree):
+    kind = is_row_cache(cache_tree)
+    if kind is not None:
         raise TypeError(
-            f"{what} handles per-head K and V only and refuses a latent "
-            "cache (LatentCache: one [c | k_rope] row a token, no head "
-            "axis); serve it from the contiguous pool (block_size=0, no "
-            "prefix cache, no migration)")
+            f"{what} handles per-head K and V only and refuses {kind}; "
+            "serve it from the contiguous pool (block_size=0, no prefix "
+            "cache, no migration)")
 
 
 def is_paged(cache) -> bool:

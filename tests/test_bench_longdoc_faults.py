@@ -15,7 +15,10 @@ _TESTS = os.path.join(_ROOT, "benchmarks", "tests")
 
 
 @pytest.mark.parametrize("script,fault", [
-    ("broken_longdoc.py", "no_select_bias"),
+    # `broken_longdoc.py`'s own stand-in for this fault takes five
+    # arguments and `routed_experts` names the scoring rule: the fault is
+    # planted by the file beside it until a benchmark issue repairs it
+    ("broken_longctx.py", "no_select_bias"),
     ("broken_longdoc.py", "no_shared_expert"),
     ("broken_longdoc.py", "no_k_rope_rotation"),
     ("broken_run.py", "altered_token"),
