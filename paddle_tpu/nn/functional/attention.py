@@ -391,18 +391,23 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
 # ---------------------------------------------------------------------------
 
 
-def _kv_append_route(c, u):
-    """Does this append take the in-place `kv_append` kernel
-    (ops/pallas/kv_append.py)? None = XLA's slot-by-slot scatter, else
-    the kernel's `interpret` flag. Decided from what the write is handed
-    and nothing else: one new row a slot (`u` is [B, H, 1, D]: the decode
-    step) into a float32 or bfloat16 [B, H, cap, D] array whose capacity
-    is whole 128-lane tiles and whose head_dim is under a tile's 128
-    lanes — only then does the chip store capacity in the lanes, so that
-    the kernel's `[B, H, D, cap]` view is a bitcast; at D >= 128 the view
-    would be a copy of the whole cache tensor. A non-trivial mesh keeps
-    the scatter (GSPMD partitions it; a bare pallas_call it would not).
-    Off the TPU the kernel runs only in the interpreter, for tests, by
+def _lane_cache_route(c, u):
+    """Does a decode step's work on this cache take the Pallas kernels on
+    the cache as the chip stores it: the in-place append `kv_append`
+    (ops/pallas/kv_append.py) for the write, `decode_attention`
+    (ops/pallas/decode_attention.py) for the read? None = XLA's own form
+    (the slot-by-slot scatter, the dense attention over the capacity),
+    else the kernels' `interpret` flag. The write and the read ask this
+    one question, because they rest on one fact, and it is decided from
+    what the call is handed and nothing else: one row a slot (`u`, the
+    new K or V row or the query, is [B, H, 1, D]: the decode step) and a
+    float32 or bfloat16 [B, H, cap, D] array whose capacity is whole
+    128-lane tiles and whose head_dim is under a tile's 128 lanes — only
+    then does the chip store capacity in the lanes, so that the kernels'
+    `[B, H, D, cap]` view is a bitcast; at D >= 128 the view would be a
+    copy of the whole cache tensor. A non-trivial mesh keeps XLA's form
+    (GSPMD partitions it; a bare pallas_call it would not). Off the TPU
+    the kernels run only in the interpreter, for tests, by
     `PADDLE_FLASH_DEFAULT=interpret`."""
     import jax.numpy as jnp
 
@@ -431,7 +436,7 @@ def cache_update(cache, new, pos):
 
     The decode step's write (one row a slot into a plain float cache, on
     the chip) is the same write as one in-place Pallas kernel instead
-    (`_kv_append_route`, ops/pallas/kv_append.py): XLA:TPU expands the
+    (`_lane_cache_route`, ops/pallas/kv_append.py): XLA:TPU expands the
     vmapped slice into a serial loop of B iterations a cache tensor.
     `observability.metrics.kv_append_routes()` counts both ways.
 
@@ -484,7 +489,7 @@ def cache_update(cache, new, pos):
         )(c, u, jnp.asarray(p, jnp.int32))
 
     def write(c, u, p):
-        interpret = _kv_append_route(c, u)
+        interpret = _lane_cache_route(c, u)
         if interpret is None:
             _count_route("scatter")
             return scatter(c, u, p)
@@ -516,11 +521,19 @@ def cached_attention(query, key, value, pos, *, scale=None):
     which also masks every not-yet-written cache slot (kpos > qpos by
     construction — the engine only writes at monotonically growing pos).
 
-    This is deliberately the dense form: decode's Sq is 1 (a matvec per
-    head); a Pallas tile would be degenerate, and a TRACED offset cannot
-    feed the flash kernel's static q_offset seam. Static end-aligned
-    Sq != Sk shapes (prefill-with-history) route through the flash
-    kernel via `flash_plan` instead. Inference-only (no VJP).
+    The decode step (one query row a slot over a plain float cache, on
+    the chip) is one Pallas kernel a layer that reads only what the slot
+    holds (`_lane_cache_route`, ops/pallas/decode_attention.py): lane
+    tiles of 128 positions up to the one that holds ``pos[b]``, on the
+    cache as the chip stores it. Every other call keeps the dense form
+    over the whole capacity under the position mask: prefill, chunked
+    prefill and speculative steps (Sq > 1; a TRACED offset cannot feed
+    the flash kernel's static q_offset seam), a quantized or paged cache,
+    a head_dim of 128 or more, a sharded cache, the CPU.
+    `observability.metrics.cached_attention_routes()` counts both ways.
+    Static end-aligned Sq != Sk shapes (prefill-with-history) route
+    through the flash kernel via `flash_plan` instead. Inference-only
+    (no VJP).
 
     A PAGED cache (``PagedKV``, ISSUE 13) gathers the slot's view
     [B, H, nmax*bs, D] from the block pool through the table first (one
@@ -531,6 +544,8 @@ def cached_attention(query, key, value, pos, *, scale=None):
     import jax.numpy as jnp
 
     from ...distributed import quantized_comm as qc
+    from ...observability.metrics import (
+        record_cached_attention_route as _count_route)
     from ...serving import paged_kv as pk
 
     sc = scale if scale is not None else int(query.shape[-1]) ** -0.5
@@ -544,6 +559,7 @@ def cached_attention(query, key, value, pos, *, scale=None):
         Sk = int((key.q if quantized else key).shape[2])
 
     def core(qr, kr, vr, pr):
+        _count_route("dense")
         s = jnp.einsum("bhqd,bhkd->bhqk", qr, kr) * sc
         qpos = pr[:, None].astype(jnp.int32) + jnp.arange(Sq)[None, :]
         kpos = jnp.arange(Sk)
@@ -590,4 +606,19 @@ def cached_attention(query, key, value, pos, *, scale=None):
             return AG.apply_nondiff(
                 fq, (query, key.q, key.scale, value.q, value.scale, pos)
             )
-        return AG.apply_nondiff(core, (query, key, value, pos))
+
+        def attend(qr, kr, vr, pr):
+            from ...ops.pallas.decode_attention import (LANES,
+                                                        decode_attention)
+
+            interpret = _lane_cache_route(kr, qr)
+            # the read's own two facts: V laid out as K is, and the heads
+            # of a slot's query fit the one lane tile the kernel turns
+            if (interpret is None or kr.shape[1] > LANES
+                    or (vr.shape, vr.dtype) != (kr.shape, kr.dtype)):
+                return core(qr, kr, vr, pr)
+            _count_route("kernel")
+            return decode_attention(qr, kr, vr, pr, scale=sc,
+                                    interpret=interpret)
+
+        return AG.apply_nondiff(attend, (query, key, value, pos))

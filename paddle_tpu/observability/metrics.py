@@ -388,3 +388,30 @@ def kv_append_routes() -> dict:
     2 x layers to ``kernel``, a `PrefillStep` as many to ``scatter``.
     Process-wide, as :func:`sampler_steps` is."""
     return dict(_kv_append_routes)
+
+
+# ---------------------------------------------------------------------------
+# decode attention engagement: which way each `cached_attention` call was
+# lowered, counted where the call is traced
+# ---------------------------------------------------------------------------
+
+_cached_attention_routes = {"kernel": 0, "dense": 0}
+
+
+def record_cached_attention_route(route: str) -> None:
+    """Count one `nn.functional.attention.cached_attention` call:
+    ``kernel`` when it became the `decode_attention` Pallas kernel that
+    stops at each slot's live length, ``dense`` when it kept XLA's form
+    over the whole capacity (prefill, speculative steps, quantized and
+    paged caches, a sharded cache, the CPU). Called as the read is
+    traced, so a compiled step counts once however often it runs."""
+    _cached_attention_routes[route] += 1
+
+
+def cached_attention_routes() -> dict:
+    """{"kernel": n, "dense": n} — running totals of the
+    `cached_attention` calls traced in this process, by the way each was
+    lowered: a `DecodeStep` over a plain float cache on the chip adds
+    ``layers`` to ``kernel``, a `PrefillStep` as many to ``dense``.
+    Process-wide, as :func:`kv_append_routes` is."""
+    return dict(_cached_attention_routes)

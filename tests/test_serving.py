@@ -716,10 +716,50 @@ class TestInferenceEngine:
         assert routes == {"kernel": 2 * layers,
                           "scatter": 2 * layers * engine._prefill.compiles}
         with monkeypatch.context() as m:
-            m.setattr(attn_route, "_kv_append_route", lambda c, u: None)
+            m.setattr(attn_route, "_lane_cache_route", lambda c, u: None)
             _, want, old = serve()
         assert old["kernel"] == 0 and old["scatter"] > routes["scatter"]
         assert tokens == want and [len(t) for t in tokens] == [7, 4, 6]
+
+    def test_decode_attention_kernel_serves_the_same_tokens(
+            self, trivial_mesh, monkeypatch):
+        """With the `decode_attention` kernel forced in the interpreter a
+        greedy run emits the dense form's tokens, `DecodeStep` compiles
+        once, and `cached_attention_routes()` says that the decode
+        program's `layers` reads took the kernel and prefill's the dense
+        form; with the route answering None every read is dense."""
+        from paddle_tpu.nn.functional import attention as attn_route
+        from paddle_tpu.observability import metrics
+
+        monkeypatch.setenv("PADDLE_FLASH_DEFAULT", "interpret")
+        layers = 2
+        prompts = [rng.randint(0, 48, size=(n,)) for n in (9, 2, 130, 6)]
+
+        def serve():
+            paddle.seed(79)
+            # three lane tiles a slot: the 130-token prompt decodes past
+            # a tile's edge, the others inside their first
+            engine = InferenceEngine(_tiny_lm(cap=384, layers=layers),
+                                     slots=2, max_length=384, sync_every=3)
+            reqs = [Request(p, max_new_tokens=m)
+                    for p, m in zip(prompts, (6, 5, 4, 7))]
+            for q in reqs:
+                engine.submit(q)
+            before = metrics.cached_attention_routes()
+            results = engine.run()
+            after = metrics.cached_attention_routes()
+            return (engine, [results[q.rid].tokens for q in reqs],
+                    {k: after[k] - before[k] for k in after})
+
+        engine, tokens, routes = serve()
+        assert engine._decode.compiles == 1
+        assert routes == {"kernel": layers,
+                          "dense": layers * engine._prefill.compiles}
+        with monkeypatch.context() as m:
+            m.setattr(attn_route, "_lane_cache_route", lambda c, u: None)
+            _, want, old = serve()
+        assert old["kernel"] == 0 and old["dense"] == routes["dense"] + layers
+        assert tokens == want and [len(t) for t in tokens] == [6, 5, 4, 7]
 
     @pytest.mark.slow
     def test_insert_on_free_many_requests(self, trivial_mesh):

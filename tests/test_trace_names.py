@@ -341,23 +341,20 @@ def test_smoke_counts_the_kernels_lowered_for_v5e(one_chip):
         "LN / add-LN fwd": 2, "LN bwd": 2})
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
-                         ids=["f32", "bf16"])
-def test_kv_append_stays_in_place_compiled_for_v5e(one_chip, dtype,
-                                                   monkeypatch):
-    """A `DecodeStep`-shaped append + attention at the chat cell's cache
-    (32 slots x 16 heads x 1,024 x 64, two layers, donated): one
-    `kv_append` custom call a cache tensor on the `[B, H, D, cap]` view,
-    which has to stay a bitcast of what the chip stores: no `while`, no
-    `dynamic-update-slice`, no temporary the size of a cache tensor, and
-    every cache aliased in to out. (The route asks `jax.default_backend()`,
-    which is the CPU here, so the test answers for it.)"""
+def _decode_shaped_program(one_chip, monkeypatch, B, H, dtype, layers=2,
+                           cap=1024, D=64):
+    """What a `DecodeStep` does to its caches, a layer at a time (K and V
+    rows appended at `pos`, then the one query row a slot attends),
+    compiled for the described chip with the caches donated; with it, how
+    many `kv_append` and `decode_attention` kernels the trace counted.
+    (The route asks `jax.default_backend()`, the CPU here: the test
+    answers for it.)"""
     from paddle_tpu.core.tensor import Tensor
     from paddle_tpu.nn.functional import attention as attn_route
-    from paddle_tpu.observability.metrics import kv_append_routes
+    from paddle_tpu.observability.metrics import (cached_attention_routes,
+                                                  kv_append_routes)
 
-    monkeypatch.setattr(attn_route, "_kv_append_route", lambda c, u: False)
-    B, H, cap, D, layers = 32, 16, 1024, 64, 2
+    monkeypatch.setattr(attn_route, "_lane_cache_route", lambda c, u: False)
     T = Tensor._wrap
 
     def step(caches, q, u, pos):
@@ -371,11 +368,28 @@ def test_kv_append_stays_in_place_compiled_for_v5e(one_chip, dtype,
 
     cache = _aval(one_chip, B, H, cap, D, dtype=dtype)
     row = _aval(one_chip, B, H, 1, D, dtype=dtype)
-    before = kv_append_routes()["kernel"]
+    before = kv_append_routes()["kernel"], cached_attention_routes()["kernel"]
     compiled = jax.jit(step, donate_argnums=(0,)).lower(
         [(cache, cache)] * layers, row, row,
         _aval(one_chip, B, dtype=jnp.int32)).compile()
-    assert kv_append_routes()["kernel"] - before == 2 * layers
+    return compiled, (kv_append_routes()["kernel"] - before[0],
+                      cached_attention_routes()["kernel"] - before[1])
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_kv_append_stays_in_place_compiled_for_v5e(one_chip, dtype,
+                                                   monkeypatch):
+    """A `DecodeStep`-shaped append + attention at the chat cell's cache
+    (32 slots x 16 heads x 1,024 x 64, two layers, donated): one
+    `kv_append` custom call a cache tensor on the `[B, H, D, cap]` view,
+    which has to stay a bitcast of what the chip stores: no `while`, no
+    `dynamic-update-slice`, no temporary the size of a cache tensor, and
+    every cache aliased in to out."""
+    B, H, cap, D, layers = 32, 16, 1024, 64, 2
+    compiled, (appends, _) = _decode_shaped_program(
+        one_chip, monkeypatch, B, H, dtype)
+    assert appends == 2 * layers
     text = compiled.as_text()
     calls = re.findall(r"= ([^\n]*?) custom-call\([^\n]*"
                        r"custom_call_target=\"tpu_custom_call\"[^\n]*"
@@ -388,3 +402,41 @@ def test_kv_append_stays_in_place_compiled_for_v5e(one_chip, dtype,
     assert mem.temp_size_in_bytes < 16 << 20, mem.temp_size_in_bytes
     assert mem.alias_size_in_bytes == (
         2 * layers * B * H * cap * D * jnp.dtype(dtype).itemsize)
+
+
+@pytest.mark.parametrize("B,H", [(32, 16), (16, 20)], ids=["chat", "batch"])
+def test_decode_attention_reads_the_stored_view_compiled_for_v5e(
+        one_chip, B, H, monkeypatch):
+    """A `DecodeStep`-shaped append + attention at the chat and the batch
+    cell's caches (1,024 x 64 float32, two layers, donated): one
+    `decode_attention` custom call a layer, its K and V operands the
+    `[B, H, D, cap]` view of what `kv_append` wrote (a bitcast, no
+    copy), no temporary the size of a cache tensor, every cache still
+    aliased in to out, and nothing left in the program that touches a
+    whole cache tensor but parameters, bitcasts, the result's tuple and
+    the two kernels: the dense read of the capacity is gone."""
+    cap, D, layers = 1024, 64, 2
+    compiled, counted = _decode_shaped_program(
+        one_chip, monkeypatch, B, H, jnp.float32)
+    assert counted == (2 * layers, layers)
+    text = compiled.as_text()
+    reads = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and "decode_attention" in line]
+    assert len(reads) == layers, reads
+    view = f"f32[{B},{H},{D},{cap}]{{3,2,1,0}}"
+    for line in reads:
+        # pos, q, then K and V once as the first tile's block and once whole
+        assert line.count(view) == 4, line
+        operands = re.search(r"custom-call\(([^)]*)\)", line).group(1)
+        assert operands.count("%kv_append") == 4, operands
+    stored, seen = f"[{B},{H},{cap},{D}]", f"[{B},{H},{D},{cap}]"
+    for line in text.splitlines():
+        made = re.match(r"\s*(?:ROOT )?%\S+ = .*? ([\w-]+)\(", line)
+        if made is None or not (stored in line or seen in line):
+            continue
+        assert made.group(1) in ("parameter", "bitcast", "tuple") or (
+            made.group(1) == "custom-call"
+            and re.search(r"kv_append|decode_attention", line)), line
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 16 << 20, mem.temp_size_in_bytes
+    assert mem.alias_size_in_bytes == 2 * layers * B * H * cap * D * 4
