@@ -319,8 +319,11 @@ class ParallelMultiHeadAttention(Layer):
         def place(z, s=None):
             if self.mesh.size > 1:
                 # the scale buffer's leading dims match the payload's,
-                # so one spec lays out both
-                z = jax.device_put(
+                # so one spec lays out both; a constraint places eagerly
+                # as device_put does, and also lays out the output of a
+                # compiled program (the engine's SlotCache), where a
+                # device_put does not
+                z = jax.lax.with_sharding_constraint(
                     z, NamedSharding(self.mesh, spec if s is None else s))
             # _wrap, not Tensor(): the ctor's dtype inference would
             # np.asarray the buffer — a device read per cache allocation

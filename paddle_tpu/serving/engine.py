@@ -478,6 +478,7 @@ class InferenceEngine:
         self._expert_load = getattr(model, "expert_load", None)
         self._selected_keys = getattr(model, "selected_keys", None)
         self._insert_jitted = None
+        self._slot_cache_jitted = None
         self._migrate = None  # lazy jit.MigrateInsert (ISSUE 17)
         #: resident fine-tune fleet, if the model carries one (attach
         #: the AdapterSet BEFORE building the engine — the compiled
@@ -1124,9 +1125,31 @@ class InferenceEngine:
 
     def _slot_cache(self, req, slot):
         """A CONTIGUOUS batch-1 cache for one request's prefill (the
-        pool may be paged; the splice re-blocks it)."""
+        pool may be paged; the splice re-blocks it), as raw arrays: the
+        model's own `gen_cache` zeros, built by one compiled program
+        (ledger label ``SlotCache``) instead of one eager launch a layer
+        and tensor. Its outputs are committed where `_commit_tree` would
+        put them, so the one-shot, chunked and shared-prefix admissions
+        hand `PrefillStep` one signature."""
         with _prof.phase("engine.slot_cache", rid=req.rid, slot=slot):
-            return self.model.gen_cache(1, self.max_length, block_size=0)
+            if self._slot_cache_jitted is None:
+                from jax.sharding import NamedSharding, PartitionSpec as P
+
+                from ..distributed import comm as _comm
+                from ..jit.decode_step import _raw_tree
+                from ..observability import ledger as _ledger
+
+                mesh = _comm.hybrid_mesh()
+                # a program without inputs hands back uncommitted arrays
+                # on a one-device mesh; on a real one they come back
+                # committed, replicated or as `gen_cache` constrained them
+                pin = (NamedSharding(mesh, P())
+                       if mesh is not None and mesh.size == 1 else None)
+                self._slot_cache_jitted = _ledger.jit(
+                    lambda: _raw_tree(self.model.gen_cache(
+                        1, self.max_length, block_size=0)),
+                    "SlotCache", out_shardings=pin)
+            return self._slot_cache_jitted()
 
     def _advance_prefills(self, results) -> None:
         """One chunk per pending prefill per engine turn: the chunked-
@@ -1212,15 +1235,8 @@ class InferenceEngine:
                 continue
             L = req.prefill_ids.size
             if self.prefill_chunk > 0 and L > self.prefill_chunk:
-                # committed like every later chunk's cache (a step's
-                # output): a fresh uncommitted scratch would give the
-                # first chunk a signature of its own, and the chunk
-                # program a second compile
-                from ..jit.decode_step import _commit_tree, _raw_tree
-
                 self._pending[slot] = _Pending(
-                    req, slot, blocks,
-                    _commit_tree(_raw_tree(self._slot_cache(req, slot))),
+                    req, slot, blocks, self._slot_cache(req, slot),
                     time.perf_counter())
                 continue
             t0 = time.perf_counter()

@@ -29,6 +29,7 @@ import paddle_tpu as paddle
 from paddle_tpu.core.tensor import Tensor
 from paddle_tpu.distributed import comm
 from paddle_tpu.jit import DecodeState, DecodeStep, PrefillStep
+from paddle_tpu.jit.decode_step import _raw_tree
 from paddle_tpu.nn import functional as F
 from paddle_tpu.nn.functional import attention as attn_route
 from paddle_tpu.observability import bus
@@ -793,6 +794,124 @@ class TestInferenceEngine:
                 want = generate(model, [q.prompt_ids],
                                 q.max_new_tokens, max_length=40)[0]
                 assert got == [t for t in want.tolist() if t >= 0]
+
+
+# ---------------------------------------------------------------------------
+# an admission's batch-1 scratch cache: one compiled program
+# ---------------------------------------------------------------------------
+
+
+def _tiny_latent():
+    from paddle_tpu.serving import LatentMoELM
+
+    return LatentMoELM(48, 32, 2, 2, nope_dim=8, rope_dim=8, v_dim=8,
+                       kv_rank=16, dense_ffn=32, expert_ffn=16,
+                       num_experts=4, top_k=2, max_position=32)
+
+
+def _tiny_sparse():
+    from paddle_tpu.serving import SparseMoELM
+
+    return SparseMoELM(48, 32, 4, 2, 8, 2, index_heads=2, index_dim=8,
+                       topk=4, expert_ffn=16, num_experts=4, top_k=2,
+                       max_position=32)
+
+
+class TestSlotCache:
+    @pytest.mark.parametrize(
+        "build", [lambda: _tiny_lm(cap=32), _tiny_latent, _tiny_sparse],
+        ids=["transformer", "latent", "sparse"])
+    def test_compiled_scratch_is_gen_cache(self, trivial_mesh, build):
+        """`_slot_cache` hands back what the model's own `gen_cache`
+        builds, leaf for leaf, committed on the mesh as `_commit_tree`
+        commits a step's state."""
+        from jax.sharding import NamedSharding
+
+        paddle.seed(83)
+        model = build()
+        model.eval()
+        engine = InferenceEngine(model, slots=2, max_length=32,
+                                 block_size=0)
+        got = engine._slot_cache(Request(np.arange(3), max_new_tokens=2), 0)
+        want = _raw_tree(model.gen_cache(1, 32, block_size=0))
+        assert (jax.tree_util.tree_structure(got)
+                == jax.tree_util.tree_structure(want))
+        for g, w in zip(jax.tree_util.tree_leaves(got),
+                        jax.tree_util.tree_leaves(want)):
+            assert g.shape == w.shape and g.dtype == w.dtype
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+            assert g.committed and isinstance(g.sharding, NamedSharding)
+        assert engine._slot_cache_jitted.compiles == 1
+
+    def test_scratch_keeps_the_mesh_layout(self, dp2mp2):
+        """On a real mesh the compiled scratch is laid out as the eager
+        `gen_cache` lays it out: heads over 'mp'."""
+        paddle.seed(89)
+        model = _tiny_lm(cap=32)
+        engine = InferenceEngine(model, slots=2, max_length=32)
+        got = engine._slot_cache(Request(np.arange(3), max_new_tokens=2), 0)
+        want = _raw_tree(model.gen_cache(1, 32, block_size=0))
+        for g, w in zip(jax.tree_util.tree_leaves(got),
+                        jax.tree_util.tree_leaves(want)):
+            assert g.sharding.is_equivalent_to(w.sharding, w.ndim)
+            assert g.sharding.spec[1] == "mp"
+
+    def _mixed_admissions(self, engine, seed=0):
+        """Two one-shot prompts (under the chunk) and two chunked ones."""
+        r = np.random.default_rng(seed)
+        reqs = [Request(r.integers(0, 48, size=n), max_new_tokens=m)
+                for n, m in [(5, 4), (19, 5), (7, 3), (13, 6)]]
+        for q in reqs:
+            engine.submit(q)
+        return reqs, engine.run()
+
+    def test_both_paths_share_one_slot_cache_and_prefill_program(
+            self, trivial_mesh, monkeypatch):
+        """A one-shot prompt in bucket 8 and a chunk of 8 hand
+        `PrefillStep` the same signature: one compile between them, and
+        `SlotCache` compiles once over every admission."""
+        monkeypatch.setenv("PADDLE_SERVE_BUCKETS", "8,16")
+        paddle.seed(97)
+        engine = InferenceEngine(_tiny_lm(cap=32), slots=2, max_length=32,
+                                 sync_every=4, prefill_chunk=8)
+        for seed in (0, 1):
+            self._mixed_admissions(engine, seed)
+            assert engine._slot_cache_jitted.compiles == 1
+            assert engine._prefill.compiles == 1
+            assert engine._decode.compiles == 1
+
+    def test_no_eager_gen_cache_on_the_admission_path(self, trivial_mesh):
+        """The model's `gen_cache` runs once, inside the trace of the
+        compiled program; later admissions call nothing eager."""
+        paddle.seed(101)
+        model = _tiny_lm(cap=32)
+        engine = InferenceEngine(model, slots=2, max_length=32,
+                                 sync_every=4, prefill_chunk=8)
+        real, calls = model.gen_cache, []
+
+        def spy(*args, **kwargs):
+            out = real(*args, **kwargs)
+            leaves = jax.tree_util.tree_leaves(_raw_tree(out))
+            calls.append(all(isinstance(v, jax.core.Tracer)
+                             for v in leaves))
+            return out
+
+        model.gen_cache = spy
+        for seed in (2, 3):
+            self._mixed_admissions(engine, seed)
+        assert calls == [True]
+
+    def test_one_shot_and_chunked_tokens_match_generate(self, trivial_mesh):
+        paddle.seed(103)
+        model = _tiny_lm(cap=32)
+        engine = InferenceEngine(model, slots=2, max_length=32,
+                                 sync_every=4, prefill_chunk=8)
+        reqs, results = self._mixed_admissions(engine, seed=4)
+        for q in reqs:
+            want = generate(model, [q.prompt_ids], q.max_new_tokens,
+                            max_length=32)[0]
+            assert results[q.rid].tokens == [t for t in want.tolist()
+                                             if t >= 0], q.rid
 
 
 # ---------------------------------------------------------------------------

@@ -109,7 +109,8 @@ def lowered_names():
 
 
 @pytest.mark.parametrize(
-    "label", ["TrainStep", "DecodeStep", "PrefillStep", "CacheInsert"])
+    "label", ["TrainStep", "DecodeStep", "PrefillStep", "CacheInsert",
+              "SlotCache"])
 def test_lowered_module_bears_the_ledger_label(lowered_names, label):
     assert lowered_names[label] == f"jit_{label}", lowered_names
 
