@@ -39,6 +39,8 @@ from .core import (  # noqa: F401
     set_grad_enabled,
 )
 from .core.flags import get_flags, set_flags  # noqa: F401
+# the compile ledger's jax.monitoring listener: every compile from here on
+from .observability import ledger as _ledger  # noqa: F401
 
 # the full flat op namespace (paddle.add, paddle.matmul, ...)
 from .ops import *  # noqa: F401,F403

@@ -221,10 +221,6 @@ class _CompiledDecodeBase:
         self._jitted = None
         self._carried_idx = ()
         self._n_steps = 0
-        from ..observability import bus as _bus, ledger as _ledger
-
-        if _bus.enabled():
-            _ledger.install_backend_listener()
 
     # -- the pure forward segment -----------------------------------------
     def _fwd_objs(self, model, p_objs, b_objs, p_raws, b_raws, ids,
@@ -470,10 +466,6 @@ class MigrateInsert:
         self._jitted = None
         self._carried_idx = ()
         self._n_steps = 0
-        from ..observability import bus as _bus, ledger as _ledger
-
-        if _bus.enabled():
-            _ledger.install_backend_listener()
 
     def _step_fn(self, cache_raws, rows, slot, table_row, pos, tok,
                  done, temp, top_k, top_p, eos, budget, adapter, ctx,

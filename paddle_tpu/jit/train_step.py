@@ -341,7 +341,7 @@ class TrainStep:
         # arrays; the jitted program is wrapped by the recompile ledger
         self._n_steps = 0
         self._lower_avals = None
-        from ..observability import bus as _bus, ledger as _ledger
+        from ..observability import bus as _bus
 
         # quantized-compute byte attribution (ISSUE 19): resident matmul-
         # weight bytes under the armed QAT policy and the Adam-moment
@@ -362,7 +362,6 @@ class TrainStep:
             self._guard._sampler.set_quant_bytes(
                 self._q_matmul_info, self._moment_bytes_info)
         if _bus.enabled():
-            _ledger.install_backend_listener()
             _bus.emit("grad_comm", self._grad_comm_info, step=0)
             _bus.emit("q_matmul", self._q_matmul_info, step=0)
             _bus.emit("moment_bytes", self._moment_bytes_info, step=0)

@@ -1,4 +1,4 @@
-"""Recompile ledger — every jit cache miss becomes a bus record.
+"""Compile ledger — every jit cache miss and every compile stage, on record.
 
 A silent recompile is the classic TPU training-loop performance cliff:
 an input whose shape/dtype wobbles per step (a last partial batch, a
@@ -24,14 +24,41 @@ The ledger instruments OUR compiled entry points (``jit.TrainStep``,
   (``args[3].shape: f32[32,128] -> f32[33,128]``) — the answer to "why
   is every step compiling", read straight off the bus.
 
-``install_backend_listener()`` additionally taps ``jax.monitoring``'s
-event-duration stream for backend compile keys, so compiles that happen
-OUTSIDE an instrumented wrapper (eager ops, collectives) still land on
-the bus as ``backend_compile`` rows with their true compile seconds.
-
 ``compile_count()`` is the process-wide miss total.
 ``compile_seconds()`` is the wall time of those compiling calls, summed:
 the part of a process's set-up that went into compiling its steps.
+
+**Compile stages.** One ``jax.monitoring`` listener, installed when this
+module is imported (with ``paddle_tpu``), records every program jax
+makes, eager operations and unledgered jits included, as
+``(stage, start_ns, end_ns, label)`` on ``time.perf_counter_ns()``:
+
+- ``trace``: ``/jax/core/compile/jaxpr_trace_duration`` (a function to
+  its jaxpr; a jit called inside another's trace nests in its parent's);
+- ``lower``: ``/jax/core/compile/jaxpr_to_mlir_module_duration`` (the
+  jaxpr to an MLIR module, Pallas kernels through Mosaic included);
+- ``xla``: ``/jax/core/compile/backend_compile_duration`` of a program
+  the backend compiled (the persistent cache's key, lookup and write
+  inside it);
+- ``cache_read``: ``/jax/compilation_cache/cache_retrieval_time_sec``,
+  and the ``backend_compile_duration`` that wraps it — a program the
+  persistent cache served is read and loaded, not compiled;
+- ``cache_request``, ``cache_hit``, ``cache_miss``: the persistent
+  cache's counts (``compile_requests_use_cache``, ``cache_hits``,
+  ``cache_misses``), as records of no length when each fired.
+
+A record ends when its event fires and starts the event's seconds
+earlier. Its ``label`` is the ledger label of the compiling call that
+contains it (the innermost), set when that call returns; ``None`` for
+everything outside one. Nested records overlap, so a stage's seconds
+are the union of its intervals, never their sum. A record takes in the
+newest unlabelled records of its stage that lie inside it, and a
+lowering the traces inside it: the union of each other stage, and that
+of ``trace`` with ``lower``, stay as they were. The records live in a
+deque of the last :data:`STAGE_RECORDS_MAX`; read them with
+:func:`compile_stages`. After warm-up no compile event fires, so the hot
+path pays nothing. With the bus on, the listener also writes a
+``backend_compile`` row for each backend event.
 
 :func:`jit` is the one way a compiled step is made: it names the function
 by its ledger label before jitting it, so the label on a ``recompile``
@@ -39,24 +66,30 @@ row and the module a device trace shows (``jit_<label>``) are one string.
 """
 from __future__ import annotations
 
+import collections
 import functools
 import os
+import threading
 import time
-from typing import Dict, List, Optional, Tuple
+from typing import List, Optional, Tuple
 
 from . import bus
 
 __all__ = [
     "arg_fingerprint", "diff_fingerprints", "instrument", "jit",
     "LedgeredFunction", "compile_count", "compile_seconds",
-    "install_backend_listener", "reset",
+    "compile_stages", "STAGE_RECORDS_MAX", "reset",
 ]
 
 _STORM_ENV = "PADDLE_OBS_STORM_N"
 
 _total_compiles = 0
 _total_compile_s = 0.0
-_listener_installed = False
+
+#: the compile records kept, newest last; a set-up leaves a few hundred
+STAGE_RECORDS_MAX = 1 << 16
+_stages: collections.deque = collections.deque(maxlen=STAGE_RECORDS_MAX)
+_stages_lock = threading.Lock()
 
 
 def compile_count() -> int:
@@ -70,11 +103,24 @@ def compile_seconds() -> float:
     return _total_compile_s
 
 
+def compile_stages(until_ns: Optional[int] = None
+                   ) -> List[Tuple[str, int, int, Optional[str]]]:
+    """The compile records, oldest first, as ``(stage, start_ns, end_ns,
+    label)``; with ``until_ns`` only those that started before it."""
+    with _stages_lock:
+        recs = [tuple(r) for r in _stages]
+    if until_ns is not None:
+        recs = [r for r in recs if r[1] < until_ns]
+    return recs
+
+
 def reset() -> None:
-    """Tests: zero the process-wide counters."""
+    """Tests: zero the process-wide counters and drop the records."""
     global _total_compiles, _total_compile_s
     _total_compiles = 0
     _total_compile_s = 0.0
+    with _stages_lock:
+        _stages.clear()
 
 
 def _leaf_sig(x) -> str:
@@ -174,16 +220,17 @@ class LedgeredFunction:
             missed = key not in self._seen
             self._seen.add(key)
         if missed:
-            self._on_compile(fp, wall)
+            self._on_compile(fp, t0, wall)
         if fp is not None:
             self._prev_fp = fp
         return out
 
-    def _on_compile(self, fp, wall_s: float) -> None:
+    def _on_compile(self, fp, t0: float, wall_s: float) -> None:
         global _total_compiles, _total_compile_s
         self.compiles += 1
         _total_compiles += 1
         _total_compile_s += wall_s
+        _label_since(int(t0 * 1e9), self.label)
         changed = (diff_fingerprints(self._prev_fp, fp)
                    if self._prev_fp is not None and fp is not None else [])
         if bus.enabled():
@@ -231,27 +278,72 @@ def jit(fn, label: str, *, donate_argnums=(),
         label=label, donate=donate_argnums)
 
 
-def install_backend_listener() -> None:
-    """Tap jax.monitoring's duration events for backend compiles (once
-    per process; covers compiles outside instrumented wrappers). Only
-    meaningful when the bus is on — rows go nowhere otherwise."""
-    global _listener_installed
-    if _listener_installed:
+_DURATION_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "xla",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_read",
+}
+_COUNT_STAGES = {
+    "/jax/compilation_cache/compile_requests_use_cache": "cache_request",
+    "/jax/compilation_cache/cache_hits": "cache_hit",
+    "/jax/compilation_cache/cache_misses": "cache_miss",
+}
+_ABSORBS = {"lower": ("lower", "trace")}
+# a cache hit fires inside the backend_compile_duration that wraps it,
+# in the same thread: that wrapper then read a program, it compiled none
+_served = threading.local()
+
+
+def _on_duration(event: str, secs: float, **kw) -> None:
+    stage = _DURATION_STAGES.get(event)
+    if stage is None:
         return
-    _listener_installed = True
-    try:
-        import jax.monitoring as M
+    end = time.perf_counter_ns()
+    if stage == "xla":
+        if getattr(_served, "hit", False):
+            _served.hit = False
+            stage = "cache_read"
+        if bus.enabled():
+            bus.emit("backend_compile", {
+                "key": event, "seconds": round(float(secs), 3)})
+    start = end - int(secs * 1e9)
+    absorbs = _ABSORBS.get(stage, (stage,))
+    with _stages_lock:
+        # a record absorbs the newest unlabelled records inside it of its
+        # own stage, or traces inside a lowering (the jnp functions traced
+        # inside a jit's trace or as a primitive is lowered, the read
+        # inside the backend step of a hit): the union of trace and lower
+        # is the same, and a step keeps a few records, not thousands
+        while (_stages and _stages[-1][0] in absorbs
+               and _stages[-1][1] >= start and _stages[-1][3] is None):
+            _stages.pop()
+        _stages.append([stage, start, end, None])
 
-        def _on_duration(key: str, value: float, **kw) -> None:
-            # only true XLA backend compiles: the trace/lowering keys
-            # ('jaxpr_trace_duration' etc.) fire for every trivial eager
-            # jaxpr and would drown the stream
-            if "backend_compile" not in key:
-                return
-            if bus.enabled():
-                bus.emit("backend_compile", {
-                    "key": key, "seconds": round(float(value), 3)})
 
-        M.register_event_duration_secs_listener(_on_duration)
-    except Exception:  # noqa: BLE001 — telemetry stays best-effort
-        pass
+def _on_count(event: str, **kw) -> None:
+    stage = _COUNT_STAGES.get(event)
+    if stage is None:
+        return
+    if stage == "cache_hit":
+        _served.hit = True
+    now = time.perf_counter_ns()
+    with _stages_lock:
+        _stages.append([stage, now, now, None])
+
+
+def _label_since(t0_ns: int, label: str) -> None:
+    """Give ``label`` to the unlabelled records that began at or after
+    ``t0_ns``: those of the compiling call that started then."""
+    with _stages_lock:
+        for rec in reversed(_stages):
+            if rec[2] < t0_ns:
+                break
+            if rec[1] >= t0_ns and rec[3] is None:
+                rec[3] = label
+
+
+import jax.monitoring as _monitoring  # noqa: E402
+
+_monitoring.register_event_duration_secs_listener(_on_duration)
+_monitoring.register_event_listener(_on_count)

@@ -286,6 +286,21 @@ class TestRecompileLedger:
         assert any("args[0]" in c for c in p["changing_fields"])
         assert "signature keeps changing" in p["detail"]
 
+    def test_backend_compile_row_from_the_one_listener(self, obs_env,
+                                                       tmp_path):
+        """With the bus on, the ledger's listener writes a
+        ``backend_compile`` row for a compile outside any ledgered call."""
+        busf = str(tmp_path / "bus.jsonl")
+        obs_env.setenv("PADDLE_OBS_BUS_FILE", busf)
+        import jax.numpy as jnp
+
+        jax.jit(lambda x: x * 3 - 1)(jnp.ones((5,)))
+        rows = [r["payload"] for r in bus.read_stream(busf)
+                if r["kind"] == "backend_compile"]
+        assert rows, "no backend_compile row"
+        assert rows[-1]["key"].endswith("backend_compile_duration")
+        assert rows[-1]["seconds"] >= 0
+
     def test_train_step_single_compile(self, obs_env, tmp_path):
         """The real TrainStep compiles exactly once over repeated
         same-shape steps (the out_shardings pinning contract) — and the
