@@ -377,11 +377,12 @@ class ParallelMultiHeadAttention(Layer):
 
         if cache is not None:
             # static-capacity decode-append: write this step's K/V rows
-            # at per-slot `pos`, attend position-masked over the full
-            # capacity. Plain XLA ops throughout, so GSPMD partitions
-            # them over (dp -> batch, mp -> heads) exactly like the
-            # training path — no shard_map seam needed (a traced pos
-            # cannot feed the flash kernel's static q_offset anyway).
+            # at per-slot `pos`, attend position-masked over what the
+            # slot holds. On one chip one Pallas kernel a layer does
+            # both; on a real mesh plain XLA ops, which GSPMD partitions
+            # over (dp -> batch, mp -> heads) exactly like the training
+            # path (a traced pos cannot feed the flash kernel's static
+            # q_offset seam).
             if pos is None:
                 raise ValueError(
                     "cache decoding needs `pos` (per-slot write "
@@ -389,12 +390,10 @@ class ParallelMultiHeadAttention(Layer):
                 )
             from ..nn.layers.transformer import MultiHeadAttention
 
-            k = attn_route.cache_update(cache.k, k, pos)
-            v = attn_route.cache_update(cache.v, v, pos)
-            new_cache = MultiHeadAttention.Cache(k, v)
-            ctx = attn_route.cached_attention(
-                q, k, v, pos, scale=dh ** -0.5
+            ctx, k, v = attn_route.cached_append_attention(
+                q, cache.k, cache.v, k, v, pos, scale=dh ** -0.5
             )
+            new_cache = MultiHeadAttention.Cache(k, v)
             ctx = ctx.transpose([0, 2, 1, 3]).reshape([B, T, H * dh])
             ctx = _constrain(ctx, self.mesh, P(None, None, "mp"))
             return self.out_proj(ctx), new_cache

@@ -57,6 +57,7 @@ __all__ = [
     "shard_factoring", "flash_plan", "flash_routable", "flash_core",
     "flash_core_sharded", "flash_core_routed",
     "scaled_dot_product_attention", "cache_update", "cached_attention",
+    "cached_append_attention",
 ]
 
 
@@ -394,20 +395,22 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
 def _lane_cache_route(c, u):
     """Does a decode step's work on this cache take the Pallas kernels on
     the cache as the chip stores it: the in-place append `kv_append`
-    (ops/pallas/kv_append.py) for the write, `decode_attention`
-    (ops/pallas/decode_attention.py) for the read? None = XLA's own form
-    (the slot-by-slot scatter, the dense attention over the capacity),
-    else the kernels' `interpret` flag. The write and the read ask this
-    one question, because they rest on one fact, and it is decided from
-    what the call is handed and nothing else: one row a slot (`u`, the
-    new K or V row or the query, is [B, H, 1, D]: the decode step) and a
-    float32 or bfloat16 [B, H, cap, D] array whose capacity is whole
-    128-lane tiles and whose head_dim is under a tile's 128 lanes — only
-    then does the chip store capacity in the lanes, so that the kernels'
-    `[B, H, D, cap]` view is a bitcast; at D >= 128 the view would be a
-    copy of the whole cache tensor. A non-trivial mesh keeps XLA's form
-    (GSPMD partitions it; a bare pallas_call it would not). Off the TPU
-    the kernels run only in the interpreter, for tests, by
+    (ops/pallas/kv_append.py) for a write alone, `decode_attention`
+    (ops/pallas/decode_attention.py) for a read alone, and
+    `decode_append_attention` (the same module) where the step's two
+    writes and its read come together (`cached_append_attention`)? None =
+    XLA's own form (the slot-by-slot scatter, the dense attention over
+    the capacity), else the kernels' `interpret` flag. Every one of them
+    asks this one question, because they rest on one fact, and it is
+    decided from what the call is handed and nothing else: one row a slot
+    (`u`, the new K or V row or the query, is [B, H, 1, D]: the decode
+    step) and a float32 or bfloat16 [B, H, cap, D] array whose capacity
+    is whole 128-lane tiles and whose head_dim is under a tile's 128
+    lanes — only then does the chip store capacity in the lanes, so that
+    the kernels' `[B, H, D, cap]` view is a bitcast; at D >= 128 the view
+    would be a copy of the whole cache tensor. A non-trivial mesh keeps
+    XLA's form (GSPMD partitions it; a bare pallas_call it would not).
+    Off the TPU the kernels run only in the interpreter, for tests, by
     `PADDLE_FLASH_DEFAULT=interpret`."""
     import jax.numpy as jnp
 
@@ -437,8 +440,10 @@ def cache_update(cache, new, pos):
     The decode step's write (one row a slot into a plain float cache, on
     the chip) is the same write as one in-place Pallas kernel instead
     (`_lane_cache_route`, ops/pallas/kv_append.py): XLA:TPU expands the
-    vmapped slice into a serial loop of B iterations a cache tensor.
-    `observability.metrics.kv_append_routes()` counts both ways.
+    vmapped slice into a serial loop of B iterations a cache tensor. A
+    decode step that writes K and V and then attends goes through
+    `cached_append_attention`, which folds the write into the read.
+    `observability.metrics.kv_append_routes()` counts every way.
 
     A block-quantized cache (``quantized_comm.QuantKV`` — int8/fp8
     payload at the full cache shape + per-row-block f32 scales, ISSUE
@@ -622,3 +627,64 @@ def cached_attention(query, key, value, pos, *, scale=None):
                                     interpret=interpret)
 
         return AG.apply_nondiff(attend, (query, key, value, pos))
+
+
+def cached_append_attention(query, k_cache, v_cache, k_new, v_new, pos, *,
+                            scale=None):
+    """A decode layer's cache work in one call: the [B, H, Sq, D] new K
+    and V rows written into the caches at per-slot ``pos`` (as
+    `cache_update` writes them), then ``query`` attended over the
+    written caches (as `cached_attention` reads them). Returns
+    ``(out, k_cache', v_cache')``.
+
+    The decode step over a plain float cache on the chip (one row a
+    slot: `_lane_cache_route`, with the read's own checks that
+    `cached_attention` makes, and at most `APPEND_HEADS` (40) heads, so
+    that the query and the two new rows turn in one tile) is one Pallas
+    kernel a layer, `decode_append_attention`
+    (ops/pallas/decode_attention.py): the tile that holds ``pos[b]`` is the last one the read fetches, so
+    the rows go into it there and it is written back once, where
+    `kv_append` would read and write it again in a launch of its own.
+    The result is the two kernels' bit for bit. Every other call is
+    exactly `cache_update` for K, for V, then `cached_attention`:
+    prefill and speculative steps (Sq > 1), a quantized or paged cache,
+    a head_dim of 128 or more, more heads than the turn holds, a sharded
+    cache, the CPU. `observability.metrics.kv_append_routes()["fused"]`
+    counts the rows written inside the kernel (two a call), and
+    `cached_attention_routes()["kernel"]` its read. Inference-only (no
+    VJP)."""
+    from ...core.tensor import Tensor
+    from ...observability.metrics import (record_cached_attention_route,
+                                          record_kv_append_route)
+    from ...ops.pallas.decode_attention import (APPEND_HEADS,
+                                                decode_append_attention)
+
+    sc = scale if scale is not None else int(query.shape[-1]) ** -0.5
+    interpret = None
+    if isinstance(k_cache, Tensor) and isinstance(v_cache, Tensor):
+        kc, vc = k_cache._data, v_cache._data
+        interpret = _lane_cache_route(kc, k_new._data)
+        if (interpret is not None
+                and (_lane_cache_route(kc, v_new._data) is None
+                     or _lane_cache_route(kc, query._data) is None
+                     or kc.shape[1] > APPEND_HEADS
+                     or (vc.shape, vc.dtype) != (kc.shape, kc.dtype))):
+            interpret = None
+    if interpret is None:
+        k = cache_update(k_cache, k_new, pos)
+        v = cache_update(v_cache, v_new, pos)
+        return cached_attention(query, k, v, pos, scale=scale), k, v
+
+    def fused(qr, kr, vr, kn, vn, pr):
+        record_kv_append_route("fused")
+        record_kv_append_route("fused")
+        record_cached_attention_route("kernel")
+        return decode_append_attention(qr, kr, vr, kn, vn, pr, scale=sc,
+                                       interpret=interpret)
+
+    from ... import profiler as _prof
+
+    with _prof.device_annotation("attention::cached"):
+        out, k, v = AG.apply_nondiff(
+            fused, (query, k_cache, v_cache, k_new, v_new, pos))
+    return out, k, v

@@ -283,12 +283,11 @@ class MultiHeadAttention(Layer):
                         )
                     from ..functional import attention as attn_route
 
-                    k = attn_route.cache_update(cache.k, k, pos)
-                    v = attn_route.cache_update(cache.v, v, pos)
-                    cache = MultiHeadAttention.Cache(k, v)
-                    out = attn_route.cached_attention(
-                        q, k, v, pos, scale=self.head_dim ** -0.5
+                    out, k, v = attn_route.cached_append_attention(
+                        q, cache.k, cache.v, k, v, pos,
+                        scale=self.head_dim ** -0.5
                     )
+                    cache = MultiHeadAttention.Cache(k, v)
                     return self._finish_output(out, None, cache)
                 from ...ops.manipulation import concat
 

@@ -369,24 +369,28 @@ def sampler_steps() -> dict:
 # counted where the call is traced
 # ---------------------------------------------------------------------------
 
-_kv_append_routes = {"kernel": 0, "scatter": 0}
+_kv_append_routes = {"kernel": 0, "scatter": 0, "fused": 0}
 
 
 def record_kv_append_route(route: str) -> None:
-    """Count one `nn.functional.attention.cache_update` call: ``kernel``
-    when it became the in-place `kv_append` Pallas kernel, ``scatter``
-    when it kept XLA's write (prefill, speculative steps, quantized and
-    paged caches, a sharded cache, the CPU). Called as the write is
-    traced, so a compiled step counts once however often it runs."""
+    """Count one K or V row write of a cache: ``kernel`` when a
+    `nn.functional.attention.cache_update` call became the in-place
+    `kv_append` Pallas kernel, ``scatter`` when it kept XLA's write
+    (prefill, speculative steps, quantized and paged caches, a sharded
+    cache, the CPU), ``fused`` when `cached_append_attention` wrote the
+    row inside the `decode_append_attention` kernel that attends over
+    it. Called as the write is traced, so a compiled step counts once
+    however often it runs."""
     _kv_append_routes[route] += 1
 
 
 def kv_append_routes() -> dict:
-    """{"kernel": n, "scatter": n} — running totals of the
-    `cache_update` calls traced in this process, by the way each was
+    """{"kernel": n, "scatter": n, "fused": n} — running totals of the
+    K and V row writes traced in this process, by the way each was
     lowered: a `DecodeStep` over a plain float cache on the chip adds
-    2 x layers to ``kernel``, a `PrefillStep` as many to ``scatter``.
-    Process-wide, as :func:`sampler_steps` is."""
+    2 x layers to ``fused`` (and nothing to ``kernel``: its writes are
+    folded into the attention), a `PrefillStep` 2 x layers to
+    ``scatter``. Process-wide, as :func:`sampler_steps` is."""
     return dict(_kv_append_routes)
 
 
@@ -399,19 +403,23 @@ _cached_attention_routes = {"kernel": 0, "dense": 0}
 
 
 def record_cached_attention_route(route: str) -> None:
-    """Count one `nn.functional.attention.cached_attention` call:
-    ``kernel`` when it became the `decode_attention` Pallas kernel that
-    stops at each slot's live length, ``dense`` when it kept XLA's form
-    over the whole capacity (prefill, speculative steps, quantized and
-    paged caches, a sharded cache, the CPU). Called as the read is
-    traced, so a compiled step counts once however often it runs."""
+    """Count one decode attention read of a cache: ``kernel`` when a
+    `nn.functional.attention.cached_attention` call became the
+    `decode_attention` Pallas kernel that stops at each slot's live
+    length, or a `cached_append_attention` call the
+    `decode_append_attention` kernel that also writes the step's rows,
+    ``dense`` when it kept XLA's form over the whole capacity (prefill,
+    speculative steps, quantized and paged caches, a sharded cache, the
+    CPU). Called as the read is traced, so a compiled step counts once
+    however often it runs."""
     _cached_attention_routes[route] += 1
 
 
 def cached_attention_routes() -> dict:
-    """{"kernel": n, "dense": n} — running totals of the
-    `cached_attention` calls traced in this process, by the way each was
-    lowered: a `DecodeStep` over a plain float cache on the chip adds
-    ``layers`` to ``kernel``, a `PrefillStep` as many to ``dense``.
+    """{"kernel": n, "dense": n} — running totals of the decode
+    attention reads traced in this process, by the way each was lowered:
+    a `DecodeStep` over a plain float cache on the chip adds ``layers``
+    to ``kernel`` (one `decode_append_attention` a layer), a
+    `PrefillStep` as many to ``dense``.
     Process-wide, as :func:`kv_append_routes` is."""
     return dict(_cached_attention_routes)

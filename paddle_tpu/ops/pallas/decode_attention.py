@@ -23,6 +23,16 @@ and sum of its own across tiles (float32 whatever the cache holds), and
 `p[h, t] * V[h, d, t]` is accumulated per lane; the lanes are reduced once
 a slot, at the end. Lanes past `pos[b]` in the last live tile weigh
 exactly 0, as the dense form's masked keys do.
+
+`decode_append_attention` is the decode step's whole cache work in one
+call: it also writes the step's new K and V rows. The tile that holds
+`pos[b]` is the last live one the attention fetches, so the rows go into
+its copy in fast memory and that copy goes back to the cache, aliased in
+to out: one whole-tile write a slot a tensor, where `kv_append` first
+reads the same tile again in a launch of its own. There each cache tensor
+enters once, in HBM: a slot's first tiles are the kernel's own copies,
+started while the slot before it computes, and a slot's write is waited
+for two slots later, when its buffer is next filled.
 """
 from __future__ import annotations
 
@@ -44,6 +54,18 @@ TILE = LANES
 # below every score a float32 product of finite operands can reach, and
 # finite itself: exp(_NEG - _NEG) is 1, never nan
 _NEG = -1e30
+
+
+def _stride(heads):
+    """Sublanes between the query's rows and each further row set in one
+    turn: whole 8-sublane tiles, so that every store into the turn is
+    aligned."""
+    return -(-heads // 8) * 8
+
+
+#: the most heads `decode_append_attention` takes: the query's rows and
+#: the two new rows, each from a whole number of 8 sublanes, in one turn
+APPEND_HEADS = LANES // 3 // 8 * 8
 
 
 def _attend_tile(k_ref, v_ref, base, last, q_scr, m_scr, l_scr, acc_scr):
@@ -77,16 +99,26 @@ def _attend_tile(k_ref, v_ref, base, last, q_scr, m_scr, l_scr, acc_scr):
     jax.lax.fori_loop(0, heads // pair, group, None)
 
 
-def _begin(q_ref, scale, turn_scr, q_scr, m_scr, l_scr, acc_scr):
+def _begin(q_ref, scale, turn_scr, q_scr, m_scr, l_scr, acc_scr,
+           rows=()):
     """A slot's start. `q_ref` holds its query rows as the program has
     them, [H, D]: a head's row lies along the lanes, and the scores want
     it down the sublanes. One 128 x 128 turn in the kernel (XLA's own
     transpose of so small an array is a launch of its own, dearer than
     the attention of a short slot), then each head's column is scaled
-    and spread over the lanes once."""
+    and spread over the lanes once. `rows` are further [1, H, D] refs
+    (the new K and V rows) turned with the query, each from its own
+    multiple of 8 sublanes (`_stride`); the turned tile then stays in
+    `turn_scr`, where `_insert` finds their columns."""
     heads, depth = q_ref.shape[1:]
+    stride = _stride(heads)
     turn_scr[:heads, :depth] = q_ref[0].astype(jnp.float32) * scale
+    for i, r in enumerate(rows, 1):
+        turn_scr[i * stride:i * stride + heads, :depth] = (
+            r[0].astype(jnp.float32))
     q = turn_scr[...].T
+    if rows:
+        turn_scr[...] = q
     for h in range(heads):
         q_scr[h] = jnp.broadcast_to(q[:depth, h:h + 1], q_scr.shape[1:])
     m_scr[...] = jnp.full(m_scr.shape, _NEG, jnp.float32)
@@ -213,3 +245,202 @@ def decode_attention(query, key, value, pos, *, scale=None, interpret=False):
         name="decode_attention",
     )(pos, query[:, :, 0, :], kt, vt, kt, vt)
     return out[:, :, None, :]
+
+
+def _insert(k_tile, v_tile, k_dst, v_dst, turn_scr, lane):
+    """The fetched tiles `k_tile` / `v_tile` ([H, D, TILE]) into `k_dst`
+    / `v_dst` with the slot's new K and V rows, whose columns `_begin`
+    left in `turn_scr`, in lane `lane`, as the cache's dtype has them:
+    the value `kv_append` would have written and this kernel would then
+    have read back."""
+    heads, depth = k_tile.shape[:2]
+    stride = _stride(heads)
+    turned = turn_scr[:depth, :]
+    here = jax.lax.broadcasted_iota(jnp.int32, (depth, TILE), 1) == lane
+    for i, (src, dst) in enumerate(((k_tile, k_dst), (v_tile, v_dst)), 1):
+        for h in range(heads):
+            c = i * stride + h
+            row = turned[:, c:c + 1].astype(dst.dtype).astype(jnp.float32)
+            dst[h] = jnp.where(here, row,
+                               src[h].astype(jnp.float32)).astype(dst.dtype)
+
+
+#: buffers of a cache tensor's tiles in fast memory, by first index: a
+#: slot's first tile (by the slot's parity), its later live tiles (by the
+#: tile's), and the tile with the new row in it that goes back to the
+#: cache (by the slot's parity: its write is waited for two slots later)
+_FIRST, _LATER, _WRITTEN = 0, 2, 4
+
+
+def _append_attention_kernel(pos_ref, q_ref, kn_ref, vn_ref, k_hbm, v_hbm,
+                             o_ref, k_out, v_out, turn_scr, q_scr, m_scr,
+                             l_scr, acc_scr, k_buf, v_buf, sem, *, scale):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b = pl.program_id(0)
+    slots = pl.num_programs(0)
+    last = pos_ref[b]
+    tiles = last // TILE + 1
+    bufs = (k_buf, v_buf)
+
+    def copies(kind, s, t):
+        """K's and V's copy of slot `s`'s tile `t`: fetched into the
+        buffer of `kind` (`_FIRST`, `_LATER`), or written back from
+        `_WRITTEN`. The semaphores are laid out as the buffers are."""
+        at = pl.ds(pl.multiple_of(t * TILE, TILE), TILE)
+        i = kind + (t % 2 if kind == _LATER else s % 2)
+        out = []
+        for j, (src, dst) in enumerate(((k_hbm, k_out), (v_hbm, v_out))):
+            hbm, vmem = src.at[s, :, :, at], bufs[j].at[i]
+            if kind == _WRITTEN:
+                hbm, vmem = vmem, dst.at[s, :, :, at]
+            out.append(pltpu.make_async_copy(hbm, vmem, sem.at[j, i]))
+        return out
+
+    def written(s):
+        """Slot `s`'s write, to be waited for (the tile is the last
+        live one of that slot, whose `pos` is at hand)."""
+        return copies(_WRITTEN, s, pos_ref[s] // TILE)
+
+    @pl.when(b == 0)
+    def _():
+        for copy in copies(_FIRST, b, 0):
+            copy.start()
+
+    @pl.when(b + 1 < slots)
+    def _():
+        for copy in copies(_FIRST, b + 1, 0):
+            copy.start()
+
+    @pl.when(tiles > 1)
+    def _():
+        for copy in copies(_LATER, b, 1):
+            copy.start()
+
+    state = (q_scr, m_scr, l_scr, acc_scr)
+    _begin(q_ref, scale, turn_scr, *state, rows=(kn_ref, vn_ref))
+
+    def attend(i, t):
+        """Fold tile `t`, fetched into buffer `i`; the last live one
+        takes the new rows first, into the buffer that goes back."""
+        is_last = t == tiles - 1
+        out = _WRITTEN + b % 2
+
+        @pl.when(is_last)
+        def _():
+            @pl.when(b >= 2)
+            def _():
+                for copy in written(b - 2):
+                    copy.wait()
+
+            _insert(k_buf.at[i], v_buf.at[i], k_buf.at[out], v_buf.at[out],
+                    turn_scr, last % TILE)
+            for copy in copies(_WRITTEN, b, t):
+                copy.start()
+
+        at = jnp.where(is_last, out, i)
+        _attend_tile(k_buf.at[at], v_buf.at[at], t * TILE, last, *state)
+
+    for copy in copies(_FIRST, b, 0):
+        copy.wait()
+    attend(_FIRST + b % 2, 0)
+
+    def later(t, carry):
+        @pl.when(t + 1 < tiles)
+        def _():
+            for copy in copies(_LATER, b, t + 1):
+                copy.start()
+
+        for copy in copies(_LATER, b, t):
+            copy.wait()
+        attend(_LATER + t % 2, t)
+        return carry
+
+    jax.lax.fori_loop(1, tiles, later, None)
+    _finish(o_ref, turn_scr, m_scr, l_scr, acc_scr)
+
+    # the writes still in flight when the grid ends
+    @pl.when(b == slots - 1)
+    def _():
+        @pl.when(b >= 1)
+        def _():
+            for copy in written(b - 1):
+                copy.wait()
+
+        for copy in written(b):
+            copy.wait()
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def decode_append_attention(query, key, value, k_new, v_new, pos, *,
+                            scale=None, interpret=False):
+    """`kv_append(key, k_new, pos)`, `kv_append(value, v_new, pos)` and
+    `decode_attention` over the two in one kernel: returns the attention
+    [B, H, 1, D] and the caches with `k_new` / `v_new` ([B, H, 1, D])
+    cast to the cache dtype and written at `[b, :, pos[b], :]`, bit for
+    bit what the three calls give. The cache's tile that holds `pos[b]`
+    is the last one the attention fetches anyway, so the rows go into its
+    copy in fast memory before it is attended and that copy goes back in
+    place (the caches are aliased in to out): one whole-tile write a slot
+    a tensor, where `kv_append` reads the tile again. Each cache tensor
+    enters once, in HBM; a slot's first tiles are fetched while the slot
+    before it computes, so the grid runs in order. `pos` as `kv_append`
+    takes it: a negative one counts from the end, then it is clamped into
+    `[0, cap)`. At most `APPEND_HEADS` (40) heads: a slot's query and
+    two new rows are turned in one 128 x 128 tile. `interpret=True` runs
+    the Pallas interpreter (CPU tests)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, H, cap, D = key.shape
+    row_shape = (B, H, 1, D)
+    if (cap % TILE or H > APPEND_HEADS or D > LANES
+            or query.shape != row_shape or k_new.shape != row_shape
+            or v_new.shape != row_shape
+            or value.shape != key.shape or value.dtype != key.dtype):
+        raise ValueError(
+            f"decode_append_attention: key {key.shape} {key.dtype} wants "
+            f"a capacity that is a multiple of {TILE}, at most "
+            f"{APPEND_HEADS} heads of at most {LANES} (the query and "
+            f"the new rows are turned in one {LANES} x {LANES} tile), a "
+            f"value like it and a query and new rows [B, H, 1, D], got "
+            f"value {value.shape} {value.dtype}, query {query.shape}, "
+            f"new rows {k_new.shape} and {v_new.shape}")
+    sc = scale if scale is not None else D ** -0.5
+    out_dtype = jnp.result_type(query.dtype, key.dtype, value.dtype)
+    pos = jnp.asarray(pos, jnp.int32)
+    pos = jnp.clip(jnp.where(pos < 0, pos + cap, pos), 0, cap - 1)
+    row = pl.BlockSpec((1, H, D), lambda b, pos: (b, 0, 0))
+    whole = pl.BlockSpec(memory_space=pl.ANY)
+    kt, vt = jnp.swapaxes(key, 2, 3), jnp.swapaxes(value, 2, 3)
+    out, kt, vt = pl.pallas_call(
+        functools.partial(_append_attention_kernel, scale=sc),
+        out_shape=(jax.ShapeDtypeStruct((B, H, D), out_dtype),
+                   jax.ShapeDtypeStruct(kt.shape, kt.dtype),
+                   jax.ShapeDtypeStruct(vt.shape, vt.dtype)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B,),
+            in_specs=[row, row, row, whole, whole],
+            out_specs=[row, whole, whole],
+            scratch_shapes=[
+                pltpu.VMEM((LANES, LANES), jnp.float32),     # the turns
+                pltpu.VMEM((H, D, TILE), jnp.float32),       # q, spread
+                pltpu.VMEM((H, 1, TILE), jnp.float32),       # maximum
+                pltpu.VMEM((H, 1, TILE), jnp.float32),       # sum
+                pltpu.VMEM((H, D, TILE), jnp.float32),       # values
+                pltpu.VMEM((6, H, D, TILE), key.dtype),      # the tiles
+                pltpu.VMEM((6, H, D, TILE), value.dtype),
+                pltpu.SemaphoreType.DMA((2, 6)),
+            ],
+        ),
+        # operand 0 is the prefetched `pos`, 1-3 the query and new rows
+        input_output_aliases={4: 1, 5: 2},
+        # slot b + 1's first tiles and slot b's write are carried from
+        # slot b's step into later ones: the grid runs in order
+        compiler_params=_tpu_params("arbitrary"),
+        interpret=interpret,
+        name="decode_append_attention",
+    )(pos, query[:, :, 0, :], k_new[:, :, 0, :], v_new[:, :, 0, :], kt, vt)
+    return out[:, :, None, :], jnp.swapaxes(kt, 2, 3), jnp.swapaxes(vt, 2, 3)

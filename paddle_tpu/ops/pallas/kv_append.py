@@ -16,6 +16,11 @@ the block index is data), puts the new row into lane `pos[b] % 128` and
 writes the tile back — the smallest whole-tile read-modify-write the
 stored layout allows. Blocks no step visits keep the input's bytes
 because the output IS the input buffer.
+
+A decode step that attends right after it writes does not come here:
+`decode_append_attention` (ops/pallas/decode_attention.py) writes the
+rows into the tile its attention fetches anyway. This kernel serves a
+write alone (`nn.functional.attention.cache_update`).
 """
 from __future__ import annotations
 

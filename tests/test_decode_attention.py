@@ -232,3 +232,89 @@ def test_write_and_read_answer_from_the_one_predicate(monkeypatch):
     # the write asks of its cache and its new row, the read of K and q
     assert asked == [((2, 2, 256, 64), (2, 2, 1, 64))] * 2
     assert step(lambda c, u: None) == (0, 0, 1, 1)
+
+
+# -- the append folded in: decode_append_attention ---------------------------
+
+
+from paddle_tpu.ops.pallas.decode_attention import (  # noqa: E402
+    decode_append_attention)
+from paddle_tpu.ops.pallas.kv_append import kv_append  # noqa: E402
+
+APPEND_CAP = 3 * TILE
+# a tile's first and last row, the next tile's first, the last row
+APPEND_POS = np.array([0, TILE - 1, TILE, APPEND_CAP - 1])
+
+
+def _append_operands(heads, dtype, seed=7):
+    """q, K, V at the cache dtype and float32 new rows (the kernel casts
+    them, as `kv_append` does)."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    b, shape = len(APPEND_POS), (len(APPEND_POS), heads, APPEND_CAP, D)
+    q = jax.random.normal(ks[0], (b, heads, 1, D), jnp.float32)
+    k = jax.random.normal(ks[1], shape, jnp.float32).astype(dtype)
+    v = jax.random.normal(ks[2], shape, jnp.float32).astype(dtype)
+    kn = jax.random.normal(ks[3], (b, heads, 1, D), jnp.float32)
+    vn = jax.random.normal(ks[4], (b, heads, 1, D), jnp.float32)
+    return q.astype(dtype), k, v, kn, vn
+
+
+@pytest.mark.parametrize("heads", [16, 20, 7])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_append_attention_is_the_two_kernels_bit_for_bit(dtype, heads):
+    """`kv_append` of K, of V, then `decode_attention`: the attention and
+    both caches, bit for bit, with `pos` at a tile's first and last row,
+    the next tile's first and the capacity's last in one batch; and
+    nothing changed in either cache but the rows written at `pos`."""
+    q, k, v, kn, vn = _append_operands(heads, dtype)
+    pos = jnp.asarray(APPEND_POS, jnp.int32)
+    out, k2, v2 = decode_append_attention(q, k, v, kn, vn, pos,
+                                          interpret=True)
+    k1, v1 = kv_append(k, kn, pos, True), kv_append(v, vn, pos, True)
+    want = decode_attention(q, k1, v1, pos, interpret=True)
+    assert out.dtype == want.dtype == dtype and out.shape == want.shape
+    assert bool((out == want).all())
+    assert bool((k2 == k1).all()) and bool((v2 == v1).all())
+    rows = (np.arange(APPEND_CAP)[None, :] == APPEND_POS[:, None])
+    for new, old, written in ((k2, k, kn), (v2, v, vn)):
+        changed = np.asarray((new != old).any(axis=(1, 3)))
+        assert not (changed & ~rows).any()
+        at = new[np.arange(len(APPEND_POS)), :, APPEND_POS, :]
+        assert bool((at == written[:, :, 0, :].astype(dtype)).all())
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_append_attention_matches_the_dense_form(dtype, monkeypatch):
+    """Through `cached_append_attention`: the fused kernel's attention
+    gives the dense form's (`cache_update`'s scatter, then `core` over
+    the capacity) to its rounding, and its caches are the scatter's bit
+    for bit."""
+    q, k, v, kn, vn = (Tensor._wrap(a) for a in _append_operands(20, dtype))
+    pos = Tensor._wrap(jnp.asarray(APPEND_POS, jnp.int32))
+    monkeypatch.delenv("PADDLE_FLASH_DEFAULT", raising=False)
+    want = attn_route.cached_append_attention(q, k, v, kn, vn, pos)
+    monkeypatch.setenv("PADDLE_FLASH_DEFAULT", "interpret")
+    got = attn_route.cached_append_attention(q, k, v, kn, vn, pos)
+    tol = 2e-6 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(got[0]._data, np.float32),
+                               np.asarray(want[0]._data, np.float32),
+                               rtol=tol, atol=tol)
+    for a, b in zip(got[1:], want[1:]):
+        assert bool((a._data == b._data).all())
+
+
+@pytest.mark.parametrize("k_shape,q_shape,new_shape", [
+    ((2, 2, 100, 64), (2, 2, 1, 64), (2, 2, 1, 64)),  # no whole lane tiles
+    ((2, 2, 128, 64), (2, 2, 2, 64), (2, 2, 2, 64)),  # two rows a slot
+    ((2, 2, 128, 64), (2, 2, 1, 64), (2, 2, 1, 32)),  # rows unlike the cache
+    ((1, 41, 128, 8), (1, 41, 1, 8), (1, 41, 1, 8)),  # past one turn
+])
+def test_append_attention_refuses_what_it_cannot_tile(k_shape, q_shape,
+                                                      new_shape):
+    with pytest.raises(ValueError, match="decode_append_attention"):
+        decode_append_attention(
+            jnp.zeros(q_shape), jnp.zeros(k_shape), jnp.zeros(k_shape),
+            jnp.zeros(new_shape), jnp.zeros(new_shape),
+            jnp.zeros(k_shape[0], jnp.int32), interpret=True)

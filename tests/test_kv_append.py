@@ -135,7 +135,7 @@ def test_decode_shape_takes_the_kernel(monkeypatch):
     monkeypatch.setenv("PADDLE_FLASH_DEFAULT", "interpret")
     cache, u = _plain()
     out, routes = _update(cache, u, [128, 7])
-    assert routes == {"kernel": 1, "scatter": 0}
+    assert routes == {"kernel": 1, "scatter": 0, "fused": 0}
     assert bool((out._data == _scatter(cache._data, u,
                                        jnp.asarray([128, 7]))).all())
 
@@ -144,7 +144,7 @@ def test_cpu_without_the_interpreter_keeps_the_scatter(monkeypatch):
     monkeypatch.delenv("PADDLE_FLASH_DEFAULT", raising=False)
     cache, u = _plain()
     _, routes = _update(cache, u, [128, 7])
-    assert routes == {"kernel": 0, "scatter": 1}
+    assert routes == {"kernel": 0, "scatter": 1, "fused": 0}
 
 
 def _sq_gt_1():
@@ -197,4 +197,4 @@ def test_every_other_call_keeps_the_scatter(case, monkeypatch):
     monkeypatch.setenv("PADDLE_FLASH_DEFAULT", "interpret")
     cache, u, pos = case()
     _, routes = _update(cache, u, pos)
-    assert routes == {"kernel": 0, "scatter": 1}
+    assert routes == {"kernel": 0, "scatter": 1, "fused": 0}

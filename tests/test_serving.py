@@ -75,6 +75,15 @@ def _tiny_lm(vocab=48, cap=24, layers=2, heads=4, d=32):
     return m
 
 
+def _unfused_append_attention(query, k_cache, v_cache, k_new, v_new, pos,
+                               *, scale=None):
+    """`cached_append_attention` as its three calls, never folded: the
+    write of K, of V, then the read."""
+    k = attn_route.cache_update(k_cache, k_new, pos)
+    v = attn_route.cache_update(v_cache, v_new, pos)
+    return attn_route.cached_attention(query, k, v, pos, scale=scale), k, v
+
+
 def _ref_greedy(model, prompts, n):
     """Cache-OFF reference: full forward over the growing sequence at
     every step — the oracle the cached decode must match exactly."""
@@ -687,14 +696,18 @@ class TestInferenceEngine:
 
     def test_kv_append_kernel_serves_the_same_tokens(
             self, trivial_mesh, monkeypatch):
-        """With the `kv_append` kernel forced in the interpreter a greedy
-        run emits the tokens of XLA's scatter, `DecodeStep` compiles
-        once, and `kv_append_routes()` says that the decode program's
-        2 x layers appends took the kernel and none of prefill's did."""
+        """With the `kv_append` kernel forced in the interpreter (and the
+        decode step's writes and read made as separate calls, so that
+        they are not folded into one kernel) a greedy run emits the
+        tokens of XLA's scatter, `DecodeStep` compiles once, and
+        `kv_append_routes()` says that the decode program's 2 x layers
+        appends took the kernel and none of prefill's did."""
         from paddle_tpu.nn.functional import attention as attn_route
         from paddle_tpu.observability import metrics
 
         monkeypatch.setenv("PADDLE_FLASH_DEFAULT", "interpret")
+        monkeypatch.setattr(attn_route, "cached_append_attention",
+                            _unfused_append_attention)
         layers = 2
         prompts = [rng.randint(0, 48, size=(n,)) for n in (5, 11, 3)]
 
@@ -715,7 +728,8 @@ class TestInferenceEngine:
         engine, tokens, routes = serve()
         assert engine._decode.compiles == 1
         assert routes == {"kernel": 2 * layers,
-                          "scatter": 2 * layers * engine._prefill.compiles}
+                          "scatter": 2 * layers * engine._prefill.compiles,
+                          "fused": 0}
         with monkeypatch.context() as m:
             m.setattr(attn_route, "_lane_cache_route", lambda c, u: None)
             _, want, old = serve()
@@ -761,6 +775,58 @@ class TestInferenceEngine:
             _, want, old = serve()
         assert old["kernel"] == 0 and old["dense"] == routes["dense"] + layers
         assert tokens == want and [len(t) for t in tokens] == [6, 5, 4, 7]
+
+    def test_append_attention_kernel_serves_the_same_tokens(
+            self, trivial_mesh, monkeypatch):
+        """With the decode step's cache work forced through the fused
+        `decode_append_attention` kernel in the interpreter a greedy run
+        emits the tokens of XLA's scatter and dense read and of the two
+        kernels it replaces, `DecodeStep` compiles once, and the counters
+        say that the decode program wrote its 2 x layers rows inside the
+        kernel (none through `kv_append`) and read `layers` times through
+        it, and that prefill kept XLA's write and read."""
+        from paddle_tpu.observability import metrics
+
+        monkeypatch.setenv("PADDLE_FLASH_DEFAULT", "interpret")
+        layers = 2
+        prompts = [rng.randint(0, 48, size=(n,)) for n in (9, 2, 130, 6)]
+
+        def serve():
+            paddle.seed(83)
+            # three lane tiles a slot: the 130-token prompt writes and
+            # reads past a tile's edge, the others inside their first
+            engine = InferenceEngine(_tiny_lm(cap=384, layers=layers),
+                                     slots=2, max_length=384, sync_every=3)
+            reqs = [Request(p, max_new_tokens=m)
+                    for p, m in zip(prompts, (6, 5, 4, 7))]
+            for q in reqs:
+                engine.submit(q)
+            w0 = metrics.kv_append_routes()
+            r0 = metrics.cached_attention_routes()
+            results = engine.run()
+            w1 = metrics.kv_append_routes()
+            r1 = metrics.cached_attention_routes()
+            return (engine, [results[q.rid].tokens for q in reqs],
+                    {k: w1[k] - w0[k] for k in w1},
+                    {k: r1[k] - r0[k] for k in r1})
+
+        engine, tokens, writes, reads = serve()
+        prefills = engine._prefill.compiles
+        assert engine._decode.compiles == 1
+        assert writes == {"kernel": 0, "scatter": 2 * layers * prefills,
+                          "fused": 2 * layers}
+        assert reads == {"kernel": layers, "dense": layers * prefills}
+        with monkeypatch.context() as m:
+            m.setattr(attn_route, "cached_append_attention",
+                      _unfused_append_attention)
+            _, two_kernels, split, _ = serve()
+        assert split["fused"] == 0 and split["kernel"] == 2 * layers
+        with monkeypatch.context() as m:
+            m.setattr(attn_route, "_lane_cache_route", lambda c, u: None)
+            _, want, old, _ = serve()
+        assert old["fused"] == 0 and old["kernel"] == 0
+        assert tokens == two_kernels == want
+        assert [len(t) for t in tokens] == [6, 5, 4, 7]
 
     @pytest.mark.slow
     def test_insert_on_free_many_requests(self, trivial_mesh):
@@ -1163,3 +1229,107 @@ class TestAdapterSetUnit:
         out2 = np.asarray(blk._adapter_delta(
             paddle.to_tensor(x), paddle.to_tensor(ids))._data)
         assert np.all(out2 == 0.0)
+
+
+# ---------------------------------------------------------------------------
+# cached_append_attention: one seam for a decode layer's writes and read
+# ---------------------------------------------------------------------------
+
+
+def _seam_operands(shape=(2, 2, 256, 64), sq=1):
+    B, H, cap, D = shape
+    ks = jax.random.split(jax.random.PRNGKey(17), 4)
+    c = jax.random.normal(ks[0], shape, jnp.float32)
+    q, kn, vn = (jax.random.normal(k, (B, H, sq, D), jnp.float32)
+                 for k in ks[1:])
+    return (Tensor._wrap(q), Tensor._wrap(c), Tensor._wrap(c + 1),
+            Tensor._wrap(kn), Tensor._wrap(vn))
+
+
+def _seam_decode():
+    return _seam_operands()
+
+
+def _seam_sq_2():
+    return _seam_operands(sq=2)
+
+
+def _seam_heads_48():
+    # more heads than the fused kernel turns with the two new rows
+    return _seam_operands(shape=(2, 48, 128, 8))
+
+
+def _seam_quantized():
+    from paddle_tpu.distributed import quantized_comm as qc
+
+    q, _, _, kn, vn = _seam_operands()
+    p, s = qc.kv_zero((2, 2, 256, 64), "int8")
+    cache = qc.QuantKV(Tensor._wrap(p), Tensor._wrap(s))
+    return q, cache, cache, kn, vn
+
+
+def _seam_paged():
+    from paddle_tpu.serving import paged_kv as pk
+
+    q, _, _, kn, vn = _seam_operands()
+    raw = pk.paged_zero(2, 2, 256, 64, block=128, dtype=jnp.float32)
+    cache = pk.PagedKV(Tensor._wrap(raw.kv), Tensor._wrap(raw.table))
+    return q, cache, cache, kn, vn
+
+
+def _arrays(x):
+    if isinstance(x, tuple):
+        return [a for part in x for a in _arrays(part)]
+    return [x._data]
+
+
+@pytest.mark.parametrize("case,writes,reads", [
+    (_seam_decode, {"kernel": 0, "scatter": 0, "fused": 2},
+     {"kernel": 1, "dense": 0}),
+    (_seam_heads_48, {"kernel": 2, "scatter": 0, "fused": 0},
+     {"kernel": 1, "dense": 0}),
+    (_seam_sq_2, {"kernel": 0, "scatter": 2, "fused": 0},
+     {"kernel": 0, "dense": 1}),
+    (_seam_quantized, {"kernel": 0, "scatter": 2, "fused": 0},
+     {"kernel": 0, "dense": 1}),
+    (_seam_paged, {"kernel": 0, "scatter": 2, "fused": 0},
+     {"kernel": 0, "dense": 1}),
+], ids=["decode", "heads_48", "sq_2", "quantized", "paged"])
+def test_cached_append_attention_routes(case, writes, reads, trivial_mesh,
+                                        monkeypatch):
+    """With the kernels forced in the interpreter, the decode step's one
+    row a slot over a plain float cache is one fused kernel (two rows
+    written inside it, one read); more heads than its turn holds keep
+    the two kernels it replaces; prefill and speculative steps (Sq > 1),
+    `QuantKV` and `PagedKV` keep XLA's write and read. Each gives what
+    the three calls give, bit for bit."""
+    from paddle_tpu.observability import metrics
+
+    monkeypatch.setenv("PADDLE_FLASH_DEFAULT", "interpret")
+    q, kc, vc, kn, vn = case()
+    pos = Tensor._wrap(jnp.asarray([3, 127], jnp.int32))
+    w0, r0 = metrics.kv_append_routes(), metrics.cached_attention_routes()
+    got = attn_route.cached_append_attention(q, kc, vc, kn, vn, pos)
+    w1, r1 = metrics.kv_append_routes(), metrics.cached_attention_routes()
+    assert {k: w1[k] - w0[k] for k in w1} == writes
+    assert {k: r1[k] - r0[k] for k in r1} == reads
+    want = _unfused_append_attention(q, kc, vc, kn, vn, pos)
+    for a, b in zip(_arrays(got), _arrays(want), strict=True):
+        assert a.shape == b.shape and bool((a == b).all())
+
+
+def test_cached_append_attention_off_the_chip_keeps_xla(trivial_mesh,
+                                                       monkeypatch):
+    """Without the interpreter forced, the CPU keeps XLA's write and
+    dense read."""
+    from paddle_tpu.observability import metrics
+
+    monkeypatch.delenv("PADDLE_FLASH_DEFAULT", raising=False)
+    q, kc, vc, kn, vn = _seam_decode()
+    pos = Tensor._wrap(jnp.asarray([3, 127], jnp.int32))
+    w0, r0 = metrics.kv_append_routes(), metrics.cached_attention_routes()
+    attn_route.cached_append_attention(q, kc, vc, kn, vn, pos)
+    w1, r1 = metrics.kv_append_routes(), metrics.cached_attention_routes()
+    assert {k: w1[k] - w0[k] for k in w1} == {
+        "kernel": 0, "scatter": 2, "fused": 0}
+    assert {k: r1[k] - r0[k] for k in r1} == {"kernel": 0, "dense": 1}

@@ -441,3 +441,88 @@ def test_decode_attention_reads_the_stored_view_compiled_for_v5e(
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 16 << 20, mem.temp_size_in_bytes
     assert mem.alias_size_in_bytes == 2 * layers * B * H * cap * D * 4
+
+
+def _fused_decode_program(one_chip, monkeypatch, B, H, dtype, layers=2,
+                          cap=1024, D=64):
+    """What a `DecodeStep` does to its caches through the one seam it
+    calls, `cached_append_attention` (the K and V rows written at `pos`
+    and the one query row a slot attended, a layer at a time), compiled
+    for the described chip with the caches donated; with it, how the
+    trace counted the writes and the reads. (The route asks
+    `jax.default_backend()`, the CPU here: the test answers for it.)"""
+    from paddle_tpu.core.tensor import Tensor
+    from paddle_tpu.nn.functional import attention as attn_route
+    from paddle_tpu.observability.metrics import (cached_attention_routes,
+                                                  kv_append_routes)
+
+    monkeypatch.setattr(attn_route, "_lane_cache_route", lambda c, u: False)
+    T = Tensor._wrap
+
+    def step(caches, q, u, pos):
+        x, out = q, []
+        for k, v in caches:
+            o, k, v = attn_route.cached_append_attention(
+                T(q + x), T(k), T(v), T(u + x), T(u - x), T(pos))
+            x = x + o._data
+            out.append((k._data, v._data))
+        return out, x
+
+    cache = _aval(one_chip, B, H, cap, D, dtype=dtype)
+    row = _aval(one_chip, B, H, 1, D, dtype=dtype)
+    w0, r0 = kv_append_routes(), cached_attention_routes()
+    compiled = jax.jit(step, donate_argnums=(0,)).lower(
+        [(cache, cache)] * layers, row, row,
+        _aval(one_chip, B, dtype=jnp.int32)).compile()
+    w1, r1 = kv_append_routes(), cached_attention_routes()
+    return compiled, ({r: w1[r] - w0[r] for r in w1},
+                      {r: r1[r] - r0[r] for r in r1})
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("B,H", [(32, 16), (16, 20)], ids=["chat", "batch"])
+def test_decode_append_attention_writes_in_place_compiled_for_v5e(
+        one_chip, B, H, dtype, monkeypatch):
+    """The decode step's cache work through `cached_append_attention` at
+    the chat and the batch cell's caches (1,024 x 64, two layers,
+    donated): one `decode_append_attention` custom call a layer and no
+    `kv_append`, each cache tensor its operand once, as the `[B, H, D,
+    cap]` view (a bitcast), and aliased in to out; no copy of a `[B, H,
+    D, 1]` row, no temporary the size of a cache tensor, and nothing in
+    the program that touches a whole cache tensor but parameters,
+    bitcasts, the result's tuple and the kernel."""
+    cap, D, layers = 1024, 64, 2
+    compiled, (writes, reads) = _fused_decode_program(
+        one_chip, monkeypatch, B, H, dtype)
+    assert writes == {"kernel": 0, "scatter": 0, "fused": 2 * layers}
+    assert reads == {"kernel": layers, "dense": 0}
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line]
+    assert len(calls) == layers, calls
+    assert all("decode_append_attention" in c and "kv_append" not in c
+               for c in calls), calls
+    name = jnp.dtype(dtype).name.replace("float", "f")
+    view = f"{name}[{B},{H},{D},{cap}]{{3,2,1,0"
+    for line in calls:
+        # the result (out, K, V) and the operands K and V, once each
+        assert line.count(view) == 4, line
+    for line in calls:
+        # the query and the new rows come as the projection gives them
+        # ([B, H, D]): no launch of its own lays a row out for the kernel
+        operands = re.search(r"custom-call\(([^)]*)\)", line).group(1)
+        assert not re.search(r"%copy(\.\d+)?(,|$)", operands), operands
+    stored, seen = f"[{B},{H},{cap},{D}]", f"[{B},{H},{D},{cap}]"
+    for line in text.splitlines():
+        made = re.match(r"\s*(?:ROOT )?%\S+ = .*? ([\w-]+)\(", line)
+        if made is None or not (stored in line or seen in line):
+            continue
+        assert made.group(1) in ("parameter", "bitcast", "tuple",
+                                 "get-tuple-element") or (
+            made.group(1) == "custom-call"
+            and "decode_append_attention" in line), line
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 16 << 20, mem.temp_size_in_bytes
+    assert mem.alias_size_in_bytes == (
+        2 * layers * B * H * cap * D * jnp.dtype(dtype).itemsize)
