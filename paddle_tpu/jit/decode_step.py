@@ -224,7 +224,8 @@ class _CompiledDecodeBase:
 
     # -- the pure forward segment -----------------------------------------
     def _fwd_objs(self, model, p_objs, b_objs, p_raws, b_raws, ids,
-                  cache_raws, pos, label=None, adapter=None, carried=None):
+                  cache_raws, pos, label=None, adapter=None, carried=None,
+                  length=None):
         """A model forward with the KV-cache seam as a pure function of
         (params, buffers, ids, caches, pos) -> (logits, new caches).
         Parameterized over the model so SpeculativeDecodeStep can run
@@ -234,7 +235,10 @@ class _CompiledDecodeBase:
         ``carried`` (a list) collects (buffer index, new raw) of every
         buffer the forward replaced — a device counter such as
         `nn.RoutedExperts.load`; a model that replaces none leaves it
-        empty and its program as it was."""
+        empty and its program as it was. ``length`` ([B] int32, each
+        row's valid tokens) is forwarded only to a model that declares
+        ``takes_length`` (a state would advance over padding); every other
+        model's call, and program, stays as it was."""
         from .. import profiler as _prof
 
         objs = p_objs + b_objs
@@ -242,6 +246,8 @@ class _CompiledDecodeBase:
         kw = {}
         if adapter is not None:
             kw["adapter"] = Tensor._wrap(adapter)
+        if length is not None and getattr(model, "takes_length", False):
+            kw["length"] = Tensor._wrap(length)
         with AG.trace_mode(), \
                 _prof.device_annotation(
                     label or f"{self._label}::forward"), \
@@ -258,13 +264,14 @@ class _CompiledDecodeBase:
                     enumerate(zip(b_objs, b_raws)) if b._data is not r)
         return logits, new_raws
 
-    def _fwd(self, p_raws, b_raws, ids, cache_raws, pos, adapter=None):
+    def _fwd(self, p_raws, b_raws, ids, cache_raws, pos, adapter=None,
+             length=None):
         """-> (logits, new caches, the replaced buffers' new values);
         which buffers those are is noted at trace time for `_rebind`."""
         carried = []
         logits, new_raws = self._fwd_objs(
             self.model, self._p_objs, self._b_objs, p_raws, b_raws, ids,
-            cache_raws, pos, adapter=adapter, carried=carried)
+            cache_raws, pos, adapter=adapter, carried=carried, length=length)
         self._carried_idx = tuple(i for i, _ in carried)
         return logits, new_raws, tuple(r for _, r in carried)
 
@@ -381,6 +388,10 @@ class PrefillStep(_CompiledDecodeBase):
     step whose query sits at p (write-then-attend), so a stale row is
     never read.
 
+    A model that declares ``takes_length`` (a state-space hybrid, whose
+    state padding would advance) is handed ``length`` as well; no other
+    model sees it.
+
     Round 13 (chunked prefill): ``start`` ([B] int32, default zeros)
     writes the chunk at positions start..start+len-1 instead of 0 —
     the prefill-with-history continuation the engine interleaves with
@@ -396,7 +407,7 @@ class PrefillStep(_CompiledDecodeBase):
         logits, new_caches, carried = self._fwd(
             p_raws, b_raws, ids, cache_raws,
             jnp.asarray(start, jnp.int32),
-            adapter=adapter if self._use_adapters else None,
+            adapter=adapter if self._use_adapters else None, length=length,
         )
         idx = jnp.clip(length - 1, 0, ids.shape[1] - 1)
         last = jnp.take_along_axis(
@@ -687,6 +698,17 @@ class SpeculativeDecodeStep(_CompiledDecodeBase):
 
     def __call__(self, state: SpecDecodeState):
         """-> (emitted [B, k+1] int32 with -1 sentinels, new state)."""
+        if self._jitted is None:
+            from ..nn.functional.dsa import is_row_cache
+            from ..nn.functional.ssm import SSMState
+
+            kind = is_row_cache((state.caches, state.draft_caches),
+                                (SSMState,))
+            if kind is not None:
+                raise TypeError(
+                    f"SpeculativeDecodeStep rolls a rejected draft back by "
+                    f"position and refuses {kind}: a state cannot be "
+                    "rolled back; decode it with DecodeStep")
         state = SpecDecodeState(*_commit_tree(state.astuple()))
         args = (
             tuple(p._data for p in self._p_objs),

@@ -29,6 +29,7 @@ from .layers import conv  # noqa: F401,E402
 # that does not name them pays their import
 _LATENT = ("RMSNorm", "GatedMLP", "LatentAttention", "RoutedExperts")
 _DSA = ("IndexedAttention",)
+_SSM = ("Mamba2Mixer", "GroupedAttention")
 
 
 def __getattr__(name):
@@ -40,4 +41,8 @@ def __getattr__(name):
         from .layers import dsa
 
         return getattr(dsa, name)
+    if name in _SSM:
+        from .layers import ssm
+
+        return getattr(ssm, name)
     raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

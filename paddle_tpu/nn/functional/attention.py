@@ -536,9 +536,12 @@ def cached_attention(query, key, value, pos, *, scale=None):
     the flash kernel's static q_offset seam), a quantized or paged cache,
     a head_dim of 128 or more, a sharded cache, the CPU.
     `observability.metrics.cached_attention_routes()` counts both ways.
-    Static end-aligned Sq != Sk shapes (prefill-with-history) route
-    through the flash kernel via `flash_plan` instead. Inference-only
-    (no VJP).
+    Grouped queries (query heads a multiple of the cache's K/V heads:
+    query head j reads K/V head j // (H / G)) always take XLA's form, a
+    tile of keys at a time up to the furthest query, with float32
+    scores. Static end-aligned Sq != Sk shapes (prefill-with-history)
+    route through the flash kernel via `flash_plan` instead.
+    Inference-only (no VJP).
 
     A PAGED cache (``PagedKV``, ISSUE 13) gathers the slot's view
     [B, H, nmax*bs, D] from the block pool through the table first (one
@@ -565,6 +568,8 @@ def cached_attention(query, key, value, pos, *, scale=None):
 
     def core(qr, kr, vr, pr):
         _count_route("dense")
+        if qr.shape[1] != kr.shape[1]:
+            return grouped(qr, kr, vr, pr)
         s = jnp.einsum("bhqd,bhkd->bhqk", qr, kr) * sc
         qpos = pr[:, None].astype(jnp.int32) + jnp.arange(Sq)[None, :]
         kpos = jnp.arange(Sk)
@@ -572,6 +577,49 @@ def cached_attention(query, key, value, pos, *, scale=None):
         s = jnp.where(masked, -1e9, s)
         w = jax.nn.softmax(s, axis=-1)
         return jnp.einsum("bhqk,bhkd->bhqd", w, vr)
+
+    def grouped(qr, kr, vr, pr):
+        # query head j reads K/V head j // (heads / kv heads); scores, the
+        # running max and sum, and the product in float32, a tile of
+        # KEY_BLOCK keys at a time (an online softmax), visiting only the
+        # tiles at or below the furthest query: a softmax over the whole
+        # capacity at once lowers to a reduce-window as wide as the
+        # capacity, quadratic in it on the chip
+        from .latent import _NEG, KEY_BLOCK
+
+        B, H = int(qr.shape[0]), int(qr.shape[1])
+        G, D = int(kr.shape[1]), int(qr.shape[-1])
+        bk = KEY_BLOCK if (Sk > KEY_BLOCK and Sk % KEY_BLOCK == 0) else Sk
+        qg = qr.reshape(B, G, H // G, Sq, D)
+        qpos = pr[:, None].astype(jnp.int32) + jnp.arange(Sq)[None, :]
+
+        def tile(j, carry):
+            m, l, acc = carry
+            kb = jax.lax.dynamic_slice_in_dim(kr, j * bk, bk, axis=2)
+            vb = jax.lax.dynamic_slice_in_dim(vr, j * bk, bk, axis=2)
+            s = jnp.einsum("bgrqd,bgkd->bgrqk", qg, kb,
+                           preferred_element_type=jnp.float32) * sc
+            kpos = j * bk + jnp.arange(bk)
+            s = jnp.where(kpos[None, None, None, None, :]
+                          > qpos[:, None, None, :, None], _NEG, s)
+            m_new = jnp.maximum(m, s.max(-1))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new[..., None])
+            pv = jnp.einsum("bgrqk,bgkd->bgrqd", p.astype(vb.dtype), vb,
+                            preferred_element_type=jnp.float32)
+            return m_new, l * alpha + p.sum(-1), acc * alpha[..., None] + pv
+
+        init = (jnp.full((B, G, H // G, Sq), _NEG, jnp.float32),
+                jnp.zeros((B, G, H // G, Sq), jnp.float32),
+                jnp.zeros((B, G, H // G, Sq, D), jnp.float32))
+        if Sk == bk:
+            _, l, acc = tile(0, init)
+        else:
+            n = jnp.minimum((jnp.max(pr).astype(jnp.int32) + Sq + bk - 1)
+                            // bk, Sk // bk)
+            _, l, acc = jax.lax.fori_loop(0, n, tile, init)
+        o = acc / l[..., None]
+        return o.reshape(B, H, Sq, D).astype(qr.dtype)
 
     from ... import profiler as _prof
 

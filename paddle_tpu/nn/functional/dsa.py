@@ -41,6 +41,7 @@ import jax.numpy as jnp
 
 from ...core import autograd as AG
 from .latent import KEY_BLOCK, _NEG, LatentCache
+from .ssm import SSMState
 
 __all__ = ["IndexedKVCache", "is_row_cache", "indexed_cache_update",
            "indexed_attend_plan", "index_scores", "kth_largest",
@@ -54,18 +55,21 @@ IndexedKVCache = collections.namedtuple("IndexedKVCache", ["k", "v", "idx"])
 #: keys a pass of the bisection counts at once
 COUNT_BLOCK = 4096
 
-#: the caches made of rows with no per-head `[.., H, rows, Dh]` layout:
-#: what a paged pool, the prefix cache and migration refuse by name
+#: the caches with no per-head `[.., H, rows, Dh]` layout: rows with no
+#: head axis, or a state with no rows at all; what a paged pool, the
+#: prefix cache and migration refuse by name
 ROW_CACHES = {LatentCache: "a latent cache (LatentCache: one [c | k_rope] "
                            "row a token, no head axis)",
               IndexedKVCache: "an indexed cache (IndexedKVCache: K rows, V "
-                              "rows and the indexer's key rows side by side)"}
+                              "rows and the indexer's key rows side by side)",
+              SSMState: "a state cache (SSMState: one [H, P, N] state a "
+                        "slot, no rows)"}
 
 
-def is_row_cache(cache_tree):
-    """The description of the first row cache (`ROW_CACHES`) a cache
-    pytree holds, or None."""
-    kinds = tuple(ROW_CACHES)
+def is_row_cache(cache_tree, kinds=None):
+    """The description of the first cache of `ROW_CACHES` (or of those of
+    its `kinds`) a cache pytree holds, or None."""
+    kinds = tuple(ROW_CACHES) if kinds is None else tuple(kinds)
     for leaf in jax.tree_util.tree_leaves(
             cache_tree, is_leaf=lambda v: isinstance(v, kinds)):
         if isinstance(leaf, kinds):

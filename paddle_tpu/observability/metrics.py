@@ -423,3 +423,26 @@ def cached_attention_routes() -> dict:
     `PrefillStep` as many to ``dense``.
     Process-wide, as :func:`kv_append_routes` is."""
     return dict(_cached_attention_routes)
+
+
+# ---------------------------------------------------------------------------
+# state-space layers: which shape of call each mixer was traced as
+# ---------------------------------------------------------------------------
+
+_ssm_routes = {"scan": 0, "step": 0}
+
+
+def record_ssm_route(route: str) -> None:
+    """Count one state-space mixer call (`nn.functional.ssm.mixer`):
+    ``scan`` for the chunked SSD form (a prompt or a chunk), ``step`` for
+    the one-token recurrence (a decode step). Called as the call is
+    traced, so a compiled step counts once however often it runs."""
+    _ssm_routes[route] += 1
+
+
+def ssm_routes() -> dict:
+    """{"scan": n, "step": n} — running totals of the state-space mixer
+    calls traced in this process: a `PrefillStep` of a hybrid model adds
+    its mixer layers to ``scan``, a `DecodeStep` as many to ``step``.
+    Process-wide, as :func:`kv_append_routes` is."""
+    return dict(_ssm_routes)

@@ -64,14 +64,17 @@ from .adapters import AdapterSet  # noqa: F401
 from .engine import (  # noqa: F401
     GeneratedResult, GenerationConfig, InferenceEngine, Request, generate,
 )
-from .model import LatentMoELM, SparseMoELM, TransformerLM  # noqa: F401
+from .model import (  # noqa: F401
+    HybridSSMLM, LatentMoELM, SparseMoELM, TransformerLM,
+)
 from .prefix_cache import PrefixCache  # noqa: F401
 from .router import (  # noqa: F401
     FileHost, FilePrefillHost, LocalHost, PrefillHost, Router,
 )
 
 __all__ = [
-    "sampling", "TransformerLM", "LatentMoELM", "SparseMoELM", "generate",
+    "sampling", "TransformerLM", "LatentMoELM", "SparseMoELM", "HybridSSMLM",
+    "generate",
     "GenerationConfig",
     "Request", "InferenceEngine", "GeneratedResult", "paged_kv",
     "Router", "LocalHost", "FileHost", "PrefillHost", "FilePrefillHost",

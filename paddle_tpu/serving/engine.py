@@ -1243,6 +1243,11 @@ class InferenceEngine:
             scratch = self._slot_cache(req, slot)
             with _prof.phase("engine.prefill", rid=req.rid, slot=slot):
                 bucket = bucket_for(L, self.max_length)
+                if self.prefill_chunk > 0:
+                    # a prompt of one chunk or less pads at most to the
+                    # chunk, the shape every chunk of a longer prompt runs,
+                    # and not past the largest bucket to the capacity
+                    bucket = min(bucket, self.prefill_chunk)
                 ids, lens = _pad_prompts([req.prefill_ids], bucket)
                 last, slot_raws, _ = self._prefill(
                     scratch, ids, lens,

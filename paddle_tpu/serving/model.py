@@ -8,7 +8,7 @@ consume any Layer with this surface::
     model(ids, cache=cs, pos=pos)    -> ([B, Sq, V] logits, new caches)
     model.gen_cache(B, cap[, dtype]) -> per-layer static-capacity caches
 
-Three decoders implement it:
+Four decoders implement it:
 
 * `TransformerLM` — full multi-head attention, learned positions,
   per-head K and V (below).
@@ -20,10 +20,14 @@ Three decoders implement it:
   attention that reads only the cached keys a learned indexer selects,
   whose `gen_cache` returns two kinds of state a layer side by side: K
   and V rows of the few K/V heads, and the indexer's key rows.
+* `HybridSSMLM` — Mamba-2 state-space mixers beside a few grouped-query
+  attention layers with no position encoding, whose `gen_cache` returns
+  a layer's state (`SSMState`: one `[H, P, N]` state a slot, no rows) or
+  its K/V cache, and whose forward takes each row's valid `length`.
 
-The last two share one skeleton, `_RoutedDecoderLM`: embedding, pre-norm
-blocks of an attention and an MLP, final RMSNorm, a float32 head, the
-routed layers' counters.
+The last three share one skeleton, `_RoutedDecoderLM`: embedding, pre-norm
+blocks of a mixer (attention or state space) and an MLP, final RMSNorm,
+a float32 head, the routed layers' counters.
 
 `TransformerLM` is the first in-repo implementation: token + learned position
 embeddings, a `ParallelGPTBlock` stack (tensor-parallel attention/MLP —
@@ -38,7 +42,7 @@ from ..distributed import comm
 from ..distributed.meta_parallel import ParallelGPTBlock
 from ..ops.creation import arange
 
-__all__ = ["TransformerLM", "LatentMoELM", "SparseMoELM"]
+__all__ = ["TransformerLM", "LatentMoELM", "SparseMoELM", "HybridSSMLM"]
 
 
 class TransformerLM(nn.Layer):
@@ -110,9 +114,11 @@ class TransformerLM(nn.Layer):
 class _Block(nn.Layer):
     """Pre-norm residual block: an attention layer of the cache contract
     (`attn(x)`, `attn(x, cache=, pos=)`, `attn.gen_cache`), then a dense
-    gated MLP or routed experts."""
+    gated MLP or routed experts. `residual` scales both branches (None:
+    full weight); a state-space layer in `attn` also takes `length=`."""
 
-    def __init__(self, attn, mlp, d_model, eps, weight_attr, dtype):
+    def __init__(self, attn, mlp, d_model, eps, weight_attr, dtype,
+                 residual=None):
         super().__init__()
         from ..nn.layers.latent import RMSNorm
 
@@ -123,25 +129,32 @@ class _Block(nn.Layer):
                            dtype=dtype)
         self.mlp = mlp
         self.routed = hasattr(mlp, "held")
+        self.residual = residual
 
-    def forward(self, h, cache=None, pos=None):
+    def _branch(self, x):
+        return x if self.residual is None else x * self.residual
+
+    def forward(self, h, cache=None, pos=None, **length):
         if cache is None:
-            h = h + self.attn(self.norm1(h))
-            return h + self.mlp(self.norm2(h))
-        a, new_cache = self.attn(self.norm1(h), cache=cache, pos=pos)
-        h = h + a
+            h = h + self._branch(self.attn(self.norm1(h)))
+            return h + self._branch(self.mlp(self.norm2(h)))
+        a, new_cache = self.attn(self.norm1(h), cache=cache, pos=pos,
+                                 **length)
+        h = h + self._branch(a)
         x = self.norm2(h)
-        return h + (self.mlp(x, count=True) if self.routed
-                    else self.mlp(x)), new_cache
+        return h + self._branch(self.mlp(x, count=True) if self.routed
+                                else self.mlp(x)), new_cache
 
 
 class _RoutedDecoderLM(nn.Layer):
-    """The skeleton `LatentMoELM` and `SparseMoELM` share: an embedding,
-    `blocks` (each subclass builds its own pairs of attention and MLP
-    through `_build`), a final RMSNorm, an untied head whose logits are
-    float32, no biases, rotary positions inside the attention (no
-    position table). Parameters and cache are `dtype`; norm and softmax
-    statistics, the router and the logits are float32.
+    """The skeleton `LatentMoELM`, `SparseMoELM` and `HybridSSMLM` share:
+    an embedding, `blocks` (each subclass builds its own pairs of mixer
+    and MLP through `_build`), a final RMSNorm, a head whose logits are
+    float32, no biases, no position table. Parameters and cache are
+    `dtype`; norm and softmax statistics, the router and the logits are
+    float32. `_build`'s `embedding_multiplier` and `residual` (None: no
+    scaling) and `head=False` (a subclass's own `_logits`) are the hooks
+    a hybrid uses; `length` reaches the blocks only where it is given.
 
     The serving contract of this module: `model(ids)`, `model(ids,
     cache=, pos=)`, `gen_cache(B, cap[, dtype], block_size=,
@@ -149,7 +162,8 @@ class _RoutedDecoderLM(nn.Layer):
     `expert_load()` reads the routed layers' device counters."""
 
     def _build(self, vocab_size, d_model, pairs, *, max_position, epsilon,
-               weight_attr, dtype):
+               weight_attr, dtype, embedding_multiplier=None, residual=None,
+               head=True):
         """`pairs`: one (attention, mlp) a block."""
         from ..nn.initializer import Normal
         from ..nn.layers.latent import RMSNorm
@@ -158,19 +172,21 @@ class _RoutedDecoderLM(nn.Layer):
             comm.init_hybrid_mesh(dp=1, mp=1, pp=1, sp=1)
         self.vocab_size, self.d_model = int(vocab_size), int(d_model)
         self.max_position = int(max_position)
+        self.embedding_multiplier = embedding_multiplier
         # nn.Embedding makes its table in the default dtype
         self.embedding = nn.Embedding(vocab_size, d_model,
                                       weight_attr=weight_attr)
         self.embedding.weight._data = \
             self.embedding.weight._data.astype(dtype)
         self.blocks = nn.LayerList([
-            _Block(attn, mlp, d_model, epsilon, weight_attr, dtype)
+            _Block(attn, mlp, d_model, epsilon, weight_attr, dtype, residual)
             for attn, mlp in pairs])
         self.norm_f = RMSNorm(d_model, epsilon, weight_attr=weight_attr,
                             dtype=dtype)
-        self.head = self.create_parameter(
-            shape=[d_model, vocab_size], attr=weight_attr, dtype=dtype,
-            default_initializer=Normal(0.0, 0.02))
+        if head:
+            self.head = self.create_parameter(
+                shape=[d_model, vocab_size], attr=weight_attr, dtype=dtype,
+                default_initializer=Normal(0.0, 0.02))
 
     def _logits(self, h):
         """The head's product leaves the accumulator as float32: logits
@@ -185,17 +201,20 @@ class _RoutedDecoderLM(nn.Layer):
                                     preferred_element_type=jnp.float32),
             (self.norm_f(h), self.head), name="lm_head")
 
-    def forward(self, ids, cache=None, pos=None, adapter=None):
+    def forward(self, ids, cache=None, pos=None, adapter=None, length=None):
         h = self.embedding(ids)
+        if self.embedding_multiplier is not None:
+            h = h * self.embedding_multiplier
         if cache is None:
             for blk in self.blocks:
                 h = blk(h)
             return self._logits(h)
         if pos is None:
             raise ValueError("cache decoding needs `pos` ([B] int32)")
+        kw = {} if length is None else {"length": length}
         new_caches = []
         for blk, c in zip(self.blocks, cache):
-            h, nc = blk(h, cache=c, pos=pos)
+            h, nc = blk(h, cache=c, pos=pos, **kw)
             new_caches.append(nc)
         return self._logits(h), new_caches
 
@@ -306,3 +325,72 @@ class SparseMoELM(_RoutedDecoderLM):
         wide = read_wide(jnp.stack([b.attn.keys._data
                                     for b in self.blocks]))
         return dict(enumerate(wide))
+
+
+class HybridSSMLM(_RoutedDecoderLM):
+    """A causal LM whose blocks are Mamba-2 mixers (`nn.Mamba2Mixer`) or
+    grouped-query attention with no position encoding
+    (`nn.GroupedAttention`), as `layer_types` ("mamba" / "attention")
+    says, each beside a dense gated-SiLU MLP (granite-4.0-h's
+    `granitemoehybrid` with no experts): the embedding times
+    `embedding_multiplier`, each residual branch times
+    `residual_multiplier`, attention scores times `attention_multiplier`,
+    a final RMSNorm and a head tied to the embedding whose float32 logits
+    are divided by `logits_scaling`. The rest is `_RoutedDecoderLM`'s.
+
+    The serving contract, with one addition: `model(ids, cache=, pos=,
+    length=)` takes each row's valid length (`takes_length`), which
+    `jit.PrefillStep` hands it, because a padded position would advance a
+    state. `gen_cache` returns a layer's `SSMState` or K/V cache in order;
+    a paged pool, the prefix cache, migration and speculative decoding
+    refuse the state by name."""
+
+    #: `jit.PrefillStep` passes `length=` to a model that declares it
+    takes_length = True
+
+    def __init__(self, vocab_size, d_model, layer_types, *, num_heads,
+                 kv_heads, head_dim, ssm_heads, ssm_head_dim, state_dim,
+                 conv_kernel, ffn, embedding_multiplier=1.0,
+                 residual_multiplier=1.0, attention_multiplier=None,
+                 logits_scaling=1.0, chunk=256, max_position=131072,
+                 epsilon=1e-5, weight_attr=None, dtype="bfloat16"):
+        super().__init__()
+        from ..nn.layers.latent import GatedMLP
+        from ..nn.layers.ssm import GroupedAttention, Mamba2Mixer
+
+        self.layer_types = tuple(layer_types)
+        self.logits_scaling = float(logits_scaling)
+        scale = (attention_multiplier if attention_multiplier is not None
+                 else int(head_dim) ** -0.5)
+
+        def mixer(kind):
+            if kind == "mamba":
+                return Mamba2Mixer(d_model, ssm_heads, ssm_head_dim,
+                                   state_dim, conv_kernel, epsilon=epsilon,
+                                   chunk=chunk, weight_attr=weight_attr,
+                                   dtype=dtype)
+            if kind == "attention":
+                return GroupedAttention(d_model, num_heads, kv_heads,
+                                        head_dim, scale=scale,
+                                        weight_attr=weight_attr, dtype=dtype)
+            raise ValueError(f"unknown layer type {kind!r}")
+
+        pairs = [(mixer(kind), GatedMLP(d_model, ffn, weight_attr, dtype))
+                 for kind in self.layer_types]
+        self._build(vocab_size, d_model, pairs, max_position=max_position,
+                    epsilon=epsilon, weight_attr=weight_attr, dtype=dtype,
+                    embedding_multiplier=float(embedding_multiplier),
+                    residual=float(residual_multiplier), head=False)
+
+    def _logits(self, h):
+        """RMSNorm(h) E^T / logits_scaling, leaving the accumulator as
+        float32."""
+        import jax.numpy as jnp
+
+        from ..core import autograd as AG
+
+        return AG.apply(
+            lambda a, w: jnp.matmul(a, w.T,
+                                    preferred_element_type=jnp.float32)
+            / self.logits_scaling,
+            (self.norm_f(h), self.embedding.weight), name="lm_head")

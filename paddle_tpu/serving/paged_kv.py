@@ -74,10 +74,11 @@ def block_size_default() -> int:
 def refuse_latent(cache_tree, what: str) -> None:
     """Blocks, block tables, shared prefixes and migration bundles are
     all made of per-head K and V `[.., H, rows, Dh]`; a cache made of
-    rows with no head axis (`nn.functional.dsa.ROW_CACHES`: a latent
-    cache's one `[c | k_rope]` row a token, an indexed cache's K rows, V
-    rows and indexer-key rows side by side) would be mis-spliced by them,
-    so `what` refuses it by name."""
+    rows with no head axis, or of a state with no rows
+    (`nn.functional.dsa.ROW_CACHES`: a latent cache's one `[c | k_rope]`
+    row a token, an indexed cache's K rows, V rows and indexer-key rows
+    side by side, a state-space layer's `[H, P, N]` state a slot) would be
+    mis-spliced by them, so `what` refuses it by name."""
     from ..nn.functional.dsa import is_row_cache
 
     kind = is_row_cache(cache_tree)
