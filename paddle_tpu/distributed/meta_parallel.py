@@ -363,7 +363,7 @@ class ParallelMultiHeadAttention(Layer):
         out = [place(jnp.zeros(shape, dt)) for _ in range(2)]
         return MultiHeadAttention.Cache(out[0], out[1])
 
-    def forward(self, x, cache=None, pos=None):
+    def forward(self, x, cache=None, pos=None, live=None):
         from .. import ops
 
         B, T = x.shape[0], x.shape[1]
@@ -379,10 +379,11 @@ class ParallelMultiHeadAttention(Layer):
             # static-capacity decode-append: write this step's K/V rows
             # at per-slot `pos`, attend position-masked over what the
             # slot holds. On one chip one Pallas kernel a layer does
-            # both; on a real mesh plain XLA ops, which GSPMD partitions
-            # over (dp -> batch, mp -> heads) exactly like the training
-            # path (a traced pos cannot feed the flash kernel's static
-            # q_offset seam).
+            # both, and skips the slots `live` says hold no request; on a
+            # real mesh plain XLA ops, which GSPMD partitions over (dp ->
+            # batch, mp -> heads) exactly like the training path (a
+            # traced pos cannot feed the flash kernel's static q_offset
+            # seam).
             if pos is None:
                 raise ValueError(
                     "cache decoding needs `pos` (per-slot write "
@@ -391,7 +392,7 @@ class ParallelMultiHeadAttention(Layer):
             from ..nn.layers.transformer import MultiHeadAttention
 
             ctx, k, v = attn_route.cached_append_attention(
-                q, cache.k, cache.v, k, v, pos, scale=dh ** -0.5
+                q, cache.k, cache.v, k, v, pos, scale=dh ** -0.5, live=live
             )
             new_cache = MultiHeadAttention.Cache(k, v)
             ctx = ctx.transpose([0, 2, 1, 3]).reshape([B, T, H * dh])
@@ -475,9 +476,10 @@ class ParallelGPTBlock(Layer):
         self.fc2 = RowParallelLinear(ffn, d_model, input_is_parallel=True)
         self.dropout = dropout
 
-    def forward(self, x, cache=None, pos=None, adapter=None):
+    def forward(self, x, cache=None, pos=None, adapter=None, live=None):
         if cache is not None:
-            a, new_cache = self.attn(self.ln1(x), cache=cache, pos=pos)
+            a, new_cache = self.attn(self.ln1(x), cache=cache, pos=pos,
+                                     live=live)
         else:
             a, new_cache = self.attn(self.ln1(x)), None
         # residual-add + LN fused in one Pallas pass on TPU (the sum is

@@ -225,7 +225,7 @@ class _CompiledDecodeBase:
     # -- the pure forward segment -----------------------------------------
     def _fwd_objs(self, model, p_objs, b_objs, p_raws, b_raws, ids,
                   cache_raws, pos, label=None, adapter=None, carried=None,
-                  length=None):
+                  length=None, live=None):
         """A model forward with the KV-cache seam as a pure function of
         (params, buffers, ids, caches, pos) -> (logits, new caches).
         Parameterized over the model so SpeculativeDecodeStep can run
@@ -236,8 +236,10 @@ class _CompiledDecodeBase:
         buffer the forward replaced — a device counter such as
         `nn.RoutedExperts.load`; a model that replaces none leaves it
         empty and its program as it was. ``length`` ([B] int32, each
-        row's valid tokens) is forwarded only to a model that declares
-        ``takes_length`` (a state would advance over padding); every other
+        row's valid tokens) and ``live`` ([B] bool, the slots that hold a
+        request) are each forwarded only to a model that declares
+        ``takes_length`` (a state would advance over padding) or
+        ``takes_live`` (its attention skips dead slots); every other
         model's call, and program, stays as it was."""
         from .. import profiler as _prof
 
@@ -246,8 +248,9 @@ class _CompiledDecodeBase:
         kw = {}
         if adapter is not None:
             kw["adapter"] = Tensor._wrap(adapter)
-        if length is not None and getattr(model, "takes_length", False):
-            kw["length"] = Tensor._wrap(length)
+        for name, value in (("length", length), ("live", live)):
+            if value is not None and getattr(model, f"takes_{name}", False):
+                kw[name] = Tensor._wrap(value)
         with AG.trace_mode(), \
                 _prof.device_annotation(
                     label or f"{self._label}::forward"), \
@@ -265,13 +268,14 @@ class _CompiledDecodeBase:
         return logits, new_raws
 
     def _fwd(self, p_raws, b_raws, ids, cache_raws, pos, adapter=None,
-             length=None):
+             length=None, live=None):
         """-> (logits, new caches, the replaced buffers' new values);
         which buffers those are is noted at trace time for `_rebind`."""
         carried = []
         logits, new_raws = self._fwd_objs(
             self.model, self._p_objs, self._b_objs, p_raws, b_raws, ids,
-            cache_raws, pos, adapter=adapter, carried=carried, length=length)
+            cache_raws, pos, adapter=adapter, carried=carried, length=length,
+            live=live)
         self._carried_idx = tuple(i for i, _ in carried)
         return logits, new_raws, tuple(r for _, r in carried)
 
@@ -306,18 +310,28 @@ class DecodeStep(_CompiledDecodeBase):
     ``emitted`` is [B] int32 with ``-1`` for slots that were already
     done; ``logits`` is the [B, V] f32 pre-sampling distribution of this
     step (device array — read it only where a sync is acceptable).
+
+    A model that declares ``takes_live`` is handed ``live = ~done``: a
+    slot done when the step begins holds no request, and its attention
+    may skip it. ``masks_dead_slots`` says, once the step is traced,
+    whether its program does (`observability.metrics.masked_kernels()`).
     """
 
     _label = "DecodeStep"
+    masks_dead_slots = False
 
     def _step_fn(self, p_raws, b_raws, cache_raws, pos, tok, done, key,
                  temp, top_k, top_p, eos, budget, adapter):
+        from ..observability.metrics import masked_kernels
         from ..serving import sampling as _sampling
 
+        masked = masked_kernels()
         logits, new_caches, carried = self._fwd(
             p_raws, b_raws, tok[:, None], cache_raws, pos,
             adapter=adapter if self._use_adapters else None,
+            live=jnp.logical_not(done),
         )
+        self.masks_dead_slots = masked_kernels() > masked
         last = logits[:, -1, :].astype(jnp.float32)
         key, sub = jax.random.split(key)
         from .. import profiler as _prof

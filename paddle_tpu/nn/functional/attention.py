@@ -678,7 +678,7 @@ def cached_attention(query, key, value, pos, *, scale=None):
 
 
 def cached_append_attention(query, k_cache, v_cache, k_new, v_new, pos, *,
-                            scale=None):
+                            scale=None, live=None):
     """A decode layer's cache work in one call: the [B, H, Sq, D] new K
     and V rows written into the caches at per-slot ``pos`` (as
     `cache_update` writes them), then ``query`` attended over the
@@ -693,17 +693,23 @@ def cached_append_attention(query, k_cache, v_cache, k_new, v_new, pos, *,
     (ops/pallas/decode_attention.py): the tile that holds ``pos[b]`` is the last one the read fetches, so
     the rows go into it there and it is written back once, where
     `kv_append` would read and write it again in a launch of its own.
-    The result is the two kernels' bit for bit. Every other call is
-    exactly `cache_update` for K, for V, then `cached_attention`:
-    prefill and speculative steps (Sq > 1), a quantized or paged cache,
-    a head_dim of 128 or more, more heads than the turn holds, a sharded
-    cache, the CPU. `observability.metrics.kv_append_routes()["fused"]`
-    counts the rows written inside the kernel (two a call), and
-    `cached_attention_routes()["kernel"]` its read. Inference-only (no
-    VJP)."""
+    The result is the two kernels' bit for bit. ``live`` ([B] bool
+    Tensor, the decode step's slots that hold a request) goes to the
+    kernel, which then moves no tile for a dead slot: its rows are not
+    written and its row of the result is zero, where nothing reads
+    either. Every other call is exactly `cache_update` for K, for V, then
+    `cached_attention`, and takes no notice of ``live``: prefill and
+    speculative steps (Sq > 1), a quantized or paged cache, a head_dim
+    of 128 or more, more heads than the turn holds, a sharded cache, the
+    CPU. `observability.metrics.kv_append_routes()["fused"]` counts the
+    rows written inside the kernel (two a call),
+    `cached_attention_routes()["kernel"]` its read, and
+    `masked_kernels()` the calls that handed it ``live``.
+    Inference-only (no VJP)."""
     from ...core.tensor import Tensor
     from ...observability.metrics import (record_cached_attention_route,
-                                          record_kv_append_route)
+                                          record_kv_append_route,
+                                          record_masked_kernel)
     from ...ops.pallas.decode_attention import (APPEND_HEADS,
                                                 decode_append_attention)
 
@@ -723,16 +729,19 @@ def cached_append_attention(query, k_cache, v_cache, k_new, v_new, pos, *,
         v = cache_update(v_cache, v_new, pos)
         return cached_attention(query, k, v, pos, scale=scale), k, v
 
-    def fused(qr, kr, vr, kn, vn, pr):
+    def fused(qr, kr, vr, kn, vn, pr, *lr):
         record_kv_append_route("fused")
         record_kv_append_route("fused")
         record_cached_attention_route("kernel")
-        return decode_append_attention(qr, kr, vr, kn, vn, pr, scale=sc,
-                                       interpret=interpret)
+        if lr:
+            record_masked_kernel()
+        return decode_append_attention(qr, kr, vr, kn, vn, pr, *lr,
+                                       scale=sc, interpret=interpret)
 
     from ... import profiler as _prof
 
+    operands = (query, k_cache, v_cache, k_new, v_new, pos)
     with _prof.device_annotation("attention::cached"):
         out, k, v = AG.apply_nondiff(
-            fused, (query, k_cache, v_cache, k_new, v_new, pos))
+            fused, operands if live is None else operands + (live,))
     return out, k, v

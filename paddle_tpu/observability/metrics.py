@@ -426,6 +426,50 @@ def cached_attention_routes() -> dict:
 
 
 # ---------------------------------------------------------------------------
+# dead slots: the decode kernel that skips them, counted where it is traced,
+# and the slot-steps it saw live and dead, counted at the engine's collect
+# ---------------------------------------------------------------------------
+
+_masked_kernels = [0]
+_decode_slot_steps = {"live": 0, "dead": 0}
+
+
+def record_masked_kernel() -> None:
+    """Count one `cached_append_attention` call that became the
+    `decode_append_attention` kernel with the step's ``live`` mask, which
+    moves no tile for a dead slot. Called as the call is traced."""
+    _masked_kernels[0] += 1
+
+
+def masked_kernels() -> int:
+    """Running total of the masked decode kernels traced in this process:
+    a `DecodeStep` of a model that takes ``live`` over a plain float
+    cache on the chip adds ``layers``; every other route adds nothing.
+    `jit.DecodeStep` reads it around its trace to learn whether its
+    program skips dead slots."""
+    return _masked_kernels[0]
+
+
+def record_decode_slot_steps(live: int, dead: int) -> None:
+    """Add one readback window's slot-steps of a decode program whose
+    attention skips dead slots: ``live`` emitted a token, ``dead`` the
+    -1 of a slot that held no request when the step began. Counted by
+    `InferenceEngine.turn` from the token block it has read — no device
+    read."""
+    _decode_slot_steps["live"] += int(live)
+    _decode_slot_steps["dead"] += int(dead)
+
+
+def decode_slot_steps() -> dict:
+    """{"live": n, "dead": n} — running totals of the slot-steps of every
+    engine's decode steps in this process whose attention took the masked
+    kernel: how often a step skipped a slot, and how often it attended
+    one. Zero where no step took it (another route, the CPU). Readable
+    after the engine is gone, as :func:`expert_load` is."""
+    return dict(_decode_slot_steps)
+
+
+# ---------------------------------------------------------------------------
 # state-space layers: which shape of call each mixer was traced as
 # ---------------------------------------------------------------------------
 

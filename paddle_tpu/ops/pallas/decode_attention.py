@@ -33,6 +33,12 @@ reads the same tile again in a launch of its own. There each cache tensor
 enters once, in HBM: a slot's first tiles are the kernel's own copies,
 started while the slot before it computes, and a slot's write is waited
 for two slots later, when its buffer is next filled.
+
+A decode step's slots are not all serving: a finished request's slot
+keeps a frozen `pos` and an empty one sits at 0 until the engine fills
+it. Handed the step's live mask, `decode_append_attention` skips them:
+a dead slot's grid step moves no tile and writes a zero row, and the
+pipeline runs over the live slots alone, ranked as the grid passes.
 """
 from __future__ import annotations
 
@@ -272,24 +278,33 @@ def _insert(k_tile, v_tile, k_dst, v_dst, turn_scr, lane):
 _FIRST, _LATER, _WRITTEN = 0, 2, 4
 
 
-def _append_attention_kernel(pos_ref, q_ref, kn_ref, vn_ref, k_hbm, v_hbm,
-                             o_ref, k_out, v_out, turn_scr, q_scr, m_scr,
-                             l_scr, acc_scr, k_buf, v_buf, sem, *, scale):
+def _append_slot(pos_ref, q_ref, kn_ref, vn_ref, k_hbm, v_hbm, o_ref,
+                 k_out, v_out, turn_scr, q_scr, m_scr, l_scr, acc_scr,
+                 k_buf, v_buf, sem, *, scale, b, rank, nxt=None,
+                 order=None):
+    """Slot `b`'s grid step, the `rank`-th slot the grid attends. Every
+    slot is attended when `order` is None, so `rank` is `b` and the next
+    slot `b + 1`; else `nxt` is the next slot attended (the number of
+    slots where none is) and `order[r]` the slot attended `r`-th, for
+    every `r` up to `rank`. The buffers and semaphores go by the rank,
+    the cache's tiles by the slot."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    b = pl.program_id(0)
     slots = pl.num_programs(0)
     last = pos_ref[b]
     tiles = last // TILE + 1
     bufs = (k_buf, v_buf)
+    this = None if order is None else b
 
-    def copies(kind, s, t):
-        """K's and V's copy of slot `s`'s tile `t`: fetched into the
-        buffer of `kind` (`_FIRST`, `_LATER`), or written back from
-        `_WRITTEN`. The semaphores are laid out as the buffers are."""
+    def copies(kind, r, t, s=None):
+        """K's and V's copy of tile `t` of the slot attended `r`-th
+        (`s`, where it is not `r` itself): fetched into the buffer of
+        `kind` (`_FIRST`, `_LATER`), or written back from `_WRITTEN`.
+        The semaphores are laid out as the buffers are."""
+        s = r if s is None else s
         at = pl.ds(pl.multiple_of(t * TILE, TILE), TILE)
-        i = kind + (t % 2 if kind == _LATER else s % 2)
+        i = kind + (t % 2 if kind == _LATER else r % 2)
         out = []
         for j, (src, dst) in enumerate(((k_hbm, k_out), (v_hbm, v_out))):
             hbm, vmem = src.at[s, :, :, at], bufs[j].at[i]
@@ -298,24 +313,26 @@ def _append_attention_kernel(pos_ref, q_ref, kn_ref, vn_ref, k_hbm, v_hbm,
             out.append(pltpu.make_async_copy(hbm, vmem, sem.at[j, i]))
         return out
 
-    def written(s):
-        """Slot `s`'s write, to be waited for (the tile is the last
-        live one of that slot, whose `pos` is at hand)."""
-        return copies(_WRITTEN, s, pos_ref[s] // TILE)
+    def written(r):
+        """The write of the slot attended `r`-th, to be waited for (the
+        tile is the last live one of that slot, whose `pos` is at
+        hand)."""
+        s = r if order is None else order[r]
+        return copies(_WRITTEN, r, pos_ref[s] // TILE, s)
 
-    @pl.when(b == 0)
+    @pl.when(rank == 0)
     def _():
-        for copy in copies(_FIRST, b, 0):
+        for copy in copies(_FIRST, rank, 0, this):
             copy.start()
 
-    @pl.when(b + 1 < slots)
+    @pl.when(rank + 1 < slots if nxt is None else nxt < slots)
     def _():
-        for copy in copies(_FIRST, b + 1, 0):
+        for copy in copies(_FIRST, rank + 1, 0, nxt):
             copy.start()
 
     @pl.when(tiles > 1)
     def _():
-        for copy in copies(_LATER, b, 1):
+        for copy in copies(_LATER, rank, 1, this):
             copy.start()
 
     state = (q_scr, m_scr, l_scr, acc_scr)
@@ -325,34 +342,34 @@ def _append_attention_kernel(pos_ref, q_ref, kn_ref, vn_ref, k_hbm, v_hbm,
         """Fold tile `t`, fetched into buffer `i`; the last live one
         takes the new rows first, into the buffer that goes back."""
         is_last = t == tiles - 1
-        out = _WRITTEN + b % 2
+        out = _WRITTEN + rank % 2
 
         @pl.when(is_last)
         def _():
-            @pl.when(b >= 2)
+            @pl.when(rank >= 2)
             def _():
-                for copy in written(b - 2):
+                for copy in written(rank - 2):
                     copy.wait()
 
             _insert(k_buf.at[i], v_buf.at[i], k_buf.at[out], v_buf.at[out],
                     turn_scr, last % TILE)
-            for copy in copies(_WRITTEN, b, t):
+            for copy in copies(_WRITTEN, rank, t, this):
                 copy.start()
 
         at = jnp.where(is_last, out, i)
         _attend_tile(k_buf.at[at], v_buf.at[at], t * TILE, last, *state)
 
-    for copy in copies(_FIRST, b, 0):
+    for copy in copies(_FIRST, rank, 0, this):
         copy.wait()
-    attend(_FIRST + b % 2, 0)
+    attend(_FIRST + rank % 2, 0)
 
     def later(t, carry):
         @pl.when(t + 1 < tiles)
         def _():
-            for copy in copies(_LATER, b, t + 1):
+            for copy in copies(_LATER, rank, t + 1, this):
                 copy.start()
 
-        for copy in copies(_LATER, b, t):
+        for copy in copies(_LATER, rank, t, this):
             copy.wait()
         attend(_LATER + t % 2, t)
         return carry
@@ -360,21 +377,60 @@ def _append_attention_kernel(pos_ref, q_ref, kn_ref, vn_ref, k_hbm, v_hbm,
     jax.lax.fori_loop(1, tiles, later, None)
     _finish(o_ref, turn_scr, m_scr, l_scr, acc_scr)
 
-    # the writes still in flight when the grid ends
-    @pl.when(b == slots - 1)
+    # the writes still in flight when the last slot attended is done
+    @pl.when(rank == slots - 1 if nxt is None else nxt == slots)
     def _():
-        @pl.when(b >= 1)
+        @pl.when(rank >= 1)
         def _():
-            for copy in written(b - 1):
+            for copy in written(rank - 1):
                 copy.wait()
 
-        for copy in written(b):
+        for copy in written(rank):
             copy.wait()
 
 
+def _append_attention_kernel(pos_ref, *refs, scale, masked):
+    """The grid's step `b`: slot `b`. `masked`: a second scalar operand,
+    `live`, says which slots hold a request. A dead slot's step moves no
+    tile and writes a zero row; the live ones are attended as if they
+    were the grid's only slots, in order (`_append_slot`), the ranks
+    counted as the grid passes in two words of scalar memory: `order`,
+    the slot attended at each rank so far, and `seen`, how many."""
+    from jax.experimental import pallas as pl
+
+    b = pl.program_id(0)
+    if not masked:
+        return _append_slot(pos_ref, *refs, scale=scale, b=b, rank=b)
+    live_ref, *refs, order, seen = refs
+    slots = pl.num_programs(0)
+    o_ref = refs[5]  # after the query, the new rows, K and V
+
+    @pl.when(b == 0)
+    def _():
+        seen[0] = 0
+
+    rank = seen[0]
+    live = live_ref[b] != 0
+
+    @pl.when(live)
+    def _():
+        def dead(s):
+            return (s < slots) & (live_ref[jnp.minimum(s, slots - 1)] == 0)
+
+        order[rank] = b
+        seen[0] = rank + 1
+        nxt = jax.lax.while_loop(dead, lambda s: s + 1, b + 1)
+        _append_slot(pos_ref, *refs, scale=scale, b=b, rank=rank, nxt=nxt,
+                     order=order)
+
+    @pl.when(jnp.logical_not(live))
+    def _():
+        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
-def decode_append_attention(query, key, value, k_new, v_new, pos, *,
-                            scale=None, interpret=False):
+def decode_append_attention(query, key, value, k_new, v_new, pos,
+                            live=None, *, scale=None, interpret=False):
     """`kv_append(key, k_new, pos)`, `kv_append(value, v_new, pos)` and
     `decode_attention` over the two in one kernel: returns the attention
     [B, H, 1, D] and the caches with `k_new` / `v_new` ([B, H, 1, D])
@@ -388,8 +444,12 @@ def decode_append_attention(query, key, value, k_new, v_new, pos, *,
     before it computes, so the grid runs in order. `pos` as `kv_append`
     takes it: a negative one counts from the end, then it is clamped into
     `[0, cap)`. At most `APPEND_HEADS` (40) heads: a slot's query and
-    two new rows are turned in one 128 x 128 tile. `interpret=True` runs
-    the Pallas interpreter (CPU tests)."""
+    two new rows are turned in one 128 x 128 tile. `live` ([B] bool, or
+    None for every slot) says which slots hold a request: a dead slot's
+    grid step fetches, writes and waits for nothing, its caches are left
+    as they were and its row of the attention is zero, and the live
+    slots' results are bit for bit what they are without it.
+    `interpret=True` runs the Pallas interpreter (CPU tests)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -411,16 +471,22 @@ def decode_append_attention(query, key, value, k_new, v_new, pos, *,
     out_dtype = jnp.result_type(query.dtype, key.dtype, value.dtype)
     pos = jnp.asarray(pos, jnp.int32)
     pos = jnp.clip(jnp.where(pos < 0, pos + cap, pos), 0, cap - 1)
-    row = pl.BlockSpec((1, H, D), lambda b, pos: (b, 0, 0))
+    scalars = (pos,) if live is None else (pos, jnp.asarray(live, jnp.int32))
+    # the live slots' ranks as the grid passes: `_append_attention_kernel`
+    ranks = [] if live is None else [pltpu.SMEM((B,), jnp.int32),
+                                     pltpu.SMEM((1,), jnp.int32)]
+    row = pl.BlockSpec((1, H, D), lambda b, *_: (b, 0, 0))
     whole = pl.BlockSpec(memory_space=pl.ANY)
     kt, vt = jnp.swapaxes(key, 2, 3), jnp.swapaxes(value, 2, 3)
+    n = len(scalars)
     out, kt, vt = pl.pallas_call(
-        functools.partial(_append_attention_kernel, scale=sc),
+        functools.partial(_append_attention_kernel, scale=sc,
+                          masked=live is not None),
         out_shape=(jax.ShapeDtypeStruct((B, H, D), out_dtype),
                    jax.ShapeDtypeStruct(kt.shape, kt.dtype),
                    jax.ShapeDtypeStruct(vt.shape, vt.dtype)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=n,
             grid=(B,),
             in_specs=[row, row, row, whole, whole],
             out_specs=[row, whole, whole],
@@ -433,14 +499,17 @@ def decode_append_attention(query, key, value, k_new, v_new, pos, *,
                 pltpu.VMEM((6, H, D, TILE), key.dtype),      # the tiles
                 pltpu.VMEM((6, H, D, TILE), value.dtype),
                 pltpu.SemaphoreType.DMA((2, 6)),
+                *ranks,
             ],
         ),
-        # operand 0 is the prefetched `pos`, 1-3 the query and new rows
-        input_output_aliases={4: 1, 5: 2},
+        # operands 0 to n - 1 are the prefetched scalars, then the query
+        # and the new rows
+        input_output_aliases={n + 3: 1, n + 4: 2},
         # slot b + 1's first tiles and slot b's write are carried from
         # slot b's step into later ones: the grid runs in order
         compiler_params=_tpu_params("arbitrary"),
         interpret=interpret,
         name="decode_append_attention",
-    )(pos, query[:, :, 0, :], k_new[:, :, 0, :], v_new[:, :, 0, :], kt, vt)
+    )(*scalars, query[:, :, 0, :], k_new[:, :, 0, :], v_new[:, :, 0, :], kt,
+      vt)
     return out[:, :, None, :], jnp.swapaxes(kt, 2, 3), jnp.swapaxes(vt, 2, 3)

@@ -548,6 +548,7 @@ class InferenceEngine:
 
         self._state = DecodeState(*_commit_tree(self._state.astuple()))
         from ..observability.metrics import (DecodeMetricsSampler,
+                                             record_decode_slot_steps,
                                              record_expert_load,
                                              record_sampler_steps,
                                              record_selected_keys)
@@ -556,6 +557,7 @@ class InferenceEngine:
         self._record_expert_load = record_expert_load
         self._record_selected_keys = record_selected_keys
         self._record_sampler_steps = record_sampler_steps
+        self._record_decode_slot_steps = record_decode_slot_steps
 
     # -- public API --------------------------------------------------------
     def needed_blocks(self, req: Request) -> int:
@@ -1077,6 +1079,11 @@ class InferenceEngine:
                         for s in self._active.values())
             self._record_sampler_steps(0 if draws else window,
                                        window if draws else 0)
+            if self._decode.masks_dead_slots:
+                # a -1 is a slot that held no request when its step
+                # began: one whose tiles the step's attention skipped
+                dead = int((tok_block < 0).sum())
+                self._record_decode_slot_steps(tok_block.size - dead, dead)
             self._collect(tok_block, done, results)
         with _prof.phase("engine.turn_tail"):
             if self._retiring:

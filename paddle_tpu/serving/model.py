@@ -46,6 +46,10 @@ __all__ = ["TransformerLM", "LatentMoELM", "SparseMoELM", "HybridSSMLM"]
 
 
 class TransformerLM(nn.Layer):
+    #: a decode step hands `forward` the slots that hold a request
+    #: (`live`), and each layer's attention skips the others
+    takes_live = True
+
     def __init__(self, vocab_size, d_model=256, num_heads=8,
                  num_layers=4, max_position=2048, dim_feedforward=None,
                  dropout=0.0, use_flash_attention=None):
@@ -67,7 +71,7 @@ class TransformerLM(nn.Layer):
         self.ln_f = nn.LayerNorm(d_model)
         self.head = nn.Linear(d_model, vocab_size)
 
-    def forward(self, ids, cache=None, pos=None, adapter=None):
+    def forward(self, ids, cache=None, pos=None, adapter=None, live=None):
         T = int(ids.shape[1])
         if cache is None:
             h = self.embed(ids) + self.pos_embed(
@@ -83,7 +87,7 @@ class TransformerLM(nn.Layer):
         h = self.embed(ids) + self.pos_embed(pos_ids)
         new_caches = []
         for blk, c in zip(self.blocks, cache):
-            h, nc = blk(h, cache=c, pos=pos, adapter=adapter)
+            h, nc = blk(h, cache=c, pos=pos, adapter=adapter, live=live)
             new_caches.append(nc)
         return self.head(self.ln_f(h)), new_caches
 

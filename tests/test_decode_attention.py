@@ -246,11 +246,11 @@ APPEND_CAP = 3 * TILE
 APPEND_POS = np.array([0, TILE - 1, TILE, APPEND_CAP - 1])
 
 
-def _append_operands(heads, dtype, seed=7):
+def _append_operands(heads, dtype, seed=7, slots=len(APPEND_POS)):
     """q, K, V at the cache dtype and float32 new rows (the kernel casts
     them, as `kv_append` does)."""
     ks = jax.random.split(jax.random.PRNGKey(seed), 5)
-    b, shape = len(APPEND_POS), (len(APPEND_POS), heads, APPEND_CAP, D)
+    b, shape = slots, (slots, heads, APPEND_CAP, D)
     q = jax.random.normal(ks[0], (b, heads, 1, D), jnp.float32)
     k = jax.random.normal(ks[1], shape, jnp.float32).astype(dtype)
     v = jax.random.normal(ks[2], shape, jnp.float32).astype(dtype)
@@ -318,3 +318,97 @@ def test_append_attention_refuses_what_it_cannot_tile(k_shape, q_shape,
             jnp.zeros(q_shape), jnp.zeros(k_shape), jnp.zeros(k_shape),
             jnp.zeros(new_shape), jnp.zeros(new_shape),
             jnp.zeros(k_shape[0], jnp.int32), interpret=True)
+
+
+# -- the live mask: a dead slot's grid step moves nothing --------------------
+
+# eight slots, each pattern below asks of them: a tile's first and last
+# row, the next tile's first, past two tiles, the capacity's last, just
+# past one tile, inside the first, and the second tile's second row
+MASK_POS = np.array([0, TILE - 1, TILE, 2 * TILE + 5, APPEND_CAP - 1,
+                     TILE + 2, 7, TILE + 1])
+MASKS = {
+    "all_live": [1, 1, 1, 1, 1, 1, 1, 1],
+    "all_dead": [0, 0, 0, 0, 0, 0, 0, 0],
+    "one_live": [0, 0, 0, 1, 0, 0, 0, 0],
+    "first_dead": [0, 1, 1, 1, 1, 1, 1, 1],
+    "last_dead": [1, 1, 1, 1, 1, 1, 1, 0],
+    "alternating": [1, 0, 1, 0, 1, 0, 1, 0],
+    # slots 3 and 7 write and read past their first tile, each after a
+    # dead slot: the next live slot's first tiles are fetched across it
+    "dead_then_past_a_tile": [1, 1, 0, 1, 0, 0, 0, 1],
+}
+
+
+def _masked_operands(heads):
+    return (*_append_operands(heads, jnp.float32, 13, len(MASK_POS)),
+            jnp.asarray(MASK_POS, jnp.int32))
+
+
+def _assert_masked(got, want, operands, live):
+    """Live rows and live slots' caches bit for bit the unmasked
+    kernel's; dead rows zero, dead slots' caches as they came in."""
+    (out, k, v), (out0, k0, v0) = got, want
+    _, k_in, v_in = operands[:3]
+    dead = ~live
+    assert bool((out[live] == out0[live]).all())
+    assert bool((k[live] == k0[live]).all())
+    assert bool((v[live] == v0[live]).all())
+    assert bool((out[dead] == 0).all())
+    assert bool((k[dead] == k_in[dead]).all())
+    assert bool((v[dead] == v_in[dead]).all())
+
+
+@pytest.mark.parametrize("mask", list(MASKS))
+@pytest.mark.parametrize("heads", [16, 20], ids=["chat", "batch"])
+def test_masked_kernel_skips_dead_slots(heads, mask):
+    """At the chat and the batch cell's head counts, whatever slots the
+    mask leaves live: their attention and caches are the unmasked
+    kernel's bit for bit, a dead slot's row of the attention is zero and
+    its caches are left as they were (its new rows are not written)."""
+    operands = _masked_operands(heads)
+    live = np.asarray(MASKS[mask], bool)
+    want = decode_append_attention(*operands, interpret=True)
+    got = decode_append_attention(*operands, jnp.asarray(live),
+                                  interpret=True)
+    assert got[0].shape == want[0].shape and got[0].dtype == want[0].dtype
+    _assert_masked(got, want, operands, live)
+
+
+def test_masked_kernel_copies_under_the_chips_rules(capfd):
+    """The same patterns in the interpreter that keeps the chip's rules
+    for copies (`pltpu.InterpretParams`): a copy lands only when it is
+    waited for, so a write that the pipeline never waits for leaves its
+    tile unwritten, and a buffer that two slots' copies share at once is
+    reported as a race, as is a semaphore left counting at the kernel's
+    end; fresh scratch is NaN, so a row the kernel never writes shows. (A
+    wait for a copy that was never started would spin for ever: the run
+    has a thread and a time limit of its own.)"""
+    import threading
+
+    from jax.experimental.pallas import tpu as pltpu
+
+    rules = pltpu.InterpretParams(dma_execution_mode="on_wait",
+                                  detect_races=True,
+                                  uninitialized_memory="nan")
+    operands = _masked_operands(4)
+    want = decode_append_attention(*operands, interpret=True)
+    got, failed = {}, []
+
+    def run():
+        try:
+            for name, mask in MASKS.items():
+                got[name] = jax.block_until_ready(decode_append_attention(
+                    *operands, jnp.asarray(mask, bool), interpret=rules))
+        except Exception as e:  # noqa: BLE001
+            failed.append(e)
+
+    worker = threading.Thread(target=run, daemon=True)
+    worker.start()
+    worker.join(300)
+    assert not worker.is_alive(), "a wait that no copy signals"
+    assert not failed, failed
+    said = capfd.readouterr().out
+    assert "RACE DETECTED" not in said and "non-zero count" not in said
+    for name, mask in MASKS.items():
+        _assert_masked(got[name], want, operands, np.asarray(mask, bool))

@@ -76,9 +76,9 @@ def _tiny_lm(vocab=48, cap=24, layers=2, heads=4, d=32):
 
 
 def _unfused_append_attention(query, k_cache, v_cache, k_new, v_new, pos,
-                               *, scale=None):
+                               *, scale=None, live=None):
     """`cached_append_attention` as its three calls, never folded: the
-    write of K, of V, then the read."""
+    write of K, of V, then the read, which take no notice of `live`."""
     k = attn_route.cache_update(k_cache, k_new, pos)
     v = attn_route.cache_update(v_cache, v_new, pos)
     return attn_route.cached_attention(query, k, v, pos, scale=scale), k, v
@@ -675,9 +675,6 @@ class TestInferenceEngine:
         model = _tiny_lm(cap=32)
         engine = InferenceEngine(model, slots=2, max_length=32,
                                  sync_every=2)
-        dispatched = []
-        decode = engine._decode
-        engine._decode = lambda st: (dispatched.append(1), decode(st))[1]
         # 12 decoded tokens after the first: 6 windows of 2 steps
         engine.submit(Request(rng.randint(0, 48, size=(4,)),
                               max_new_tokens=13))
@@ -691,7 +688,8 @@ class TestInferenceEngine:
         after = metrics.sampler_steps()
         moved = {k: after[k] - before[k] for k in after}
         assert sorted(moved) == ["greedy", "sampling"]
-        assert moved["greedy"] + moved["sampling"] == len(dispatched) == 12
+        dispatched = engine._decode._n_steps
+        assert moved["greedy"] + moved["sampling"] == dispatched == 12
         assert moved["sampling"] == (4 if sampled else 0)
 
     def test_kv_append_kernel_serves_the_same_tokens(
@@ -827,6 +825,91 @@ class TestInferenceEngine:
         assert old["fused"] == 0 and old["kernel"] == 0
         assert tokens == two_kernels == want
         assert [len(t) for t in tokens] == [6, 5, 4, 7]
+
+    def test_dead_slots_skipped_serve_the_same_tokens(
+            self, trivial_mesh, monkeypatch):
+        """The decode kernel handed the step's live mask serves the
+        tokens of the kernel without it and of XLA's dense form, in a
+        run where a slot finishes early in a window, stays dead for the
+        rest of it and is then given a new request, and a third slot
+        stays empty until the queue reaches it. `decode_slot_steps()`
+        counts every slot of every step the masked program ran, a token
+        emitted as live and a -1 as dead, and nothing on the other two
+        routes."""
+        from paddle_tpu.observability import metrics
+        from paddle_tpu.serving import TransformerLM
+
+        monkeypatch.setenv("PADDLE_FLASH_DEFAULT", "interpret")
+        prompts = [rng.randint(0, 48, size=(n,)) for n in (9, 130, 4, 6)]
+        budgets = (3, 9, 5, 4)
+
+        def serve():
+            paddle.seed(89)
+            engine = InferenceEngine(_tiny_lm(cap=384, layers=2), slots=3,
+                                     max_length=384, sync_every=4)
+            reqs = [Request(p, max_new_tokens=m)
+                    for p, m in zip(prompts[:2], budgets[:2])]
+            for q in reqs:
+                engine.submit(q)
+            before, results = metrics.decode_slot_steps(), {}
+            engine.turn(results)
+            # the first request's slot was dead for the last two steps of
+            # that window; the queue refills it and the empty third slot
+            for p, m in zip(prompts[2:], budgets[2:]):
+                reqs.append(Request(p, max_new_tokens=m))
+                engine.submit(reqs[-1])
+            while engine.turn(results):
+                pass
+            after = metrics.decode_slot_steps()
+            return (engine, [results[q.rid].tokens for q in reqs],
+                    {k: after[k] - before[k] for k in after},
+                    engine._decode._n_steps)
+
+        engine, tokens, steps, dispatched = serve()
+        assert engine._decode.masks_dead_slots
+        assert engine._decode.compiles == 1
+        # every token but each request's first (prefill's) is a live
+        # slot-step; every other slot of every step dispatched is dead
+        live = sum(len(t) for t in tokens) - len(tokens)
+        assert steps == {"live": live, "dead": 3 * dispatched - live}
+        assert steps["dead"] > 0
+        with monkeypatch.context() as m:
+            m.setattr(TransformerLM, "takes_live", False)
+            plain, unmasked, none, _ = serve()
+        assert not plain._decode.masks_dead_slots
+        with monkeypatch.context() as m:
+            m.setattr(attn_route, "_lane_cache_route", lambda c, u: None)
+            dense_engine, dense, also_none, _ = serve()
+        assert not dense_engine._decode.masks_dead_slots
+        assert none == also_none == {"live": 0, "dead": 0}
+        assert tokens == unmasked == dense
+        assert [len(t) for t in tokens] == list(budgets)
+
+    def test_decode_slot_steps_count_a_turn(self, trivial_mesh,
+                                            monkeypatch):
+        """One request of three tokens in three slots, four steps a
+        window: prefill gives its first token, the first two steps its
+        other two, and then it is done. Of the window's 12 slot-steps 2
+        are live and 10 dead; without the interpreter (the CPU's dense
+        form) nothing is counted."""
+        from paddle_tpu.observability import metrics
+
+        def one_turn():
+            paddle.seed(97)
+            engine = InferenceEngine(_tiny_lm(cap=128, layers=2), slots=3,
+                                     max_length=128, sync_every=4)
+            req = Request(rng.randint(0, 48, size=(5,)), max_new_tokens=3)
+            engine.submit(req)
+            before, results = metrics.decode_slot_steps(), {}
+            assert not engine.turn(results)
+            after = metrics.decode_slot_steps()
+            assert len(results[req.rid].tokens) == 3
+            return {k: after[k] - before[k] for k in after}
+
+        monkeypatch.delenv("PADDLE_FLASH_DEFAULT", raising=False)
+        assert one_turn() == {"live": 0, "dead": 0}
+        monkeypatch.setenv("PADDLE_FLASH_DEFAULT", "interpret")
+        assert one_turn() == {"live": 2, "dead": 10}
 
     @pytest.mark.slow
     def test_insert_on_free_many_requests(self, trivial_mesh):

@@ -444,12 +444,13 @@ def test_decode_attention_reads_the_stored_view_compiled_for_v5e(
 
 
 def _fused_decode_program(one_chip, monkeypatch, B, H, dtype, layers=2,
-                          cap=1024, D=64):
+                          cap=1024, D=64, live=False):
     """What a `DecodeStep` does to its caches through the one seam it
     calls, `cached_append_attention` (the K and V rows written at `pos`
-    and the one query row a slot attended, a layer at a time), compiled
-    for the described chip with the caches donated; with it, how the
-    trace counted the writes and the reads. (The route asks
+    and the one query row a slot attended, a layer at a time; with
+    `live`, the step's [B] mask of the slots that hold a request handed
+    on), compiled for the described chip with the caches donated; with
+    it, how the trace counted the writes and the reads. (The route asks
     `jax.default_backend()`, the CPU here: the test answers for it.)"""
     from paddle_tpu.core.tensor import Tensor
     from paddle_tpu.nn.functional import attention as attn_route
@@ -459,21 +460,23 @@ def _fused_decode_program(one_chip, monkeypatch, B, H, dtype, layers=2,
     monkeypatch.setattr(attn_route, "_lane_cache_route", lambda c, u: False)
     T = Tensor._wrap
 
-    def step(caches, q, u, pos):
+    def step(caches, q, u, pos, *mask):
         x, out = q, []
         for k, v in caches:
             o, k, v = attn_route.cached_append_attention(
-                T(q + x), T(k), T(v), T(u + x), T(u - x), T(pos))
+                T(q + x), T(k), T(v), T(u + x), T(u - x), T(pos),
+                live=T(mask[0]) if mask else None)
             x = x + o._data
             out.append((k._data, v._data))
         return out, x
 
     cache = _aval(one_chip, B, H, cap, D, dtype=dtype)
     row = _aval(one_chip, B, H, 1, D, dtype=dtype)
+    mask = (_aval(one_chip, B, dtype=jnp.bool_),) if live else ()
     w0, r0 = kv_append_routes(), cached_attention_routes()
     compiled = jax.jit(step, donate_argnums=(0,)).lower(
         [(cache, cache)] * layers, row, row,
-        _aval(one_chip, B, dtype=jnp.int32)).compile()
+        _aval(one_chip, B, dtype=jnp.int32), *mask).compile()
     w1, r1 = kv_append_routes(), cached_attention_routes()
     return compiled, ({r: w1[r] - w0[r] for r in w1},
                       {r: r1[r] - r0[r] for r in r1})
@@ -526,3 +529,174 @@ def test_decode_append_attention_writes_in_place_compiled_for_v5e(
     assert mem.temp_size_in_bytes < 16 << 20, mem.temp_size_in_bytes
     assert mem.alias_size_in_bytes == (
         2 * layers * B * H * cap * D * jnp.dtype(dtype).itemsize)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("B,H", [(32, 16), (16, 20)], ids=["chat", "batch"])
+def test_masked_decode_append_attention_compiled_for_v5e(
+        one_chip, B, H, dtype, monkeypatch):
+    """The decode step's cache work with the step's live mask handed on,
+    at the chat and the batch cell's caches: still one
+    `decode_append_attention` custom call a layer and no `kv_append`, its
+    scalar operands `pos` and the mask (two, where the unmasked call has
+    one: one program computes both for every layer), K and V aliased in
+    to out at their places after them, every cache aliased, no temporary
+    the size of a cache tensor; the trace counts one masked kernel a
+    layer."""
+    from paddle_tpu.observability.metrics import masked_kernels
+
+    cap, D, layers = 1024, 64, 2
+    before = masked_kernels()
+    compiled, (writes, reads) = _fused_decode_program(
+        one_chip, monkeypatch, B, H, dtype, live=True)
+    assert masked_kernels() - before == layers
+    assert writes == {"kernel": 0, "scatter": 0, "fused": 2 * layers}
+    assert reads == {"kernel": layers, "dense": 0}
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line]
+    assert len(calls) == layers, calls
+    name = jnp.dtype(dtype).name.replace("float", "f")
+    view = f"{name}[{B},{H},{D},{cap}]{{3,2,1,0"
+    for line in calls:
+        assert "decode_append_attention" in line and "kv_append" not in line
+        assert line.count(view) == 4, line
+        layout = re.search(r"operand_layout_constraints=\{(.*?)\}, "
+                           r"output_to_operand_aliasing", line).group(1)
+        assert layout.startswith(f"s32[{B}]{{0}}, s32[{B}]{{0}}, ")
+        assert layout.count(f"s32[{B}]") == 2, layout
+        assert "output_to_operand_aliasing={{1}: (5, {}), {2}: (6, {})}" \
+            in line, line
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 16 << 20, mem.temp_size_in_bytes
+    assert mem.alias_size_in_bytes == (
+        2 * layers * B * H * cap * D * jnp.dtype(dtype).itemsize)
+
+
+# -- what the live mask leaves as it was -------------------------------------
+
+#: sha-256 of the lowered text of each family's `PrefillStep` and
+#: `DecodeStep` at the toy sizes below, without and with the Pallas
+#: interpreter forced, as the program gave them before the decode step
+#: handed its live mask on. GPT-2's `DecodeStep` in the interpreter takes
+#: the masked kernel and is left out: every other program is the same.
+UNMASKED_STEPS = {
+    ("gpt2", "PrefillStep", "cpu"):
+        "e59435b9090777e5203a94396439beeb114d064cae12902d3baffc4e66d17f0c",
+    ("gpt2", "DecodeStep", "cpu"):
+        "ed1fb97c9492089857f1c0d46b6fa597765fd5e84a6be4c1b9c99e2e763521b9",
+    ("gpt2", "PrefillStep", "interpret"):
+        "e59435b9090777e5203a94396439beeb114d064cae12902d3baffc4e66d17f0c",
+    ("sarvam", "PrefillStep", "cpu"):
+        "acff77143d3c78b1b91a76fa49749e6f02c5e0021dd83de28462c0028c603235",
+    ("sarvam", "DecodeStep", "cpu"):
+        "b5fe18fc700b8eb583f56b8310846e7783af4185f1481d71fa5a383b9a9704fb",
+    ("sarvam", "PrefillStep", "interpret"):
+        "acff77143d3c78b1b91a76fa49749e6f02c5e0021dd83de28462c0028c603235",
+    ("sarvam", "DecodeStep", "interpret"):
+        "b5fe18fc700b8eb583f56b8310846e7783af4185f1481d71fa5a383b9a9704fb",
+    ("keye", "PrefillStep", "cpu"):
+        "6437cb731a19489467e2b4d5dbe96891fc9c5a9fc36859c2bad689a0a7dda272",
+    ("keye", "DecodeStep", "cpu"):
+        "88ec3eb936f59dbb8cae1391821f85707962729447623f8705d8834ab8879601",
+    ("keye", "PrefillStep", "interpret"):
+        "6437cb731a19489467e2b4d5dbe96891fc9c5a9fc36859c2bad689a0a7dda272",
+    ("keye", "DecodeStep", "interpret"):
+        "88ec3eb936f59dbb8cae1391821f85707962729447623f8705d8834ab8879601",
+    ("granite", "PrefillStep", "cpu"):
+        "f1f0f77c5873369d04bea97e55186b67056ceb96b50fae013fde64c671910c05",
+    ("granite", "DecodeStep", "cpu"):
+        "7aa467350103169d982fa3b9a4fab0c40f588991bc6523e00ec76460eb0100d1",
+    ("granite", "PrefillStep", "interpret"):
+        "f1f0f77c5873369d04bea97e55186b67056ceb96b50fae013fde64c671910c05",
+    ("granite", "DecodeStep", "interpret"):
+        "0f55a545f23b96a38c6404f73af610c7be1c4e4c3aaef588b78e67684c201e83",
+}
+
+
+def _step_texts(build, slots=2, cap=128, prompt=16):
+    """{label: sha-256 of the lowered text} of a `PrefillStep` over a
+    batch of `prompt`-token prompts and a `DecodeStep` after it, each
+    lowered where it is first called."""
+    import hashlib
+
+    from paddle_tpu.jit import DecodeState, DecodeStep, PrefillStep
+
+    comm._state.hybrid_mesh = None
+    paddle.seed(5)
+    lm = build()
+    lm.eval()
+    texts = {}
+    call = ledger.LedgeredFunction.__call__
+
+    def spy(self, *args, **kwargs):
+        if self.label not in texts:
+            texts[self.label] = hashlib.sha256(self.lower(
+                *args, **kwargs).as_text().encode()).hexdigest()
+        return call(self, *args, **kwargs)
+
+    ledger.LedgeredFunction.__call__ = spy
+    try:
+        ids = np.arange(slots * prompt).reshape(slots, prompt) % 40
+        _, cache, pos = PrefillStep(lm)(lm.gen_cache(slots, cap), ids,
+                                        [prompt] * slots)
+        DecodeStep(lm)(DecodeState.make(
+            cache, first_tokens=np.zeros(slots, np.int32), pos=pos))
+    finally:
+        ledger.LedgeredFunction.__call__ = call
+    return texts
+
+
+def _family(name, config):
+    """A builder of a benchmark family's model at its toy size
+    (`TOY_CFG`), unloaded."""
+    def build():
+        import sys
+
+        bench = os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmarks")
+        if bench not in sys.path:
+            sys.path.insert(0, bench)
+        import find
+        import weights
+
+        fam = find.load("families", name)
+        cfg = dict(weights.load_config(config), **fam.TOY_CFG)
+        return sys.modules[fam.__name__ + ".model"].build(
+            cfg, dtype="float32")
+
+    return build
+
+
+FAMILIES = {
+    "gpt2": lambda: TransformerLM(48, d_model=32, num_heads=4, num_layers=2,
+                                  max_position=128),
+    "sarvam": _family("sarvam_mla", "sarvam-105b"),
+    "keye": _family("keye_dsa", "keye-vl-2.0-30b-a3b"),
+    "granite": _family("granite_hybrid", "granite-4.0-h-micro"),
+}
+
+
+@pytest.mark.parametrize("interpret", [False, True],
+                         ids=["cpu", "interpret"])
+@pytest.mark.parametrize("family", list(FAMILIES))
+def test_programs_without_the_mask_are_unchanged(family, interpret,
+                                                 monkeypatch):
+    """Families that do not declare `takes_live` (sarvam, keye, granite)
+    lower the `PrefillStep` and `DecodeStep` they lowered before the
+    mask, textually, with the interpreter forced or not; so do GPT-2's
+    `PrefillStep` and, on the CPU's dense route, its `DecodeStep`, which
+    is handed the mask and takes no notice of it."""
+    if interpret:
+        monkeypatch.setenv("PADDLE_FLASH_DEFAULT", "interpret")
+    else:
+        monkeypatch.delenv("PADDLE_FLASH_DEFAULT", raising=False)
+    got = _step_texts(FAMILIES[family])
+    mode = "interpret" if interpret else "cpu"
+    for label in ("PrefillStep", "DecodeStep"):
+        want = UNMASKED_STEPS.get((family, label, mode))
+        if family == "gpt2" and label == "DecodeStep" and interpret:
+            assert want is None
+            continue
+        assert got[label] == want, (family, label, mode)
